@@ -74,7 +74,9 @@ class A2CConfig:
     # extra [T, B, obs] buffer + value forward; disable for image envs.
     time_limit_bootstrap: bool = True
     compute_dtype: str = "float32"  # "bfloat16" runs torsos on the MXU in bf16
-    use_pallas_scan: bool = False   # fused Pallas VMEM kernel for GAE
+    # Fused Pallas VMEM kernel for GAE: True compiles it (TPU only),
+    # "interpret" runs the Pallas interpreter (CPU-mesh tests).
+    use_pallas_scan: bool | str = False
     # In-graph all-finite guard over loss/grads/params folded into the
     # iteration (one fused reduction, surfaced as ``health_finite``) —
     # the same guard the IMPALA learner carries; ``common.run_loop``'s

@@ -29,11 +29,9 @@ from actor_critic_algs_on_tensorflow_tpu.models import (
     RecurrentActorCritic,
 )
 from actor_critic_algs_on_tensorflow_tpu.ops import Categorical, DiagGaussian
-from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
     DATA_AXIS,
     device_count,
-    donation_supported,
     put_by_specs,
     replicated_specs,
     shard_batch_specs,
@@ -97,7 +95,6 @@ def build_shard_map_iteration(
         out_specs=(specs, P()),
         check_vma=False,
     )
-    donate = donate and donation_supported()
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 
 
@@ -666,5 +663,5 @@ def run_loop(
         # caller never checkpoints a state whose final step went
         # unchecked.
         state = sentinel.flush(state)
-    profiling.sync(last_metrics)
+    jax.block_until_ready(last_metrics)
     return state, history

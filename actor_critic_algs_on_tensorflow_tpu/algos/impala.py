@@ -364,7 +364,9 @@ class ImpalaConfig:
     shard_step_barrier: bool = True
     shard_barrier_timeout_s: float = 60.0
     compute_dtype: str = "float32"  # "bfloat16" runs the torso on the MXU in bf16
-    use_pallas_scan: bool = False   # fused Pallas VMEM kernel for V-trace
+    # Fused Pallas VMEM kernel for V-trace: True compiles it (TPU only),
+    # "interpret" runs the Pallas interpreter (CPU-mesh tests).
+    use_pallas_scan: bool | str = False
     # Recurrent (LSTM) policy — the IMPALA-paper model family. Actors
     # thread the carry across rollouts like env state; each trajectory
     # ships its ENTRY carry and the learner replays the sequence from
@@ -1829,10 +1831,6 @@ def run_impala(
     ``stop_event`` set (e.g. by utils.health.ShutdownSignal on SIGTERM)
     stops at the next iteration boundary with a final checkpoint.
     """
-    from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
-        donation_supported,
-    )
-
     if cfg.actor_mode == "env_shim":
         raise ValueError(
             "actor_mode='env_shim' is the distributed serving topology "
@@ -1894,9 +1892,7 @@ def run_impala(
     # requires publication to snapshot params (device-side copy) so
     # actor snapshots never alias a donated buffer; the serialized
     # CPU-mesh mode keeps the plain step (donation buys nothing there).
-    donate = (
-        cfg.donate_buffers and donation_supported() and exec_lock is None
-    )
+    donate = cfg.donate_buffers and exec_lock is None
     if donate:
         learner_step = programs.learner_step_donated
         store = ParamStore(programs.copy_params(state.params))
@@ -2058,10 +2054,6 @@ def _run_impala_device(
     rollback re-publish) identical too. Env state is NOT checkpointed —
     a resumed run restarts the env fleet fresh, exactly like restarted
     actors in host mode."""
-    from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
-        donation_supported,
-    )
-
     if programs is None:
         programs = make_impala(cfg)
     assert programs.fused_iteration is not None, (
@@ -2073,9 +2065,7 @@ def _run_impala_device(
         else programs.init(jax.random.PRNGKey(cfg.seed))
     )
     exec_lock = _cpu_mesh_exec_lock(programs.mesh)
-    donate = (
-        cfg.donate_buffers and donation_supported() and exec_lock is None
-    )
+    donate = cfg.donate_buffers and exec_lock is None
     fused = (
         programs.fused_iteration_donated if donate
         else programs.fused_iteration
@@ -2176,7 +2166,11 @@ def _actor_process_main(
     actor ⇄ learner is the distributed-systems surface; §5 DCN row).
     Exits cleanly when the learner closes the connection.
     """
-    jax.config.update("jax_platforms", "cpu")
+    from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
+        pin_process_to_cpu,
+    )
+
+    pin_process_to_cpu(f"actor {actor_id}")
     from actor_critic_algs_on_tensorflow_tpu.distributed import (
         codec as codec_lib,
     )
@@ -2567,7 +2561,6 @@ def run_impala_distributed(
     )
     from actor_critic_algs_on_tensorflow_tpu.parallel import multihost
     from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
-        donation_supported,
         spans_processes,
     )
 
@@ -3066,9 +3059,7 @@ def run_impala_distributed(
             )
             procs[aid] = spawn(aid, restarts)
 
-    donate = (
-        cfg.donate_buffers and donation_supported() and exec_lock is None
-    )
+    donate = cfg.donate_buffers and exec_lock is None
     if donate:
         learner_step = programs.learner_step_donated
 
@@ -3584,10 +3575,6 @@ def run_impala_standby(
         LearnerServer,
         epoch_of,
     )
-    from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
-        donation_supported,
-    )
-
     if cfg.rollout_mode != "host":
         raise ValueError(
             f"--standby / run_impala_standby requires rollout_mode="
@@ -3648,7 +3635,6 @@ def run_impala_standby(
         warm_batch = jax.tree_util.tree_unflatten(treedef, dev_leaves)
         donate = (
             cfg.donate_buffers
-            and donation_supported()
             and _cpu_mesh_exec_lock(programs.mesh) is None
         )
         step = (

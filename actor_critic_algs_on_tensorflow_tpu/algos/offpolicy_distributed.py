@@ -123,7 +123,11 @@ def _offpolicy_actor_main(
     so KIND_CLOSE still reaches it; exiting would trip the runner's
     respawn) instead of free-running past the budget — the fixed-budget
     comparability contract of the acceptance test."""
-    jax.config.update("jax_platforms", "cpu")
+    from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
+        pin_process_to_cpu,
+    )
+
+    pin_process_to_cpu(f"replay-actor {actor_id}")
     from actor_critic_algs_on_tensorflow_tpu.distributed import (
         codec as codec_lib,
     )

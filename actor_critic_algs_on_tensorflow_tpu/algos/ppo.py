@@ -117,7 +117,9 @@ class PPOConfig:
     # Requires frame_stack >= 2 and time_limit_bootstrap=False.
     compact_frames: bool = False
     compute_dtype: str = "float32"  # "bfloat16" runs torsos on the MXU in bf16
-    use_pallas_scan: bool = False   # fused Pallas VMEM kernel for GAE
+    # Fused Pallas VMEM kernel for GAE: True compiles it (TPU only),
+    # "interpret" runs the Pallas interpreter (CPU-mesh tests).
+    use_pallas_scan: bool | str = False
     # In-graph all-finite guard over the per-minibatch losses and the
     # final params, folded into the iteration (one fused reduction;
     # surfaced as ``health_finite`` for common.run_loop's sentinel).

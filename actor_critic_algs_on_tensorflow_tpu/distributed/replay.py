@@ -1039,10 +1039,13 @@ def replay_server_main(
     coordinated ``--preempt-save`` teardown), flushes one final
     snapshot before exit so the shutdown is resumable end-to-end —
     only a SIGKILL costs the since-last-snapshot tail."""
-    import os
     import signal as signal_lib
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
+        pin_process_to_cpu,
+    )
+
+    pin_process_to_cpu(f"replay-server {shard_id}")
     from actor_critic_algs_on_tensorflow_tpu.distributed.transport import (
         ROLE_LEARNER,
         LearnerServer,

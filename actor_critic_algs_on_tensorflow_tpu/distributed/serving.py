@@ -805,7 +805,11 @@ def env_shim_actor_main(
     """
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
+        pin_process_to_cpu,
+    )
+
+    pin_process_to_cpu(f"env-shim {actor_id}")
     import jax.numpy as jnp  # noqa: F401  (jit inputs)
 
     from actor_critic_algs_on_tensorflow_tpu import envs as envs_lib

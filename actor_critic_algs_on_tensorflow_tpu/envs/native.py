@@ -7,13 +7,16 @@ driven through the same ordered-``io_callback`` contract as the
 gymnasium bridge, so trainers are agnostic to which backend produced
 the batch. Use ``native:CartPole-v1`` / ``native:Pendulum-v1`` env ids.
 
-The shared library is built once with g++ (no pip deps) and cached
-under ``native/build/``.
+The shared library is built with g++ (no pip deps) on first use into
+``native/build/`` (not tracked), under a name that carries the hash of
+``envpool.cpp``'s content: a checkout or copy with different source
+builds its own library, and one with the same source reuses it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,23 +29,33 @@ from flax import struct
 from jax.experimental import io_callback
 
 from actor_critic_algs_on_tensorflow_tpu.envs.core import Box, Discrete, JaxEnv
+from actor_critic_algs_on_tensorflow_tpu.envs.host import step_via_callback
 
 _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 _SRC = os.path.join(_REPO_ROOT, "native", "envpool.cpp")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libenvpool.so")
 _BUILD_LOCK = threading.Lock()
 _LIB = None
 
 
-def _compile() -> None:
+def _lib_path() -> str:
+    """Library path for the CURRENT content of ``envpool.cpp``."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libenvpool-{digest}.so")
+
+
+def _compile(lib_path: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    # Build beside the target and rename: actor processes that race to
+    # the first build each publish a complete library or none.
+    tmp_path = f"{lib_path}.{os.getpid()}.tmp"
     proc = subprocess.run(
         [
             "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-            "-pthread", _SRC, "-o", _LIB_PATH,
+            "-pthread", _SRC, "-o", tmp_path,
         ],
         capture_output=True,
         text=True,
@@ -52,26 +65,20 @@ def _compile() -> None:
             f"native envpool build failed "
             f"(exit {proc.returncode}):\n{proc.stderr}"
         )
+    os.replace(tmp_path, lib_path)
 
 
 def _load_library() -> ctypes.CDLL:
-    """Compile (once) and load the native pool."""
+    """Build (when no library exists for this source) and load the
+    native pool. A library that fails to load raises."""
     global _LIB
     with _BUILD_LOCK:
         if _LIB is not None:
             return _LIB
-        if not os.path.exists(_LIB_PATH) or os.path.getmtime(
-            _SRC
-        ) > os.path.getmtime(_LIB_PATH):
-            _compile()
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            # A cached binary from a different toolchain (e.g. a newer
-            # libstdc++ than this host ships) fails to load; rebuilding
-            # from source against the local toolchain recovers.
-            _compile()
-            lib = ctypes.CDLL(_LIB_PATH)
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _compile(lib_path)
+        lib = ctypes.CDLL(lib_path)
         lib.envpool_create.restype = ctypes.c_void_p
         lib.envpool_create.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_uint64,
@@ -135,13 +142,9 @@ class NativeEnvPool(JaxEnv):
         self._action_dim = lib.envpool_action_dim(self._handle)
         self._num_actions = lib.envpool_num_actions(self._handle)
         self._action_high = float(lib.envpool_action_high(self._handle))
-        n, od = num_envs, self._obs_dim
-        obs_struct = jax.ShapeDtypeStruct((n, od), jnp.float32)
-        vec = jax.ShapeDtypeStruct((n,), jnp.float32)
-        self._step_struct = (
-            obs_struct, vec, vec, vec, vec, obs_struct, vec, vec,
+        self._reset_struct = jax.ShapeDtypeStruct(
+            (num_envs, self._obs_dim), jnp.float32
         )
-        self._reset_struct = obs_struct
 
     # -- host-side impls ------------------------------------------------
 
@@ -171,11 +174,6 @@ class NativeEnvPool(JaxEnv):
         return None
 
     def reset(self, key: jax.Array, params=None) -> Tuple[NativeEnvState, jax.Array]:
-        from actor_critic_algs_on_tensorflow_tpu.envs.host import (
-            _require_host_callbacks,
-        )
-
-        _require_host_callbacks(self.name, key)
         seed = jax.random.randint(key, (), 0, np.iinfo(np.int32).max)
         obs = io_callback(
             self._host_reset, self._reset_struct, seed, ordered=True
@@ -183,23 +181,9 @@ class NativeEnvPool(JaxEnv):
         return NativeEnvState(t=jnp.zeros((), jnp.int32)), obs
 
     def step(self, key: jax.Array, state: NativeEnvState, action, params=None):
-        from actor_critic_algs_on_tensorflow_tpu.envs.host import (
-            _require_host_callbacks,
+        obs, reward, done, info = step_via_callback(
+            self._host_step, self.num_envs, (self._obs_dim,), action
         )
-
-        _require_host_callbacks(self.name, action)
-        out = io_callback(
-            self._host_step, self._step_struct, action, ordered=True
-        )
-        obs, reward, done, term, trunc, final_obs, ep_ret, ep_len = out
-        info = {
-            "terminated": term,
-            "truncated": trunc,
-            "final_obs": final_obs,
-            "episode_return": ep_ret,
-            "episode_length": ep_len,
-            "done_episode": done,
-        }
         return NativeEnvState(t=state.t + 1), obs, reward, done, info
 
     def observation_space(self, params=None):

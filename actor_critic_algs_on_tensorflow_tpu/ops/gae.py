@@ -28,7 +28,7 @@ def gae_advantages(
     lam: float = 0.95,
     terminations: jax.Array | None = None,
     truncation_values: jax.Array | None = None,
-    use_pallas: bool = False,
+    use_pallas: bool | str = False,
 ):
     """Compute GAE(lambda) advantages and value targets.
 
@@ -51,7 +51,9 @@ def gae_advantages(
       truncation_values: optional ``[T, ...]`` ``V(final_obs_t)`` used
         as the bootstrap at truncated steps (pre-auto-reset obs).
       use_pallas: compute the backward recurrence with the fused Pallas
-        VMEM kernel (ops.pallas_scan) instead of ``lax.scan``.
+        VMEM kernel (ops.pallas_scan) instead of ``lax.scan``. ``True``
+        compiles the kernel (TPU only — an error elsewhere);
+        ``"interpret"`` runs it in the Pallas interpreter (tests).
 
     Returns:
       ``(advantages, returns)`` each ``[T, ...]``; ``returns`` are the
@@ -80,7 +82,11 @@ def gae_advantages(
             linear_backward_scan,
         )
 
-        advantages = linear_backward_scan(deltas, gamma * lam * (1.0 - dones))
+        advantages = linear_backward_scan(
+            deltas,
+            gamma * lam * (1.0 - dones),
+            interpret=use_pallas == "interpret",
+        )
     else:
         def _step(carry, inp):
             delta, done = inp

@@ -18,8 +18,9 @@ The recurrence (identical shape for all three consumers):
   * n-step:    delta = reward,              decay = gamma * (1 - done),
                init  = bootstrap value
 
-Falls back to interpreter mode off-TPU so tests exercise the same code
-path on the CPU mesh.
+The kernel compiles through Mosaic, which exists on TPU only. Tests on
+the CPU mesh exercise the same kernel body by passing
+``interpret=True``; nothing here picks the interpreter by itself.
 """
 
 from __future__ import annotations
@@ -54,12 +55,15 @@ def linear_backward_scan(
     decay: jax.Array,
     init: jax.Array | None = None,
     *,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """``acc_t = deltas_t + decay_t * acc_{t+1}`` over axis 0, fused.
 
     ``deltas``/``decay``: ``[T, ...]`` (any trailing shape, f32).
     ``init``: optional ``[...]`` starting accumulator (``acc_T``).
+    ``interpret``: run the kernel body in the Pallas interpreter (any
+    backend; for tests). Unset, the kernel is compiled for the TPU, and
+    on another backend that is an error.
     Returns ``[T, ...]`` accumulators.
     """
     out_dtype = jnp.asarray(deltas).dtype
@@ -67,8 +71,6 @@ def linear_backward_scan(
     # precision fast); cast back so the flag is a pure perf switch.
     deltas = jnp.asarray(deltas, jnp.float32)
     decay = jnp.asarray(decay, jnp.float32)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     t_len = deltas.shape[0]
     batch_shape = deltas.shape[1:]
     n = 1

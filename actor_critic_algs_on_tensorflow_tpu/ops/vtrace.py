@@ -42,13 +42,16 @@ def vtrace(
     rho_bar: float = 1.0,
     c_bar: float = 1.0,
     pg_rho_bar: float | None = None,
-    use_pallas: bool = False,
+    use_pallas: bool | str = False,
 ) -> VTraceOutput:
     """Compute V-trace targets and policy-gradient advantages.
 
     All time-major inputs are ``[T, ...]``; ``bootstrap_value`` is
     ``[...]`` = V(s_T) under the target policy.  ``dones`` masks the
     bootstrap across episode boundaries (1.0 where s_{t+1} is a reset).
+    ``use_pallas`` selects the fused Pallas kernel as in
+    ``ops.gae.gae_advantages`` (``True`` compiled, ``"interpret"``
+    interpreted).
     """
     rewards = jnp.asarray(rewards)
     values = jnp.asarray(values)
@@ -68,7 +71,9 @@ def vtrace(
             linear_backward_scan,
         )
 
-        vs_minus_v = linear_backward_scan(deltas, discounts * cs)
+        vs_minus_v = linear_backward_scan(
+            deltas, discounts * cs, interpret=use_pallas == "interpret"
+        )
     else:
         def _step(acc, inp):
             delta, discount, c = inp

@@ -39,20 +39,9 @@ def initialize(
 
 
 def is_initialized() -> bool:
-    # jax >= 0.4.34 exposes this directly; fall back to inspecting the
-    # runtime state object for older versions. A live client means this
-    # process joined a cluster; a live service means it already HOSTS
-    # the coordinator — either way another
-    # ``jax.distributed.initialize`` would raise "should only be called
-    # once", so both count as initialized.
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None or global_state.service is not None
-    except Exception:
-        return False
+    """Whether this process already joined (or hosts) the multi-host
+    runtime — another ``jax.distributed.initialize`` would raise."""
+    return bool(jax.distributed.is_initialized())
 
 
 def process_count() -> int:

@@ -168,6 +168,8 @@ METRIC_NAMES: dict = {
     DEVICE + "step_share": "bench device leg: step_s share of wall",
     DEVICE + "vs_pipelined": "bench device leg: speedup vs pipelined",
     DEVICE + "vs_serial": "bench device leg: speedup vs serial",
+    DEVICE + "kind": "bench.py result stamp: "
+                     "jax.devices()[0].device_kind",
     # -- replay_*: prioritized replay tier (distributed/replay.py
     # shard + client-group counters, algos/offpolicy_distributed.py
     # learner loop, plus the pre-existing fused-path ring gauge)

@@ -5,7 +5,8 @@ exist under ``runs/`` or be explicitly marked cycled with a
 regeneration pointer. This script enforces that, so stale references
 (like r4's ``runs/pong21-serve``) can't rot silently:
 
-1. every literal ``runs/NAME`` in PERF.md / README.md / ARCHITECTURE.md
+1. every literal ``runs/NAME`` in PERF.md / PERF_HISTORY.md / README.md /
+   ARCHITECTURE.md
    resolves to a directory on disk, or the word "cycled" appears within
    3 lines of the reference (trailing sentence punctuation is stripped
    from the captured name before the file-vs-artifact heuristic, so
@@ -31,7 +32,7 @@ import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
-DOCS = ("PERF.md", "README.md", "ARCHITECTURE.md")
+DOCS = ("PERF.md", "PERF_HISTORY.md", "README.md", "ARCHITECTURE.md")
 
 # Rule 3: an *.orbax-checkpoint-tmp younger than this is an in-flight
 # save (async checkpointing is the default), not a stale dropping.

@@ -321,7 +321,10 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    import jax
+
+    # A host-side drill: stay off the chip whatever the environment says.
+    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )

@@ -29,7 +29,7 @@ def main() -> int:
         PPOConfig,
         make_ppo,
     )
-    from actor_critic_algs_on_tensorflow_tpu.utils.profiling import sync, trace
+    from actor_critic_algs_on_tensorflow_tpu.utils.profiling import trace
 
     cfg = PPOConfig(
         env="PongFlickerTPU-v0",
@@ -53,14 +53,14 @@ def main() -> int:
     fns = make_ppo(cfg)
     state = fns.init(jax.random.PRNGKey(0))
     state, metrics = fns.iteration(state)  # compile
-    sync(metrics)
+    jax.block_until_ready(metrics)
     state, metrics = fns.iteration(state)  # warm
-    sync(metrics)
+    jax.block_until_ready(metrics)
 
     with trace(out):
         for _ in range(2):
             state, metrics = fns.iteration(state)
-        sync(metrics)
+        jax.block_until_ready(metrics)
 
     # Aggregate the Perfetto JSON: device-lane complete events by name.
     paths = sorted(glob.glob(f"{out}/**/*.trace.json.gz", recursive=True))

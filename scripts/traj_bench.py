@@ -22,9 +22,10 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
+
+# A host-side drill: stay off the chip whatever the environment says.
+jax.config.update("jax_platforms", "cpu")
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

@@ -114,3 +114,35 @@ def test_a2c_trains_on_native_env():
         state, metrics = fns.iteration(state)
     m = {k: float(v) for k, v in metrics.items()}
     assert np.isfinite(list(m.values())).all(), m
+
+
+def test_library_is_keyed_by_source_content_not_mtime(tmp_path, monkeypatch):
+    """A checkout or copy gives every file a fresh mtime; what decides
+    a rebuild is the CONTENT of envpool.cpp."""
+    import os
+    import shutil
+
+    from actor_critic_algs_on_tensorflow_tpu.envs import native
+
+    built = native._lib_path()
+    copy = tmp_path / "envpool.cpp"
+    shutil.copy(native._SRC, copy)
+    os.utime(copy, (1, 1))  # same content, ancient mtime
+    monkeypatch.setattr(native, "_SRC", str(copy))
+    assert native._lib_path() == built
+    copy.write_text(copy.read_text() + "\n// edited\n")
+    assert native._lib_path() != built
+
+
+def test_library_load_error_is_raised_not_rebuilt(tmp_path, monkeypatch):
+    from actor_critic_algs_on_tensorflow_tpu.envs import native
+
+    broken = tmp_path / "libenvpool-broken.so"
+    broken.write_bytes(b"not a shared object")
+    compiled = []
+    monkeypatch.setattr(native, "_LIB", None)
+    monkeypatch.setattr(native, "_lib_path", lambda: str(broken))
+    monkeypatch.setattr(native, "_compile", compiled.append)
+    with pytest.raises(OSError):
+        native._load_library()
+    assert compiled == []

@@ -300,8 +300,13 @@ def test_mixed_fleet_fixed_seed_parity():
             ]
             client.push_trajectory(traj, [np.zeros(1, np.float32)])
         client.close()
+        # Wait for the goodbye frame too: the counters are compared
+        # exactly, so both drivers must have seen the whole sequence.
         deadline = time.monotonic() + 5.0
-        while len(sunk) < 6 and time.monotonic() < deadline:
+        while (
+            len(sunk) < 6
+            or server.metrics()["transport_graceful_closes"] < 1
+        ) and time.monotonic() < deadline:
             time.sleep(0.01)
         m = server.metrics()
         server.close()
