@@ -28,6 +28,7 @@ from actor_critic_algs_on_tensorflow_tpu.models import (
     GaussianActorCritic,
     RecurrentActorCritic,
 )
+from actor_critic_algs_on_tensorflow_tpu.models.networks import scale_pixels
 from actor_critic_algs_on_tensorflow_tpu.ops import Categorical, DiagGaussian
 from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
     DATA_AXIS,
@@ -37,6 +38,7 @@ from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
     shard_batch_specs,
     shard_map,
 )
+from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 # policy_fn(params, obs, key) -> (action, log_prob, value)
 PolicyFn = Callable[[Any, Any, jax.Array], Tuple[jax.Array, jax.Array, jax.Array]]
@@ -166,6 +168,17 @@ def make_policy_head(action_space, *, torso, hidden_sizes, compute_dtype):
     return model, dist_and_value
 
 
+def make_obs_prep(torso, compute_dtype):
+    """``prep(obs)``: the pixel torsos' own input conversion
+    (``scale_pixels``), for an update to run under its
+    ``minibatch_prep`` scope before the forward pass — the torso then
+    finds nothing left to convert. The same operations either way;
+    only the phase they are traced under moves."""
+    if torso in ("nature_cnn", "nature_cnn_s2d"):
+        return lambda obs: scale_pixels(obs, jnp.dtype(compute_dtype))
+    return lambda obs: obs
+
+
 def make_recurrent_policy_head(
     action_space,
     *,
@@ -236,14 +249,16 @@ def collect_rollout_recurrent(
     def _step(scan_carry, step_key):
         env_state, obs, lstm, prev_done = scan_carry
         k_act, k_env = jax.random.split(step_key)
-        dist, value, lstm = seq_dist_value(
-            params, norm(obs)[None], prev_done[None], lstm
-        )
-        action = dist.sample(k_act)[0]
-        log_prob = dist.log_prob(action[None])[0]
-        env_state, next_obs, reward, done, info = env.step(
-            k_env, env_state, action, env_params
-        )
+        with jax.named_scope(profiling.POLICY_ACT):
+            dist, value, lstm = seq_dist_value(
+                params, norm(obs)[None], prev_done[None], lstm
+            )
+            action = dist.sample(k_act)[0]
+            log_prob = dist.log_prob(action[None])[0]
+        with jax.named_scope(profiling.ENV_STEP):
+            env_state, next_obs, reward, done, info = env.step(
+                k_env, env_state, action, env_params
+            )
         traj = Trajectory(
             obs=obs,
             actions=action,
@@ -259,10 +274,11 @@ def collect_rollout_recurrent(
         }
         return (env_state, next_obs, lstm, done), (traj, ep_info)
 
-    keys = jax.random.split(key, length)
-    (env_state, obs, lstm, prev_done), (traj, ep_info) = jax.lax.scan(
-        _step, (env_state, obs, carry["lstm"], carry["prev_done"]), keys
-    )
+    with jax.named_scope(profiling.ROLLOUT):
+        keys = jax.random.split(key, length)
+        (env_state, obs, lstm, prev_done), (traj, ep_info) = jax.lax.scan(
+            _step, (env_state, obs, carry["lstm"], carry["prev_done"]), keys
+        )
     return (
         env_state,
         obs,
@@ -309,12 +325,15 @@ def collect_rollout(
     def _step(carry, step_key):
         env_state, obs = carry
         k_act, k_env = jax.random.split(step_key)
-        action, log_prob, value = policy_fn(params, obs, k_act)
-        env_state, next_obs, reward, done, info = env.step(
-            k_env, env_state, action, env_params
-        )
+        with jax.named_scope(profiling.POLICY_ACT):
+            action, log_prob, value = policy_fn(params, obs, k_act)
+        with jax.named_scope(profiling.ENV_STEP):
+            env_state, next_obs, reward, done, info = env.step(
+                k_env, env_state, action, env_params
+            )
+            stored_obs = obs if store_obs_fn is None else store_obs_fn(obs)
         traj = Trajectory(
-            obs=obs if store_obs_fn is None else store_obs_fn(obs),
+            obs=stored_obs,
             actions=action,
             rewards=reward,
             dones=done,
@@ -330,10 +349,11 @@ def collect_rollout(
             ep_info["final_obs"] = info["final_obs"]
         return (env_state, next_obs), (traj, ep_info)
 
-    keys = jax.random.split(key, length)
-    (env_state, obs), (traj, ep_info) = jax.lax.scan(
-        _step, (env_state, obs), keys
-    )
+    with jax.named_scope(profiling.ROLLOUT):
+        keys = jax.random.split(key, length)
+        (env_state, obs), (traj, ep_info) = jax.lax.scan(
+            _step, (env_state, obs), keys
+        )
     return env_state, obs, traj, ep_info
 
 
@@ -607,11 +627,12 @@ def run_loop(
     if sentinel is not None:
         # The pre-loop (or resumed) state is the first rollback target.
         sentinel.seed(state, iters_done0 - 1)
-    for it in range(num_iters):
+    for it in profiling.traced_steps(range(num_iters)):
         state, metrics = fns.iteration(state)
         last_metrics = metrics
         if sentinel is not None:
-            state = sentinel.after_step(iters_done0 + it, state, metrics)
+            with profiling.span(profiling.SENTINEL_CHECK):
+                state = sentinel.after_step(iters_done0 + it, state, metrics)
         if "episodes" in metrics:
             n = metrics["episodes"]
             r = metrics["avg_return"] * n
@@ -628,7 +649,8 @@ def run_loop(
             if ep_count is not None:
                 fetch["episodes"] = ep_count
                 fetch["_window_return_sum"] = ret_sum
-            m = device_get_metrics(fetch)
+            with profiling.span(profiling.LOG_FETCH):
+                m = device_get_metrics(fetch)
             if ep_count is not None:
                 rs = m.pop("_window_return_sum")
                 m["avg_return"] = rs / m["episodes"] if m["episodes"] else 0.0

@@ -62,7 +62,7 @@ from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
     shard_map,
 )
 from actor_critic_algs_on_tensorflow_tpu.utils import health as health_lib
-from actor_critic_algs_on_tensorflow_tpu.utils import metric_names
+from actor_critic_algs_on_tensorflow_tpu.utils import metric_names, profiling
 
 TIME_AXIS = "time"
 
@@ -630,9 +630,10 @@ class ImpalaActor(threading.Thread):
                     )
                 params = self._store.snapshot()
                 self._key, k = jax.random.split(self._key)
-                env_state, obs, carry, traj, ep = self._run_serialized(
-                    self._rollout, params, env_state, obs, carry, k
-                )
+                with profiling.span(profiling.ACTOR_ROLLOUT_DISPATCH):
+                    env_state, obs, carry, traj, ep = self._run_serialized(
+                        self._rollout, params, env_state, obs, carry, k
+                    )
                 if self._inject_poison.is_set():
                     traj = self._run_serialized(
                         lambda t: t.replace(
@@ -640,13 +641,14 @@ class ImpalaActor(threading.Thread):
                         ),
                         traj,
                     )
-                while not self._halt.is_set():
-                    try:
-                        self._queue.put((traj, ep), timeout=0.5)
-                        self.rollouts += 1
-                        break
-                    except queue_lib.Full:  # retry until stop
-                        continue
+                with profiling.span(profiling.ACTOR_QUEUE_PUT):
+                    while not self._halt.is_set():
+                        try:
+                            self._queue.put((traj, ep), timeout=0.5)
+                            self.rollouts += 1
+                            break
+                        except queue_lib.Full:  # retry until stop
+                            continue
         except BaseException as e:  # surfaced by run_impala
             self.error = e
 
@@ -811,6 +813,7 @@ def make_impala(cfg: ImpalaConfig):
             hidden_sizes=cfg.hidden_sizes,
             compute_dtype=cfg.compute_dtype,
         )
+    prep_obs = common.make_obs_prep(cfg.torso, cfg.compute_dtype)
 
     steps_per_batch = (
         cfg.batch_trajectories * cfg.envs_per_actor * cfg.rollout_length
@@ -948,23 +951,25 @@ def make_impala(cfg: ImpalaConfig):
         batch: ``(dist, values, last_value, target_log_probs)`` —
         shared by the loss, the fused device iteration (through the
         loss), and the standalone ``vtrace_targets`` probe."""
+        with jax.named_scope(profiling.MINIBATCH_PREP):
+            obs, last_obs = prep_obs(batch.obs), prep_obs(batch.last_obs)
         if cfg.recurrent:
             resets = common.replay_resets(
                 batch.entry_prev_done, batch.dones
             )
             dist, values, carry_end = seq_dist_value(
-                params, batch.obs, resets, batch.entry_lstm
+                params, obs, resets, batch.entry_lstm
             )
             # Bootstrap value of last_obs continues the sequence
             # from the replayed end-of-rollout carry.
             _, last_value_tb, _ = seq_dist_value(
-                params, batch.last_obs[None], batch.dones[-1][None],
+                params, last_obs[None], batch.dones[-1][None],
                 carry_end,
             )
             last_value = last_value_tb[0]
         else:
-            dist, values = dist_and_value(params, batch.obs)
-            _, last_value = dist_and_value(params, batch.last_obs)
+            dist, values = dist_and_value(params, obs)
+            _, last_value = dist_and_value(params, last_obs)
         target_log_probs = dist.log_prob(batch.actions)
         return dist, values, last_value, target_log_probs
 
@@ -995,16 +1000,18 @@ def make_impala(cfg: ImpalaConfig):
             rho_bar=cfg.rho_bar,
             c_bar=cfg.c_bar,
         )
-        if cfg.time_shards > 1:
-            return sp_vtrace(
-                *vtrace_args, axis_name=TIME_AXIS, **vtrace_kw
+        with jax.named_scope(profiling.ADVANTAGE):
+            if cfg.time_shards > 1:
+                return sp_vtrace(
+                    *vtrace_args, axis_name=TIME_AXIS, **vtrace_kw
+                )
+            return vtrace(
+                *vtrace_args,
+                use_pallas=cfg.use_pallas_scan,
+                **vtrace_kw,
             )
-        return vtrace(
-            *vtrace_args,
-            use_pallas=cfg.use_pallas_scan,
-            **vtrace_kw,
-        )
 
+    @jax.named_scope(profiling.UPDATE)
     def local_learner_step(state: LearnerState, batch: ActorTrajectory):
         """Batch fields are ``[T_local, B_local, ...]`` (B sharded on
         ``data``; T additionally sharded on ``time`` when
@@ -1017,9 +1024,10 @@ def make_impala(cfg: ImpalaConfig):
             vt = _vtrace_of(batch, target_log_probs, values, last_value)
             adv = jax.lax.stop_gradient(vt.pg_advantages)
             if cfg.normalize_advantages:
-                adv = common.global_normalize_advantages(
-                    adv, axis_name=mesh_axes
-                )
+                with jax.named_scope(profiling.ADVANTAGE):
+                    adv = common.global_normalize_advantages(
+                        adv, axis_name=mesh_axes
+                    )
             pg = -jnp.mean(target_log_probs * adv)
             vf = value_loss(values, jax.lax.stop_gradient(vt.vs))
             ent = dist.entropy().mean()
@@ -1027,13 +1035,17 @@ def make_impala(cfg: ImpalaConfig):
             aux = (pg, vf, ent, jnp.mean(vt.rhos))
             return total, aux
 
-        (loss, (pg, vf, ent, rho)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params)
-        # Equal-sized shards: pmean over all mesh axes = global mean.
-        grads = jax.lax.pmean(grads, mesh_axes)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(profiling.LOSS_GRAD):
+            (loss, (pg, vf, ent, rho)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params)
+        with jax.named_scope(profiling.OPTIMIZER):
+            # Equal-sized shards: pmean over all mesh axes = global mean.
+            grads = jax.lax.pmean(grads, mesh_axes)
+            updates, opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         guard_metrics = {}
         if cfg.numerics_guards:
             # In-graph numerics guard: one fused all-finite reduction
@@ -1504,14 +1516,13 @@ def _learner_loop(
         # The one place the serialize rule lives: a CPU-mesh exec_lock
         # (collective-bearing programs must retire before the next
         # dispatch) wraps batch materialization + step + sync.
-        tc = time.perf_counter()
-        if exec_lock is None:
-            state, metrics = learner_step(state, make_batch())
-        else:
-            with exec_lock:
+        with split.span("compute_s"):
+            if exec_lock is None:
                 state, metrics = learner_step(state, make_batch())
-                jax.block_until_ready(metrics)
-        split.add("compute_s", time.perf_counter() - tc)
+            else:
+                with exec_lock:
+                    state, metrics = learner_step(state, make_batch())
+                    jax.block_until_ready(metrics)
         return state, metrics
 
     if sentinel is not None:
@@ -1533,10 +1544,8 @@ def _learner_loop(
         returns None and the loop joins the stop-step consensus."""
         if step_barrier is None:
             return True
-        tb = time.perf_counter()
-        outcome = step_barrier(it, stop_evt)
-        split.add("barrier_wait_s", time.perf_counter() - tb)
-        return outcome != "stop"
+        with split.span("barrier_wait_s"):
+            return step_barrier(it, stop_evt) != "stop"
 
     def collect_and_step(state, stop_evt, it, *, q_timeout=1.0,
                          lockstep=True):
@@ -1557,15 +1566,13 @@ def _learner_loop(
             # collect + learn; nothing to drain, nothing to stack.
             if stop_evt is not None and stop_evt.is_set():
                 return None
-            td = time.perf_counter()
-            if exec_lock is None:
-                out = fused_step(state, it)
-            else:
+            with device_split.span("step_s"):
+                if exec_lock is None:
+                    return fused_step(state, it)
                 with exec_lock:
                     out = fused_step(state, it)
                     jax.block_until_ready(out[1])
-            device_split.add("step_s", time.perf_counter() - td)
-            return out
+                    return out
         if pipe is not None:
             got = pipe.get(stop=stop_evt)
             if got is None:
@@ -1578,24 +1585,23 @@ def _learner_loop(
             del batch  # donated or pipeline-owned; never reused here
             return state, metrics, eps
         trajs, eps = [], []
-        tq0 = time.perf_counter()
-        while len(trajs) < cfg.batch_trajectories:
-            if stop_evt is not None and stop_evt.is_set():
-                return None
-            check_health(it)
-            try:
-                traj, ep = q.get(timeout=q_timeout)
-            except queue_lib.Empty:  # re-check actor health
-                continue
-            if isinstance(traj, CodedTrajectory):
-                traj = decode_serial(traj, ep)
-                if traj is None:
-                    continue  # undecodable or validator-rejected
-            elif validate is not None and not validate(traj, ep):
-                continue  # dropped-and-recorded by the validator
-            trajs.append(traj)
-            eps.append(ep)
-        split.add("queue_wait_s", time.perf_counter() - tq0)
+        with split.span("queue_wait_s"):
+            while len(trajs) < cfg.batch_trajectories:
+                if stop_evt is not None and stop_evt.is_set():
+                    return None
+                check_health(it)
+                try:
+                    traj, ep = q.get(timeout=q_timeout)
+                except queue_lib.Empty:  # re-check actor health
+                    continue
+                if isinstance(traj, CodedTrajectory):
+                    traj = decode_serial(traj, ep)
+                    if traj is None:
+                        continue  # undecodable or validator-rejected
+                elif validate is not None and not validate(traj, ep):
+                    continue  # dropped-and-recorded by the validator
+                trajs.append(traj)
+                eps.append(ep)
         if lockstep and not hold_lockstep(it, stop_evt):
             return None
         state, metrics = dispatch_step(
@@ -1609,7 +1615,7 @@ def _learner_loop(
     iters_completed = 0
     interrupted = False
     try:
-        for i in range(num_learner_steps):
+        for i in profiling.traced_steps(range(num_learner_steps)):
             if stop_event is not None and stop_event.is_set():
                 interrupted = True
                 break
@@ -1628,11 +1634,13 @@ def _learner_loop(
                 # Guard check on the step that just ran; on a trip this
                 # returns the restored last-good state (and re-publishes
                 # params); on budget exhaustion it raises.
-                state = sentinel.after_step(it, state, metrics)
+                with profiling.span(profiling.SENTINEL_CHECK):
+                    state = sentinel.after_step(it, state, metrics)
             iters_completed = i + 1
             env_steps = steps_done0 + (i + 1) * steps_per_batch
             if (it + 1) % cfg.publish_interval == 0:
-                publish(state.params)
+                with profiling.span(profiling.PUBLISH_PARAMS):
+                    publish(state.params)
             if (
                 checkpointer is not None
                 and checkpoint_interval
@@ -1659,7 +1667,8 @@ def _learner_loop(
                 if latest is None or ckpt_id > latest:
                     checkpointer.save(ckpt_id, state)
             if (i + 1) % log_interval == 0 or i == num_learner_steps - 1:
-                m = device_get_metrics(metrics)
+                with profiling.span(profiling.LOG_FETCH):
+                    m = device_get_metrics(metrics)
                 m.update(_episode_stats(eps))
                 now = time.perf_counter()
                 window = i + 1 - last_log_i

@@ -49,7 +49,7 @@ from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
     make_mesh,
     put_by_specs,
 )
-from actor_critic_algs_on_tensorflow_tpu.utils import prng
+from actor_critic_algs_on_tensorflow_tpu.utils import prng, profiling
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,6 +211,7 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             hidden_sizes=cfg.hidden_sizes,
             compute_dtype=cfg.compute_dtype,
         )
+    prep_obs = common.make_obs_prep(cfg.torso, cfg.compute_dtype)
 
     num_iters = max(1, cfg.total_env_steps // (cfg.num_envs * cfg.rollout_length))
     if cfg.lr_decay:
@@ -309,20 +310,21 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             keep_final_obs=cfg.time_limit_bootstrap,
             store_obs_fn=store_obs_fn,
         )
-        _, last_value = dist_and_value(state.params, norm(obs))
-        if cfg.time_limit_bootstrap:
-            _, truncation_values = dist_and_value(
-                state.params, norm(ep_info["final_obs"])
+        with jax.named_scope(profiling.ADVANTAGE):
+            _, last_value = dist_and_value(state.params, norm(obs))
+            if cfg.time_limit_bootstrap:
+                _, truncation_values = dist_and_value(
+                    state.params, norm(ep_info["final_obs"])
+                )
+            else:
+                truncation_values = None
+            advantages, returns = gae_advantages(
+                traj.rewards, traj.values, traj.dones, last_value,
+                gamma=cfg.gamma, lam=cfg.gae_lambda,
+                terminations=ep_info["terminated"],
+                truncation_values=truncation_values,
+                use_pallas=cfg.use_pallas_scan,
             )
-        else:
-            truncation_values = None
-        advantages, returns = gae_advantages(
-            traj.rewards, traj.values, traj.dones, last_value,
-            gamma=cfg.gamma, lam=cfg.gae_lambda,
-            terminations=ep_info["terminated"],
-            truncation_values=truncation_values,
-            use_pallas=cfg.use_pallas_scan,
-        )
 
         batch = flatten_time_batch(
             {
@@ -354,8 +356,11 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             (normalization is the CALLER's job: per-minibatch for the
             minibatch path, whole-batch for accumulation)."""
 
+            with jax.named_scope(profiling.MINIBATCH_PREP):
+                obs = prep_obs(mb["obs"])
+
             def loss_fn(p):
-                dist, values = dist_and_value(p, norm(mb["obs"]))
+                dist, values = dist_and_value(p, norm(obs))
                 stats = ppo_clip_loss(
                     dist.log_prob(mb["actions"]),
                     mb["old_log_probs"],
@@ -373,9 +378,10 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 total = stats.policy_loss + cfg.vf_coef * vf - cfg.ent_coef * ent
                 return total, (stats, vf, ent)
 
-            (loss, (stats, vf, ent)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params)
+            with jax.named_scope(profiling.LOSS_GRAD):
+                (loss, (stats, vf, ent)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params)
             m = {
                 "loss": loss,
                 "policy_loss": stats.policy_loss,
@@ -387,22 +393,25 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             return grads, m
 
         def apply_grads(params, opt_state, grads):
-            grads = jax.lax.pmean(grads, DATA_AXIS)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state
+            with jax.named_scope(profiling.OPTIMIZER):
+                grads = jax.lax.pmean(grads, DATA_AXIS)
+                updates, opt_state = tx.update(grads, opt_state, params)
+                return optax.apply_updates(params, updates), opt_state
 
         def minibatch_update(carry, mb):
             params, opt_state = carry
             adv = mb["advantages"]
             if cfg.normalize_adv:
-                adv = common.global_normalize_advantages(adv)
+                with jax.named_scope(profiling.ADVANTAGE):
+                    adv = common.global_normalize_advantages(adv)
             grads, m = batch_grads(params, mb, adv)
             params, opt_state = apply_grads(params, opt_state, grads)
             return (params, opt_state), m
 
         def minibatch_step(carry, idx):
-            mb = take_minibatch(batch, idx)
-            mb["obs"] = minibatch_obs(idx)
+            with jax.named_scope(profiling.MINIBATCH_PREP):
+                mb = take_minibatch(batch, idx)
+                mb["obs"] = minibatch_obs(idx)
             return minibatch_update(carry, mb)
 
         # shuffle="env": minibatches are contiguous env blocks sliced
@@ -427,16 +436,17 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         }
 
         def env_minibatch_step(carry, start):
-            mb = {k: env_block(v, start) for k, v in env_tb.items()}
-            if cfg.compact_frames:
-                idx = (
-                    jnp.arange(cfg.rollout_length)[:, None] * local_envs
-                    + start
-                    + jnp.arange(mb_envs)[None, :]
-                ).reshape(-1)
-                mb["obs"] = minibatch_obs(idx)
-            else:
-                mb["obs"] = env_block(traj.obs, start)
+            with jax.named_scope(profiling.MINIBATCH_PREP):
+                mb = {k: env_block(v, start) for k, v in env_tb.items()}
+                if cfg.compact_frames:
+                    idx = (
+                        jnp.arange(cfg.rollout_length)[:, None] * local_envs
+                        + start
+                        + jnp.arange(mb_envs)[None, :]
+                    ).reshape(-1)
+                    mb["obs"] = minibatch_obs(idx)
+                else:
+                    mb["obs"] = env_block(traj.obs, start)
             return minibatch_update(carry, mb)
 
         def accum_epoch_update(carry):
@@ -449,22 +459,25 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             params, opt_state = carry
             adv = batch["advantages"]
             if cfg.normalize_adv:
-                adv = common.global_normalize_advantages(adv)
+                with jax.named_scope(profiling.ADVANTAGE):
+                    adv = common.global_normalize_advantages(adv)
             n_acc = cfg.grad_accum
             resh = lambda x: x.reshape((n_acc, -1) + x.shape[1:])
-            sliced = {k: resh(v) for k, v in batch.items()}
-            sliced["advantages"] = resh(adv)
-            if cfg.compact_frames:
-                obs_xs = jnp.arange(local_batch).reshape(n_acc, -1)
-                get_obs = minibatch_obs
-            else:
-                obs_xs = resh(obs_flat)
-                get_obs = lambda o: o
+            with jax.named_scope(profiling.MINIBATCH_PREP):
+                sliced = {k: resh(v) for k, v in batch.items()}
+                sliced["advantages"] = resh(adv)
+                if cfg.compact_frames:
+                    obs_xs = jnp.arange(local_batch).reshape(n_acc, -1)
+                    get_obs = minibatch_obs
+                else:
+                    obs_xs = resh(obs_flat)
+                    get_obs = lambda o: o
 
             def slice_step(gacc, xs):
                 mb, obs_x = xs
                 mb = dict(mb)
-                mb["obs"] = get_obs(obs_x)
+                with jax.named_scope(profiling.MINIBATCH_PREP):
+                    mb["obs"] = get_obs(obs_x)
                 grads, m = batch_grads(params, mb, mb["advantages"])
                 gacc = jax.tree_util.tree_map(jnp.add, gacc, grads)
                 return gacc, m
@@ -490,10 +503,13 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                     carry, m = accum_epoch_update(carry)
                 else:
                     mb = dict(batch)
-                    if cfg.compact_frames:
-                        mb["obs"] = minibatch_obs(jnp.arange(local_batch))
-                    else:
-                        mb["obs"] = obs_flat
+                    with jax.named_scope(profiling.MINIBATCH_PREP):
+                        if cfg.compact_frames:
+                            mb["obs"] = minibatch_obs(
+                                jnp.arange(local_batch)
+                            )
+                        else:
+                            mb["obs"] = obs_flat
                     carry, m = minibatch_update(carry, mb)
                 return carry, jax.tree_util.tree_map(lambda x: x[None], m)
             if env_sliced:
@@ -502,10 +518,11 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             idx = minibatch_iter_indices(k, local_batch, cfg.num_minibatches)
             return jax.lax.scan(minibatch_step, carry, idx)
 
-        epoch_keys = jax.random.split(k_perm, cfg.num_epochs)
-        (params, opt_state), m = jax.lax.scan(
-            epoch_step, (state.params, state.opt_state), epoch_keys
-        )
+        with jax.named_scope(profiling.UPDATE):
+            epoch_keys = jax.random.split(k_perm, cfg.num_epochs)
+            (params, opt_state), m = jax.lax.scan(
+                epoch_step, (state.params, state.opt_state), epoch_keys
+            )
         # Mean over [num_epochs, num_minibatches]; pmean so replicated.
         metrics = jax.lax.pmean(
             jax.tree_util.tree_map(jnp.mean, m), DATA_AXIS
@@ -558,17 +575,18 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 cfg.rollout_length, norm=norm,
             )
         )
-        _, last_value_tb, _ = seq_dist_value(
-            state.params, norm(obs)[None], carry1["prev_done"][None],
-            carry1["lstm"],
-        )
-        advantages, returns = gae_advantages(
-            traj.rewards, traj.values, traj.dones, last_value_tb[0],
-            gamma=cfg.gamma, lam=cfg.gae_lambda,
-            terminations=ep_info["terminated"],
-            truncation_values=None,
-            use_pallas=cfg.use_pallas_scan,
-        )
+        with jax.named_scope(profiling.ADVANTAGE):
+            _, last_value_tb, _ = seq_dist_value(
+                state.params, norm(obs)[None], carry1["prev_done"][None],
+                carry1["lstm"],
+            )
+            advantages, returns = gae_advantages(
+                traj.rewards, traj.values, traj.dones, last_value_tb[0],
+                gamma=cfg.gamma, lam=cfg.gae_lambda,
+                terminations=ep_info["terminated"],
+                truncation_values=None,
+                use_pallas=cfg.use_pallas_scan,
+            )
 
         resets_tb = common.replay_resets(carry0["prev_done"], traj.dones)
         env_tb = {
@@ -585,11 +603,15 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             params, opt_state = carry_po
             adv = block["advantages"].reshape(-1)
             if cfg.normalize_adv:
-                adv = common.global_normalize_advantages(adv)
+                with jax.named_scope(profiling.ADVANTAGE):
+                    adv = common.global_normalize_advantages(adv)
+
+            with jax.named_scope(profiling.MINIBATCH_PREP):
+                obs = prep_obs(block["obs"])
 
             def loss_fn(p):
                 dist, values_tb, _ = seq_dist_value(
-                    p, norm(block["obs"]), block["resets"], block["lstm"]
+                    p, norm(obs), block["resets"], block["lstm"]
                 )
                 stats = ppo_clip_loss(
                     dist.log_prob(block["actions"]).reshape(-1),
@@ -611,12 +633,14 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 )
                 return total, (stats, vf, ent)
 
-            (loss, (stats, vf, ent)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params)
-            grads = jax.lax.pmean(grads, DATA_AXIS)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope(profiling.LOSS_GRAD):
+                (loss, (stats, vf, ent)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params)
+            with jax.named_scope(profiling.OPTIMIZER):
+                grads = jax.lax.pmean(grads, DATA_AXIS)
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             m = {
                 "loss": loss,
                 "policy_loss": stats.policy_loss,
@@ -630,20 +654,16 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         mb_envs = local_envs // cfg.num_minibatches
 
         def env_block_update(carry_po, start):
-            block = {
-                k: jax.lax.dynamic_slice_in_dim(v, start, mb_envs, axis=1)
-                for k, v in env_tb.items()
-            }
-            block["obs"] = jax.lax.dynamic_slice_in_dim(
-                traj.obs, start, mb_envs, axis=1
-            )
-            block["resets"] = jax.lax.dynamic_slice_in_dim(
-                resets_tb, start, mb_envs, axis=1
-            )
-            block["lstm"] = jax.tree_util.tree_map(
-                lambda x: jax.lax.dynamic_slice_in_dim(x, start, mb_envs, 0),
-                carry0["lstm"],
-            )
+            def cut(x, axis):
+                return jax.lax.dynamic_slice_in_dim(x, start, mb_envs, axis)
+
+            with jax.named_scope(profiling.MINIBATCH_PREP):
+                block = {k: cut(v, 1) for k, v in env_tb.items()}
+                block["obs"] = cut(traj.obs, 1)
+                block["resets"] = cut(resets_tb, 1)
+                block["lstm"] = jax.tree_util.tree_map(
+                    lambda x: cut(x, 0), carry0["lstm"]
+                )
             return seq_update(carry_po, block)
 
         def epoch_step(carry_po, k):
@@ -657,10 +677,11 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             starts = env_block_starts(k, cfg.num_minibatches, mb_envs)
             return jax.lax.scan(env_block_update, carry_po, starts)
 
-        epoch_keys = jax.random.split(k_perm, cfg.num_epochs)
-        (params, opt_state), m = jax.lax.scan(
-            epoch_step, (state.params, state.opt_state), epoch_keys
-        )
+        with jax.named_scope(profiling.UPDATE):
+            epoch_keys = jax.random.split(k_perm, cfg.num_epochs)
+            (params, opt_state), m = jax.lax.scan(
+                epoch_step, (state.params, state.opt_state), epoch_keys
+            )
         metrics = jax.lax.pmean(
             jax.tree_util.tree_map(jnp.mean, m), DATA_AXIS
         )

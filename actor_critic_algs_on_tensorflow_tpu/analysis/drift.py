@@ -27,7 +27,7 @@ emits). Rules:
 
 Metric *uses* are collected statically: dict-literal keys, subscript
 keys (read or write), ``.get("...")`` first args, ``TimeSplit``
-prefix + ``.add("...")`` names, and ``LatencyStats.summary(prefix)``
+prefix + ``.add("...")`` / ``.span("...")`` names, and ``LatencyStats.summary(prefix)``
 expansions — with names resolved through the ``metric_names``
 constants and f-string interpolations rendered as ``*`` wildcards.
 """
@@ -218,8 +218,11 @@ def collect_metric_uses(
                 if leaf == "get" and node.args:
                     record(fold_str(node.args[0], consts), rp,
                            node.lineno)
-                # TimeSplit .add("name", seconds) -> prefix + name.
-                elif leaf == "add" and node.args and isinstance(
+                # TimeSplit .add("name", seconds) and .span("name")
+                # -> prefix + name. (A bare ``profiling.span`` has no
+                # counter behind it: only a receiver this module bound
+                # to a TimeSplit makes a ``span`` a metric use.)
+                elif leaf in ("add", "span") and node.args and isinstance(
                     node.func, ast.Attribute
                 ):
                     name = fold_str(node.args[0], consts)
@@ -228,9 +231,9 @@ def collect_metric_uses(
                     ):
                         recv = func_name(node.func.value)
                         prefixes = prefix_bindings.get(recv)
-                        if prefixes is None or len(
-                            prefix_bindings.get(recv, ())
-                        ) > 1:
+                        if prefixes is None and leaf == "span":
+                            prefixes = set()
+                        elif prefixes is None or len(prefixes) > 1:
                             prefixes = module_prefixes or set()
                         for pref in prefixes:
                             record(pref + name, rp, node.lineno)

@@ -58,7 +58,7 @@ from actor_critic_algs_on_tensorflow_tpu.distributed.codec import (
     CodecError,
     CodedTrajectory,
 )
-from actor_critic_algs_on_tensorflow_tpu.utils import metric_names
+from actor_critic_algs_on_tensorflow_tpu.utils import metric_names, profiling
 from actor_critic_algs_on_tensorflow_tpu.utils.metrics import TimeSplit
 
 __all__ = [
@@ -337,12 +337,12 @@ class LearnerPipeline:
         post-decode hook runs once they land in the slot."""
         out = []
         for traj, ep in self._poll(n):
-            if (
-                self._validate is not None
-                and not isinstance(traj, CodedTrajectory)
-                and not self._validate(traj, ep)
+            if self._validate is not None and not isinstance(
+                traj, CodedTrajectory
             ):
-                continue
+                with profiling.span(profiling.VALIDATE_BATCH):
+                    if not self._validate(traj, ep):
+                        continue
             out.append((traj, ep))
         return out
 
@@ -354,12 +354,13 @@ class LearnerPipeline:
         pending: List[Tuple[Any, Any]] = []
         try:
             while not self._closed.is_set():
-                t0 = time.perf_counter()
-                while not pending:
-                    if self._closed.is_set():
-                        return
-                    pending.extend(self._filtered_poll(self._batch_parts))
-                self.split.add("queue_wait_s", time.perf_counter() - t0)
+                with self.split.span("queue_wait_s"):
+                    while not pending:
+                        if self._closed.is_set():
+                            return
+                        pending.extend(
+                            self._filtered_poll(self._batch_parts)
+                        )
 
                 first = pending[0][0]
                 use_arena = self._arena is not None and (
@@ -373,16 +374,15 @@ class LearnerPipeline:
                     item = self._assemble_arena(pending, slot)
                     slot = (slot + 1) % self._n_slots
                 else:
-                    t0 = time.perf_counter()
-                    while len(pending) < self._batch_parts:
-                        if self._closed.is_set():
-                            return
-                        pending.extend(
-                            self._filtered_poll(
-                                self._batch_parts - len(pending)
+                    with self.split.span("queue_wait_s"):
+                        while len(pending) < self._batch_parts:
+                            if self._closed.is_set():
+                                return
+                            pending.extend(
+                                self._filtered_poll(
+                                    self._batch_parts - len(pending)
+                                )
                             )
-                        )
-                    self.split.add("queue_wait_s", time.perf_counter() - t0)
                     parts = [t for t, _ in pending[: self._batch_parts]]
                     eps = [e for _, e in pending[: self._batch_parts]]
                     del pending[: self._batch_parts]
@@ -393,14 +393,13 @@ class LearnerPipeline:
                         {k: np.asarray(v) for k, v in ep.items()}
                         for ep in eps
                     ]
-                    t0 = time.perf_counter()
-                    if self._exec_lock is not None:
-                        with self._exec_lock:
+                    with self.split.span("assemble_s"):
+                        if self._exec_lock is not None:
+                            with self._exec_lock:
+                                batch = self._assemble_device(parts)
+                                jax.block_until_ready(batch)
+                        else:
                             batch = self._assemble_device(parts)
-                            jax.block_until_ready(batch)
-                    else:
-                        batch = self._assemble_device(parts)
-                    self.split.add("assemble_s", time.perf_counter() - t0)
                     item = (batch, eps_np, None)
                     del batch, parts, eps, eps_np
 
@@ -460,19 +459,18 @@ class LearnerPipeline:
         # Wait until this slot's previous batch fully retired: its
         # consumer step's token is device-ready (covers the transfer
         # too — the step read the transferred buffers).
-        t0 = time.perf_counter()
-        token = None
-        while not self._closed.is_set():
-            try:
-                token = self._tokens[slot].get(timeout=0.2)
-                break
-            except queue_lib.Empty:
-                continue
-        if self._closed.is_set():
-            raise _PipelineClosed()
-        if token is not None:
-            jax.block_until_ready(token)
-        self.split.add("slot_wait_s", time.perf_counter() - t0)
+        with self.split.span("slot_wait_s"):
+            token = None
+            while not self._closed.is_set():
+                try:
+                    token = self._tokens[slot].get(timeout=0.2)
+                    break
+                except queue_lib.Empty:
+                    continue
+            if self._closed.is_set():
+                raise _PipelineClosed()
+            if token is not None:
+                jax.block_until_ready(token)
 
         # Incremental fill: each polled item is placed (decoded or
         # strided-written) the moment it is available; a part whose
@@ -482,19 +480,17 @@ class LearnerPipeline:
         eps: List[Any] = []
         placed = 0
         while placed < self._batch_parts:
-            t0 = time.perf_counter()
-            while not pending:
-                if self._closed.is_set():
-                    raise _PipelineClosed()
-                pending.extend(
-                    self._filtered_poll(self._batch_parts - placed)
-                )
-            self.split.add("queue_wait_s", time.perf_counter() - t0)
+            with self.split.span("queue_wait_s"):
+                while not pending:
+                    if self._closed.is_set():
+                        raise _PipelineClosed()
+                    pending.extend(
+                        self._filtered_poll(self._batch_parts - placed)
+                    )
             traj, ep = pending.pop(0)
             if isinstance(traj, CodedTrajectory):
-                t0 = time.perf_counter()
-                tree = self._decode_into(slot, placed, traj)
-                self.split.add("decode_s", time.perf_counter() - t0)
+                with self.split.span("decode_s"):
+                    tree = self._decode_into(slot, placed, traj)
                 if tree is None:
                     continue
                 if self._validate_coded is not None and not (
@@ -505,11 +501,11 @@ class LearnerPipeline:
                     self.decode_rejects += 1
                     continue
             else:
-                t0 = time.perf_counter()
                 try:
-                    self._arena.write_part(
-                        slot, placed, jax.tree_util.tree_leaves(traj)
-                    )
+                    with self.split.span("assemble_s"):
+                        self._arena.write_part(
+                            slot, placed, jax.tree_util.tree_leaves(traj)
+                        )
                 except ValueError as e:
                     # Same fault envelope as the coded path: a plain
                     # frame whose layout does not match this learner's
@@ -521,32 +517,28 @@ class LearnerPipeline:
                         f"plain trajectory: {e}",
                         flush=True,
                     )
-                    self.split.add(
-                        "assemble_s", time.perf_counter() - t0
-                    )
                     continue
-                self.split.add("assemble_s", time.perf_counter() - t0)
             eps.append(ep)
             placed += 1
 
         eps_np = [
             {k: np.asarray(v) for k, v in ep.items()} for ep in eps
         ]
-        t0 = time.perf_counter()
-        if self._transfer is not None:
-            dev_leaves = self._transfer(self._arena.slot_leaves(slot))
-        else:
-            dev_leaves = [
-                jax.device_put(buf, s)
-                for buf, s in zip(
-                    self._arena.slot_leaves(slot), self._shardings
-                )
-            ]
-        # Block THIS thread (not the learner) until the host->device
-        # copies land — the transfer rides under the learner's compute,
-        # and once ready the slot's host memory is provably unread.
-        jax.block_until_ready(dev_leaves)
-        self.split.add("transfer_s", time.perf_counter() - t0)
+        with self.split.span("transfer_s"):
+            if self._transfer is not None:
+                dev_leaves = self._transfer(self._arena.slot_leaves(slot))
+            else:
+                dev_leaves = [
+                    jax.device_put(buf, s)
+                    for buf, s in zip(
+                        self._arena.slot_leaves(slot), self._shardings
+                    )
+                ]
+            # Block THIS thread (not the learner) until the host->device
+            # copies land — the transfer rides under the learner's
+            # compute, and once ready the slot's host memory is
+            # provably unread.
+            jax.block_until_ready(dev_leaves)
         batch = (
             jax.tree_util.tree_unflatten(self._treedef, dev_leaves)
             if self._wrap_batch
@@ -573,25 +565,24 @@ class LearnerPipeline:
         turns it into a loud ``ShardDesync``); plain consumers never
         pass it and keep the block-forever contract."""
         t0 = time.perf_counter()
-        while True:
-            if self._error is not None:
-                raise self._error
-            try:
-                item = self._ready.get(timeout=timeout)
-                self.split.add("stall_s", time.perf_counter() - t0)
-                return item
-            except queue_lib.Empty:
-                if stop is not None and stop.is_set():
-                    return None
-                if self._closed.is_set() and self._error is None:
-                    raise RuntimeError("pipeline closed while waiting")
-                if (
-                    max_wait_s is not None
-                    and time.perf_counter() - t0 > max_wait_s
-                ):
-                    raise TimeoutError(
-                        f"no batch staged within {max_wait_s:.1f}s"
-                    )
+        with self.split.span("stall_s"):
+            while True:
+                if self._error is not None:
+                    raise self._error
+                try:
+                    return self._ready.get(timeout=timeout)
+                except queue_lib.Empty:
+                    if stop is not None and stop.is_set():
+                        return None
+                    if self._closed.is_set() and self._error is None:
+                        raise RuntimeError("pipeline closed while waiting")
+                    if (
+                        max_wait_s is not None
+                        and time.perf_counter() - t0 > max_wait_s
+                    ):
+                        raise TimeoutError(
+                            f"no batch staged within {max_wait_s:.1f}s"
+                        )
 
     def mark_consumed(self, handle, token) -> None:
         """Release the arena slot behind ``handle`` once ``token`` (an
@@ -700,16 +691,15 @@ class DeviceRolloutSource:
     ):
         if stop is not None and stop.is_set():
             return None
-        t0 = time.perf_counter()
-        if self._env is None:
+        with self.split.span("collect_s"):
+            if self._env is None:
+                self._key, k = jax.random.split(self._key)
+                self._env = tuple(self._dispatch(self._reset, k))
             self._key, k = jax.random.split(self._key)
-            self._env = tuple(self._dispatch(self._reset, k))
-        self._key, k = jax.random.split(self._key)
-        env_state, obs, batch, ep = self._dispatch(
-            self._collect, self._params, self._env[0], self._env[1], k
-        )
-        self._env = (env_state, obs)
-        self.split.add("collect_s", time.perf_counter() - t0)
+            env_state, obs, batch, ep = self._dispatch(
+                self._collect, self._params, self._env[0], self._env[1], k
+            )
+            self._env = (env_state, obs)
         self.batches += 1
         return batch, [ep], None
 

@@ -218,11 +218,11 @@ class ReplayPipeline:
             if not self._try_issue():
                 time.sleep(self._poll_s)
                 continue
-            t0 = time.perf_counter()
             try:
-                sampled = self._group.sample(
-                    self._batch_size, self._beta
-                )
+                with self._ts.span("sample_wait_s"):
+                    sampled = self._group.sample(
+                        self._batch_size, self._beta
+                    )
             except Exception:
                 self._unissue()
                 if self._closed.is_set():
@@ -230,7 +230,6 @@ class ReplayPipeline:
                 self.reissues += 1
                 time.sleep(self._poll_s)
                 continue
-            self._ts.add("sample_wait_s", time.perf_counter() - t0)
             if sampled is None:
                 self._unissue()
                 time.sleep(self._poll_s)
@@ -243,11 +242,11 @@ class ReplayPipeline:
             if not self._try_issue():
                 time.sleep(self._poll_s)
                 continue
-            t0 = time.perf_counter()
             try:
-                sampled = self._group.sample_shard(
-                    shard_idx, self._batch_size, self._beta
-                )
+                with self._ts.span("sample_wait_s"):
+                    sampled = self._group.sample_shard(
+                        shard_idx, self._batch_size, self._beta
+                    )
             except (ConnectionError, OSError):
                 # Dead shard, or a deliberate interrupt (failover /
                 # takeover drain): drop the draw and reissue after the
@@ -259,7 +258,6 @@ class ReplayPipeline:
                 self.reissues += 1
                 time.sleep(self._poll_s)
                 continue
-            self._ts.add("sample_wait_s", time.perf_counter() - t0)
             if sampled is None:
                 self._unissue()         # refilling: no batch to consume
                 time.sleep(self._poll_s)
@@ -279,48 +277,45 @@ class ReplayPipeline:
         # check.py / bench subprocesses that never touch a device.
         import jax
 
-        t0 = time.perf_counter()
-        while True:
-            try:
-                slot, token = self._free.get(timeout=0.1)
-                break
-            except queue.Empty:
-                if self._closed.is_set():
-                    return False
-        if token is not None:
-            # The consuming update has this slot's buffers aliased
-            # (CPU zero-copy device_put): its retirement is the ONLY
-            # safe point to rewrite them.
-            jax.block_until_ready(token)
-        self._ts.add("slot_wait_s", time.perf_counter() - t0)
+        with self._ts.span("slot_wait_s"):
+            while True:
+                try:
+                    slot, token = self._free.get(timeout=0.1)
+                    break
+                except queue.Empty:
+                    if self._closed.is_set():
+                        return False
+            if token is not None:
+                # The consuming update has this slot's buffers aliased
+                # (CPU zero-copy device_put): its retirement is the
+                # ONLY safe point to rewrite them.
+                jax.block_until_ready(token)
 
         part = leaves + [np.asarray(sampled.weights, np.float32)]
-        t0 = time.perf_counter()
-        arena = self._arena
-        if arena is None:
-            with self._lock:
-                if self._arena is None:
-                    self._arena = HostArena(
-                        [0] * len(part), 1, self.depth + 1
-                    )
-                arena = self._arena
-        try:
-            arena.write_part(slot, 0, part)
-        except ValueError:
-            # Off-layout batch a caller-supplied validator did not
-            # catch (or none was given): the arena's first-layout-wins
-            # pin rejects it. The slot was never corrupted past this
-            # batch — recycle it.
-            self.rejects += 1
-            self._free.put((slot, None))
-            return False
-        host = arena.slot_leaves(slot)
-        self._ts.add("assemble_s", time.perf_counter() - t0)
+        with self._ts.span("assemble_s"):
+            arena = self._arena
+            if arena is None:
+                with self._lock:
+                    if self._arena is None:
+                        self._arena = HostArena(
+                            [0] * len(part), 1, self.depth + 1
+                        )
+                    arena = self._arena
+            try:
+                arena.write_part(slot, 0, part)
+            except ValueError:
+                # Off-layout batch a caller-supplied validator did not
+                # catch (or none was given): the arena's
+                # first-layout-wins pin rejects it. The slot was never
+                # corrupted past this batch — recycle it.
+                self.rejects += 1
+                self._free.put((slot, None))
+                return False
+            host = arena.slot_leaves(slot)
 
-        t0 = time.perf_counter()
-        dev = [jax.device_put(x, self._device) for x in host]
-        jax.block_until_ready(dev)
-        self._ts.add("transfer_s", time.perf_counter() - t0)
+        with self._ts.span("transfer_s"):
+            dev = [jax.device_put(x, self._device) for x in host]
+            jax.block_until_ready(dev)
 
         with self._lock:
             self._drawn += 1
@@ -340,14 +335,11 @@ class ReplayPipeline:
         check can never see the credit freed while the update it paid
         for is still uncounted (which would let one draw slip past
         the paced target and be discarded)."""
-        t0 = time.perf_counter()
-        try:
-            pb = self._ready.get(timeout=timeout)
-        except queue.Empty:
-            self._ts.add("stall_s", time.perf_counter() - t0)
-            return None
-        self._ts.add("stall_s", time.perf_counter() - t0)
-        return pb
+        with self._ts.span("stall_s"):
+            try:
+                return self._ready.get(timeout=timeout)
+            except queue.Empty:
+                return None
 
     def mark_consumed(self, pb: PrefetchedBatch, token: Any) -> None:
         """Release ``pb``'s issue credit and return its slot to the

@@ -104,6 +104,14 @@ class _FoldedConv(nn.Module):
         return y + bias.astype(self.dtype)
 
 
+def scale_pixels(x, dtype):
+    """Frames in ``dtype``: uint8 scaled to [0, 1] on the device (the
+    host->HBM transfer stays 1 byte/pixel), anything else cast."""
+    if x.dtype == jnp.uint8:
+        return x.astype(dtype) / 255.0
+    return x.astype(dtype)
+
+
 class NatureCNN(nn.Module):
     """Nature-DQN convolutional encoder for 84x84 stacked frames.
 
@@ -125,10 +133,7 @@ class NatureCNN(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        if x.dtype == jnp.uint8:
-            x = x.astype(self.dtype) / 255.0
-        else:
-            x = x.astype(self.dtype)
+        x = scale_pixels(x, self.dtype)
         batch_shape = x.shape[:-3]
         x = x.reshape((-1,) + x.shape[-3:])
         for i, (features, kernel, stride) in enumerate(

@@ -17,6 +17,7 @@ seconds, and the many sub-second programs around it add up.
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 REPO_CACHE_DIR = os.path.join(
@@ -43,3 +44,24 @@ def enable() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir
+
+
+@contextlib.contextmanager
+def metadata_in_key():
+    """Compile inside this to read a program's OWN metadata back.
+
+    JAX keys a cache entry without the program's metadata, so a cache
+    that holds the same computation from other source (before a
+    ``jax.named_scope`` was added, say) serves an executable whose
+    ``op_name``s are that source's. With the metadata in the key, what
+    ``compiled.as_text()`` shows was compiled from the caller's source.
+    """
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)

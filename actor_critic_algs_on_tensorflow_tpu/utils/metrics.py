@@ -8,6 +8,7 @@ array; host-side consumption converts to floats in one transfer.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, Mapping
@@ -27,7 +28,9 @@ class TimeSplit:
     iteration to a named bucket (queue-wait / assemble / transfer /
     compute); ``add(name, s)`` accumulates, ``window()`` returns the
     per-name seconds since the previous ``window()`` call (one window
-    per log interval), ``cumulative()`` returns lifetime totals. Keys
+    per log interval), ``cumulative()`` returns lifetime totals;
+    ``span(name)`` brackets a block (``add`` stays for a duration
+    computed some other way). Keys
     are emitted with ``prefix`` so they sort next to each other in the
     log stream and TensorBoard.
     """
@@ -41,6 +44,20 @@ class TimeSplit:
     def add(self, name: str, seconds: float) -> None:
         with self._lock:
             self._acc[name] = self._acc.get(name, 0.0) + seconds
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        """Time the block into ``name`` as ``add`` would, and show it
+        in the profiler's trace under the log-row key (``prefix`` +
+        ``name``): the column of the log and the span of the trace are
+        one name. Outside a profiling session the annotation is a flag
+        test."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(self._prefix + name):
+                yield
+        finally:
+            self.add(name, time.perf_counter() - t0)
 
     def cumulative(self) -> Dict[str, float]:
         with self._lock:

@@ -1,22 +1,65 @@
-"""Profiling and timing harnesses.
+"""The program's phases, named once, on both sides of the dispatch.
 
-Capability parity: the reference era's TensorBoard profiling and the
-env-steps/sec counters that define its headline metric (SURVEY.md §5
-"Tracing / profiling"; BASELINE.json:2). TPU-native mechanisms:
-``jax.profiler`` traces (viewable in Perfetto/XProf) around training
-iterations, and a wall-clock harness that separates compile time from
-steady-state throughput. Every timing window ends in
-``jax.block_until_ready``: dispatch is asynchronous, so a window
-without it times the enqueue.
+- ``trace(log_dir)``: one ``jax.profiler`` session (``train.py
+  --profile-dir``). Tracing is on exactly when such a session is; there
+  is no other switch.
+- The phase names below: ``jax.named_scope(<phase>)`` wraps the part of
+  a fused program that does that work (``algos/common.py``,
+  ``algos/ppo.py``, ``make_impala``). A scope is HLO metadata only —
+  every instruction's ``op_name`` carries the scopes it was traced
+  under — so the compiled program, its memory and its speed are those
+  of the unscoped build.
+- ``span(name)``: a host span in the profiler's trace
+  (``jax.profiler.TraceAnnotation``) for a wait that has no counter.
+  ``utils/metrics.py::TimeSplit.span`` is the same span under the
+  counter's log-row key, so a column of the log and a span of the
+  trace are one name. Outside a session a span is a flag test.
+- ``traced_steps(range)``: a run loop's iterations, each one step of
+  XProf's step view.
+- ``scope_table(hlo_text)``: which phases each instruction of a
+  compiled program belongs to, keyed the way the TPU trace names a
+  device operation, so a trace reader can join device time to phases
+  (``perfbench/rules/scope_time_share.py``).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
-from typing import Callable, Dict
+import dataclasses
+import re
+from typing import Dict, Tuple
 
 import jax
+
+# Device phases. Sites import the constant: a typo is an ImportError.
+ROLLOUT = "rollout"                # the whole rollout scan
+POLICY_ACT = "policy_act"          # in rollout: the acting forward pass
+ENV_STEP = "env_step"              # in rollout: dynamics, render, stack
+ADVANTAGE = "advantage"            # GAE / V-trace
+UPDATE = "update"                  # everything that trains
+MINIBATCH_PREP = "minibatch_prep"  # in update: slice, convert, relayout
+LOSS_GRAD = "loss_grad"            # in update: forward + backward
+OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
+PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
+          MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)
+
+# Host spans that have no TimeSplit counter behind them.
+ACTOR_ROLLOUT_DISPATCH = "actor_rollout_dispatch"
+ACTOR_QUEUE_PUT = "actor_queue_put"
+SENTINEL_CHECK = "sentinel_check"
+VALIDATE_BATCH = "validate_batch"
+PUBLISH_PARAMS = "publish_params"
+LOG_FETCH = "log_fetch"
+
+span = jax.profiler.TraceAnnotation
+
+
+def traced_steps(iterations):
+    """``for it in traced_steps(range(n))``: each pass of the loop's
+    body inside one ``StepTraceAnnotation("train", step_num=it)``."""
+    for it in iterations:
+        with jax.profiler.StepTraceAnnotation("train", step_num=it):
+            yield it
 
 
 @contextlib.contextmanager
@@ -32,47 +75,119 @@ def trace(log_dir: str):
         jax.profiler.stop_trace()
 
 
-def time_iteration(
-    step_fn: Callable,
-    state,
-    *,
-    warmup: int = 1,
-    iters: int = 10,
-) -> Dict[str, float]:
-    """Wall-clock a ``state -> (state, metrics)`` iteration function.
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?(%[\w.\-]+ = .*?)(?:, metadata=\{(.*?)\})?"
+    r"(?:, backend_config=.*)?$"
+)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)")
+_NAME = re.compile(r"%[\w.\-]+")
+# A transform wraps the scope under it: ``transpose(jvp(loss_grad))``.
+_TRANSFORMED = re.compile(r"^(?:\w+\()+([\w.\-]*)\)+$")
 
-    Returns compile time (first call), steady-state seconds/iteration,
-    and iterations/sec. The final state is NOT returned — use for
-    measurement only, on a disposable state.
+
+def phases_of(op_name: str) -> Tuple[str, ...]:
+    """The declared phases in one ``op_name``, outermost first. Where
+    the compiler merged instructions and joined their names with
+    ``;``, the first name speaks."""
+    found = []
+    for segment in op_name.split(";")[0].split("/"):
+        m = _TRANSFORMED.match(segment)
+        if m and not segment.startswith(("jit(", "pjit(")):
+            segment = m.group(1)
+        if segment in PHASES and segment not in found:
+            found.append(segment)
+    return tuple(found)
+
+
+def scope_table(hlo_text: str) -> Dict[str, Tuple[str, ...] | None]:
+    """Compiled HLO text -> ``{instruction: phases}``.
+
+    The key is the instruction's text up to ``, metadata=``, which is
+    the name the TPU trace gives the operation's events (there with
+    the operands' shapes, so print the text with them, or join on less
+    of it, where the two are to be joined).
+
+    An instruction the compiler made carries no ``op_name`` — a layout
+    copy, an async start/done pair, a fusion it assembled itself — and
+    on the chip those are 9-15 % of the device's time. Such a fusion
+    takes the deepest phase list among the instructions it fused; any
+    other takes the phases of the instruction it feeds (a copy exists
+    for its consumer), failing that of the one that feeds it. What is
+    still unnamed has no phase: ``()``. One text under two different
+    phase lists maps to ``None``: a reader must take that as not
+    found, never pick one.
     """
-    t0 = time.perf_counter()
-    state, metrics = step_fn(state)
-    jax.block_until_ready(metrics)
-    compile_s = time.perf_counter() - t0
-
-    for _ in range(max(0, warmup - 1)):
-        state, metrics = step_fn(state)
-    jax.block_until_ready(metrics)
-
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        state, metrics = step_fn(state)
-    jax.block_until_ready(metrics)
-    dt = time.perf_counter() - t0
-    return {
-        "compile_s": compile_s,
-        "sec_per_iter": dt / iters,
-        "iters_per_sec": iters / dt,
-    }
+    computations = _parse(hlo_text)
+    table: Dict[str, Tuple[str, ...] | None] = {}
+    for instructions in computations.values():
+        _inherit(instructions, computations)
+        for inst in instructions.values():
+            phases = inst.phases or ()
+            if table.setdefault(inst.key, phases) != phases:
+                table[inst.key] = None
+    return table
 
 
-def steps_per_sec(
-    step_fn: Callable,
-    state,
-    steps_per_iteration: int,
-    **kw,
-) -> float:
-    """Steady-state env-steps/sec of a fused training iteration —
-    the headline metric's harness (BASELINE.json:2)."""
-    t = time_iteration(step_fn, state, **kw)
-    return steps_per_iteration * t["iters_per_sec"]
+@dataclasses.dataclass
+class _Instruction:
+    key: str
+    phases: Tuple[str, ...] | None      # None: no op_name of its own
+    operands: Tuple[str, ...]
+    calls: Tuple[str, ...]
+
+
+def _parse(hlo_text: str) -> Dict[str, Dict[str, _Instruction]]:
+    """``{computation: {instruction name: _Instruction}}``."""
+    computations: Dict[str, Dict[str, _Instruction]] = {}
+    current: Dict[str, _Instruction] = {}
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = computations.setdefault(head.group(1), {})
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        key = m.group(1)
+        name, _, rest = key.partition(" = ")
+        op_name = _OP_NAME.search(m.group(2) or "")
+        calls = tuple(_CALLED.findall(rest))
+        current[name] = _Instruction(
+            key=key,
+            phases=phases_of(op_name.group(1)) if op_name else None,
+            operands=tuple(
+                n for n in _NAME.findall(rest) if n[1:] not in calls
+            ),
+            calls=calls,
+        )
+    return computations
+
+
+def _inherit(instructions, computations) -> None:
+    """Phases for one computation's instructions without ``op_name``."""
+    for inst in instructions.values():
+        if inst.phases is None and inst.calls:
+            fused = [
+                i.phases for c in inst.calls
+                for i in computations.get(c, {}).values() if i.phases
+            ]
+            if fused:
+                inst.phases = max(fused, key=len)
+    users: Dict[str, list] = {}
+    for name, inst in instructions.items():
+        for operand in inst.operands:
+            users.setdefault(operand, []).append(name)
+    for neighbours in (users.get, lambda n: instructions[n].operands):
+        changed = True
+        while changed:
+            changed = False
+            for name, inst in instructions.items():
+                if inst.phases is not None:
+                    continue
+                for other in neighbours(name) or ():
+                    near = instructions.get(other)
+                    if near is not None and near.phases is not None:
+                        inst.phases, changed = near.phases, True
+                        break

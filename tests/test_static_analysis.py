@@ -100,6 +100,7 @@ def _run_fixture(subdir: str, checker: str):
         ("jit", "jit"),
         ("lock", "lock"),
         ("drift", "drift"),
+        ("drift_span", "drift"),
         ("markers", "markers"),
     ],
 )
