@@ -28,6 +28,7 @@ from actor_critic_algs_on_tensorflow_tpu import envs as envs_lib
 from actor_critic_algs_on_tensorflow_tpu.algos import common
 from actor_critic_algs_on_tensorflow_tpu.data.rollout import (
     env_block_starts,
+    env_blocks,
     flatten_time_batch,
     frame_storage_context,
     gather_stacked_obs,
@@ -79,8 +80,12 @@ class PPOConfig:
     #            minibatch at 1024 envs in the r2 device trace).
     #   "env"  — contiguous env-sliced minibatches: each minibatch is
     #            ALL rollout steps of B/num_minibatches CONTIGUOUS
-    #            envs (a slice, no gather); only the block visit order
-    #            is drawn per epoch (data.rollout.env_block_starts).
+    #            envs; only the block visit order is drawn per epoch
+    #            (data.rollout.env_block_starts). No gather, and no
+    #            slice either: a slice of the env axis cost 28 % of the
+    #            ppo-breakout iteration on the TPU's tiled layouts, so
+    #            the rollout is arranged block-major once an iteration
+    #            (data.rollout.env_blocks) and a minibatch is an index.
     shuffle: str = "full"
     # Whole-batch epochs only (num_minibatches=1): accumulate the epoch
     # gradient over this many CONTIGUOUS rollout slices instead of one
@@ -326,15 +331,14 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 use_pallas=cfg.use_pallas_scan,
             )
 
-        batch = flatten_time_batch(
-            {
-                "actions": traj.actions,
-                "old_log_probs": traj.log_probs,
-                "old_values": traj.values,
-                "advantages": advantages,
-                "returns": returns,
-            }
-        )
+        time_major = {
+            "actions": traj.actions,
+            "old_log_probs": traj.log_probs,
+            "old_values": traj.values,
+            "advantages": advantages,
+            "returns": returns,
+        }
+        batch = flatten_time_batch(time_major)
         if cfg.compact_frames:
             extended, resets = frame_storage_context(
                 obs0, traj.obs, traj.dones, cfg.frame_stack
@@ -414,30 +418,35 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 mb["obs"] = minibatch_obs(idx)
             return minibatch_update(carry, mb)
 
-        # shuffle="env": minibatches are contiguous env blocks sliced
-        # straight out of the TIME-MAJOR [T, B] rollout arrays — no
-        # flatten-then-gather. The [T, b] -> [T*b] reshape below is
-        # contiguous in row-major layout, so XLA lowers the whole
-        # minibatch read to a strided slice, not data movement of the
-        # full buffer (the r2 device trace put the full-buffer shuffle
-        # gather + relayout at ~10 ms of every 41 ms minibatch).
+        # shuffle="env": a minibatch is every rollout step of one
+        # contiguous block of envs. Cut out of the TIME-MAJOR [T, B]
+        # arrays per minibatch, that block is not free on the TPU's
+        # tiled layouts: the envs sit in the lanes, [T, mb] -> [T*mb]
+        # is no bitcast there, and each minibatch's observations were
+        # written out in the compute dtype and re-laid for Conv_0, 28 %
+        # of the ppo-breakout iteration (PERF.md section 6, PR 26). So
+        # the arrays are arranged block-major ONCE an iteration; a
+        # minibatch is an index on the leading axis, and observations
+        # stay uint8 until the torso's own conversion in batch_grads.
         mb_envs = local_envs // cfg.num_minibatches
-
-        def env_block(x, start):
-            blk = jax.lax.dynamic_slice_in_dim(x, start, mb_envs, axis=1)
-            return blk.reshape((cfg.rollout_length * mb_envs,) + blk.shape[2:])
-
-        env_tb = {
-            "actions": traj.actions,
-            "old_log_probs": traj.log_probs,
-            "old_values": traj.values,
-            "advantages": advantages,
-            "returns": returns,
-        }
+        if env_sliced:
+            with jax.named_scope(profiling.UPDATE), jax.named_scope(
+                profiling.MINIBATCH_PREP
+            ):
+                env_major = jax.tree_util.tree_map(
+                    lambda x: env_blocks(x, cfg.num_minibatches),
+                    time_major if cfg.compact_frames
+                    else {**time_major, "obs": traj.obs},
+                )
 
         def env_minibatch_step(carry, start):
             with jax.named_scope(profiling.MINIBATCH_PREP):
-                mb = {k: env_block(v, start) for k, v in env_tb.items()}
+                mb = jax.tree_util.tree_map(
+                    lambda x: jax.lax.dynamic_index_in_dim(
+                        x, start // mb_envs, keepdims=False
+                    ),
+                    env_major,
+                )
                 if cfg.compact_frames:
                     idx = (
                         jnp.arange(cfg.rollout_length)[:, None] * local_envs
@@ -445,8 +454,6 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                         + jnp.arange(mb_envs)[None, :]
                     ).reshape(-1)
                     mb["obs"] = minibatch_obs(idx)
-                else:
-                    mb["obs"] = env_block(traj.obs, start)
             return minibatch_update(carry, mb)
 
         def accum_epoch_update(carry):

@@ -52,14 +52,29 @@ def env_block_starts(key: jax.Array, num_minibatches: int, block_envs: int):
     The gather-free minibatch schedule (``PPOConfig.shuffle="env"``):
     the env axis is partitioned into ``num_minibatches`` CONTIGUOUS
     blocks of ``block_envs`` envs — each minibatch is every rollout
-    step of one block, a plain slice instead of a full-buffer random
-    gather — and only the ORDER the blocks are visited in is drawn
-    per epoch. Env order is exchangeable (independent env instances),
-    so a fixed contiguous partition is as unbiased as a random one;
-    the permuted visit order still decorrelates the SGD sequence
-    across epochs. Returns ``[num_minibatches]`` int32 starts.
+    step of one block (block ``start // block_envs`` of ``env_blocks``)
+    instead of a full-buffer random gather — and only the ORDER the
+    blocks are visited in is drawn per epoch. Env order is
+    exchangeable (independent env instances), so a fixed contiguous
+    partition is as unbiased as a random one; the permuted visit order
+    still decorrelates the SGD sequence across epochs. Returns
+    ``[num_minibatches]`` int32 starts.
     """
     return jax.random.permutation(key, num_minibatches) * block_envs
+
+
+def env_blocks(x: jax.Array, num_blocks: int) -> jax.Array:
+    """``[T, B, ...] -> [num_blocks, T * B/num_blocks, ...]``, block-major.
+
+    Block ``i`` holds every rollout step of envs ``i*mb .. (i+1)*mb - 1``
+    (``mb = B / num_blocks``), its samples in the ``t``-major order of
+    ``x[:, i*mb:(i+1)*mb].reshape(T * mb, ...)``. An env-sliced
+    minibatch (``env_block_starts``) is then an index on the leading
+    axis; arranged once an iteration, no minibatch moves data of its own.
+    """
+    t, b = x.shape[:2]
+    x = x.reshape((t, num_blocks, b // num_blocks) + x.shape[2:])
+    return jnp.moveaxis(x, 1, 0).reshape((num_blocks, -1) + x.shape[3:])
 
 
 def frame_storage_context(obs0, frames, dones, num_stack: int):

@@ -225,6 +225,31 @@ def test_env_block_starts_is_a_permuted_partition():
     assert len(orders) > 1  # the visit order really is drawn per key
 
 
+@pytest.mark.parametrize("num_minibatches", [2, 4, 16])
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+@pytest.mark.parametrize("rank", [2, 5])
+def test_env_blocks_equal_the_per_minibatch_slice(rank, dtype, num_minibatches):
+    # Block i of the block-major arrangement is what the minibatch loop
+    # used to cut for itself: x[:, i*mb:(i+1)*mb] merged t-major.
+    from actor_critic_algs_on_tensorflow_tpu.data.rollout import env_blocks
+
+    t, b = 6, 32
+    shape = (t, b) + (5, 3, 4)[: rank - 2]
+    x = jnp.asarray(
+        np.random.default_rng(rank + num_minibatches)
+        .integers(0, 255, shape).astype(dtype)
+    )
+    mb = b // num_minibatches
+    blocks = env_blocks(x, num_minibatches)
+    assert blocks.dtype == x.dtype
+    assert blocks.shape == (num_minibatches, t * mb) + shape[2:]
+    for i in range(num_minibatches):
+        want = jax.lax.dynamic_slice_in_dim(x, i * mb, mb, axis=1).reshape(
+            (t * mb,) + shape[2:]
+        )
+        np.testing.assert_array_equal(np.asarray(blocks[i]), np.asarray(want))
+
+
 def test_ppo_shuffle_env_smoke_and_determinism():
     cfg = ppo.PPOConfig(
         num_envs=8, rollout_length=16, num_minibatches=4, shuffle="env",
@@ -247,18 +272,24 @@ def test_ppo_shuffle_env_smoke_and_determinism():
     assert run(0) != run(1)
 
 
-def test_ppo_shuffle_env_compact_frames_matches_full_storage():
+@pytest.mark.parametrize("num_epochs,num_minibatches", [(2, 4), (3, 2)])
+def test_ppo_shuffle_env_compact_frames_matches_full_storage(
+    num_epochs, num_minibatches
+):
     # The compact-frames leg of shuffle="env" rebuilds minibatch obs by
     # flat index (t*B + env); compact storage is exact, so the same
-    # seed must produce identical params with and without it.
+    # seed must produce identical params with and without it. The full
+    # leg indexes the block-major arrangement (data.rollout.env_blocks)
+    # from the same env_block_starts draw: a block taken out of order in
+    # any epoch shows as different params (2 blocks over 3 epochs too).
     kw = dict(
         env="PongTPU-v0",
         num_envs=8,
         rollout_length=16,
         frame_stack=4,
         torso="nature_cnn",
-        num_epochs=2,
-        num_minibatches=4,
+        num_epochs=num_epochs,
+        num_minibatches=num_minibatches,
         shuffle="env",
         time_limit_bootstrap=False,
         num_devices=1,

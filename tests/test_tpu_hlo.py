@@ -1,0 +1,134 @@
+"""What the TPU's compiler makes of the main path, read off the chip:
+the ``ppo-breakout`` iteration compiled for a described v5e at the
+preset's real size (nothing runs, ~13 s), and its instruction text.
+
+Keep every test that describes a topology in THIS file: the worker that
+describes one holds the TPU library until it exits, and a second file
+could land on another worker (``/opt/skills/guides/on-chip-measurement``
+section 2; ``perfbench/tests/test_families_tpu_hlo.py`` is the
+benchmark's own such file).
+"""
+
+import os
+import re
+
+import jax
+import pytest
+
+from actor_critic_algs_on_tensorflow_tpu.algos import common
+from actor_critic_algs_on_tensorflow_tpu.algos.ppo import PPOConfig, make_ppo
+from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep it out.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def iteration_text(monkeypatch, topo, preset):
+    """The preset's fused iteration on one described chip, as compiled
+    text. The program builds its mesh from ``jax.devices()``: hand it
+    the described chip while it does."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    real = jax.devices
+    monkeypatch.setattr(
+        jax, "devices",
+        lambda *a, **k: [topo.devices[0]] if not a else real(*a, **k),
+    )
+    _, base = PRESETS[preset]
+    fns = make_ppo(PPOConfig(**base, num_devices=1))
+    monkeypatch.undo()
+    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
+    args = jax.tree_util.tree_map(
+        lambda s, spec: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(fns.mesh, spec)
+        ),
+        state, common.state_specs(state),
+        is_leaf=lambda x: isinstance(x, PartitionSpec),
+    )
+    return fns.iteration.lower(args).compile().as_text()
+
+
+INSTRUCTION = re.compile(r"%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+# A view or a handle, no bytes moved.
+FREE = {"bitcast", "get-tuple-element", "tuple", "parameter"}
+# Under the update, in the epoch loop's body: the minibatch loop itself,
+# and what was traced inside its body.
+MINIBATCH_LOOP = re.compile(r"/update/while/body/.*while$")
+IN_MINIBATCH_LOOP = re.compile(r"/update/while/body/.*while/body")
+
+
+def unfused(text):
+    """``{computation: [(name, result, opcode, op_name, line)]}`` over
+    the instructions a trace can show (outside fused computations)."""
+    comps, current = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            current = comps.setdefault(head.group(1), [])
+        elif current is not None and line.startswith("  "):
+            m = INSTRUCTION.match(line.strip().removeprefix("ROOT "))
+            if m:
+                op_name = re.search(r'op_name="([^"]*)"', line)
+                current.append(
+                    m.groups() + (op_name.group(1) if op_name else "", line)
+                )
+    fused = set(re.findall(r"kind=\w+, calls=%?([\w.\-]+)", text))
+    return {k: v for k, v in comps.items() if k not in fused}
+
+
+def test_ppo_breakout_minibatch_loop_moves_no_observation(monkeypatch, topo):
+    # PR 26: the env-sliced update reads a block-major arrangement made
+    # once an iteration (data.rollout.env_blocks). Before it, each of
+    # the 64 minibatches wrote its observations out as bf16 and re-laid
+    # them for Conv_0 (convert_multiply_fusion.8 and copy.47, 28 % of
+    # the iteration on the chip: PERF.md section 6).
+    comps = unfused(iteration_text(monkeypatch, topo, "ppo-breakout"))
+    bodies = {
+        re.search(r"body=%?([\w.\-]+)", line).group(1)
+        for rows in comps.values()
+        for _, _, opcode, op_name, line in rows
+        if opcode == "while" and MINIBATCH_LOOP.search(op_name)
+    }
+    assert len(bodies) == 1, bodies
+    movers = [
+        (name, result)
+        for comp, rows in comps.items()
+        for name, result, opcode, op_name, _ in rows
+        if "84,84" in result and opcode not in FREE
+        and (comp in bodies or IN_MINIBATCH_LOOP.search(op_name))
+    ]
+    assert not movers, movers
+    # ... because Conv_0 converts for itself: its forward pass and its
+    # weight gradient read the uint8 arrangement in place.
+    (body,) = bodies
+    results = {name: result for name, result, *_ in comps[body]}
+    conv0_inputs = [
+        results[operand]
+        for _, _, opcode, op_name, line in comps[body]
+        if opcode == "fusion" and "kind=kOutput" in line
+        and "Conv_0/conv_general_dilated" in op_name
+        for operand in re.findall(
+            r"%([\w.\-]+)", line[line.index("fusion("):line.index("kind=")]
+        )
+        if "84,84" in results.get(operand, "")
+    ]
+    assert len(conv0_inputs) >= 2, conv0_inputs
+    assert all(r.startswith("u8[") for r in conv0_inputs), conv0_inputs
