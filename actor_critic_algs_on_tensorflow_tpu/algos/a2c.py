@@ -153,7 +153,7 @@ def make_a2c(cfg: A2CConfig) -> common.IterationFns:
                 model.initialize_carry(1),
             )
             carry = {
-                "lstm": model.initialize_carry(cfg.num_envs),
+                "core": model.initialize_carry(cfg.num_envs),
                 "prev_done": jnp.zeros((cfg.num_envs,), jnp.float32),
             }
         else:
@@ -244,16 +244,16 @@ def make_a2c(cfg: A2CConfig) -> common.IterationFns:
         it_key = prng.fold(state.key, state.step, dev)
 
         carry0 = state.carry
-        env_state, obs, carry1, traj, ep_info = (
+        env_state, obs, carry1, traj, ep_info, _ = (
             common.collect_rollout_recurrent(
                 env, env_params, seq_dist_value, state.params,
                 state.env_state, state.obs, carry0, it_key,
                 cfg.rollout_length,
             )
         )
-        _, last_value_tb, _ = seq_dist_value(
+        _, last_value_tb, _, _ = seq_dist_value(
             state.params, obs[None], carry1["prev_done"][None],
-            carry1["lstm"],
+            carry1["core"],
         )
         advantages, returns = gae_advantages(
             traj.rewards, traj.values, traj.dones, last_value_tb[0],
@@ -267,8 +267,8 @@ def make_a2c(cfg: A2CConfig) -> common.IterationFns:
         resets_tb = common.replay_resets(carry0["prev_done"], traj.dones)
 
         def loss_fn(params):
-            dist, values, _ = seq_dist_value(
-                params, traj.obs, resets_tb, carry0["lstm"]
+            dist, values, _, _ = seq_dist_value(
+                params, traj.obs, resets_tb, carry0["core"]
             )
             pg = policy_gradient_loss(
                 dist.log_prob(traj.actions), advantages

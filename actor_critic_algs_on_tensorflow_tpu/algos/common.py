@@ -61,9 +61,13 @@ class OnPolicyState:
     key: jax.Array
     step: jax.Array  # iteration counter; env steps = step * steps_per_iteration
     extra: Any = None
-    # Recurrent policies only: {"lstm": (c, h) each [B, lstm], "prev_done":
-    # [B]} — the policy state entering the NEXT rollout step (sharded on
-    # the env axis like obs). None for feed-forward policies.
+    # Recurrent policies only: {"core": <the core's own carry>,
+    # "prev_done": [B]} — the policy state entering the NEXT rollout
+    # step, every leaf with the env axis leading (sharded like obs). The
+    # core owns its carry's shape: an LSTM's (c, h) each [B, lstm], or
+    # per layer a DeltaNet state, a convolution's tail, a key/value
+    # cache and the position (models/qwen3_next.py). None for
+    # feed-forward policies.
     carry: Any = None
 
 
@@ -176,6 +180,8 @@ def make_obs_prep(torso, compute_dtype):
     only the phase they are traced under moves."""
     if torso in ("nature_cnn", "nature_cnn_s2d"):
         return lambda obs: scale_pixels(obs, jnp.dtype(compute_dtype))
+    # Every other torso converts its own input, and a token id
+    # (torso="qwen3_next") must reach the embedding as the integer it is.
     return lambda obs: obs
 
 
@@ -188,14 +194,27 @@ def make_recurrent_policy_head(
     compute_dtype,
     lstm_precompute_gates=False,
     lstm_unroll=1,
+    seq_model=None,
+    cache_len=0,
 ):
-    """(model, seq_dist_value) for a recurrent (LSTM) discrete policy.
+    """(model, seq_dist_value) for a recurrent discrete policy.
 
     ``seq_dist_value(params, obs_tb, resets_tb, carry)`` runs the
     time-major sequence forward: obs ``[T, B, ...]``, resets ``[T, B]``
-    (1.0 where step t begins a new episode), carry ``(c, h)``; returns
-    ``(Categorical over [T, B], values [T, B], new_carry)``. Single-step
+    (1.0 where step t begins a new episode), ``carry`` the core's own
+    pytree (``model.initialize_carry(B)``); returns ``(Categorical over
+    [T, B], values [T, B], new_carry, stats)``. Single-step
     collection/eval is the ``T == 1`` case of the same function.
+    ``stats`` are the core's counters of that call as scalars (the
+    expert layer's; ``{}`` for the LSTM).
+
+    The core is the LSTM over a torso (``RecurrentActorCritic``, carry
+    ``(c, h)``) or, with ``torso="qwen3_next"``, the model
+    ``seq_model`` describes (``models/qwen3_next.py``), whose carry
+    holds per layer a DeltaNet state or a key/value cache of
+    ``cache_len`` steps. ``model.replays_from_empty_carry`` says that
+    the sequence form (``T > 1``) starts every sequence from the empty
+    carry and reads neither ``carry`` nor ``resets``.
     """
     if not hasattr(action_space, "n"):
         raise ValueError(
@@ -203,6 +222,29 @@ def make_recurrent_policy_head(
             "(the continuous head is the MLP GaussianActorCritic); "
             "use recurrent=False for continuous-control envs"
         )
+    if torso == "qwen3_next":
+        from actor_critic_algs_on_tensorflow_tpu.models.qwen3_next import (
+            Qwen3NextActorCritic,
+        )
+
+        if seq_model is None or seq_model.vocab_size != action_space.n:
+            raise ValueError(
+                "torso='qwen3_next' needs seq_model (a Qwen3NextConfig) "
+                "whose vocab_size is the env's number of actions "
+                f"({action_space.n}); got {seq_model!r}"
+            )
+        model = Qwen3NextActorCritic(
+            cfg=seq_model, cache_len=cache_len,
+            dtype=jnp.dtype(compute_dtype),
+        )
+
+        def seq_dist_value(params, obs_tb, resets_tb, carry):
+            logits, values, carry, stats = model.apply(
+                params, obs_tb, resets_tb, carry
+            )
+            return Categorical(logits), values, carry, stats
+
+        return model, seq_dist_value
     model = RecurrentActorCritic(
         num_actions=action_space.n,
         torso=torso,
@@ -215,7 +257,7 @@ def make_recurrent_policy_head(
 
     def seq_dist_value(params, obs_tb, resets_tb, carry):
         logits, values, carry = model.apply(params, obs_tb, resets_tb, carry)
-        return Categorical(logits), values, carry
+        return Categorical(logits), values, carry, {}
 
     return model, seq_dist_value
 
@@ -235,23 +277,24 @@ def collect_rollout_recurrent(
 ):
     """Recurrent analog of :func:`collect_rollout`.
 
-    ``carry`` is the state's ``{"lstm": (c, h), "prev_done": [B]}``
+    ``carry`` is the state's ``{"core": ..., "prev_done": [B]}``
     policy-state bundle; each step feeds ``prev_done`` as the reset mask
-    (the LSTM state is zeroed inside the cell where an episode just
-    ended), calls the ``T == 1`` sequence forward, and threads the new
-    cell state. Returns ``(env_state, obs, carry, traj, ep_info)`` with
-    ``carry`` ready for the next rollout (and, unchanged in ``traj``,
-    everything the update needs to REPLAY the sequence: the caller keeps
-    the rollout-entry carry for that).
+    (the core zeroes its carry where an episode just ended), calls the
+    ``T == 1`` sequence forward, and threads the new carry. Returns
+    ``(env_state, obs, carry, traj, ep_info, stats)`` with ``carry``
+    ready for the next rollout (and, unchanged in ``traj``, everything
+    the update needs to REPLAY the sequence: the caller keeps the
+    rollout-entry carry for that) and ``stats`` the core's counters,
+    one row a step.
     """
     norm = norm if norm is not None else (lambda o: o)
 
     def _step(scan_carry, step_key):
-        env_state, obs, lstm, prev_done = scan_carry
+        env_state, obs, core, prev_done = scan_carry
         k_act, k_env = jax.random.split(step_key)
         with jax.named_scope(profiling.POLICY_ACT):
-            dist, value, lstm = seq_dist_value(
-                params, norm(obs)[None], prev_done[None], lstm
+            dist, value, core, stats = seq_dist_value(
+                params, norm(obs)[None], prev_done[None], core
             )
             action = dist.sample(k_act)[0]
             log_prob = dist.log_prob(action[None])[0]
@@ -272,19 +315,24 @@ def collect_rollout_recurrent(
             "done_episode": info["done_episode"],
             "terminated": info["terminated"],
         }
-        return (env_state, next_obs, lstm, done), (traj, ep_info)
+        return (env_state, next_obs, core, done), (traj, ep_info, stats)
 
     with jax.named_scope(profiling.ROLLOUT):
         keys = jax.random.split(key, length)
-        (env_state, obs, lstm, prev_done), (traj, ep_info) = jax.lax.scan(
-            _step, (env_state, obs, carry["lstm"], carry["prev_done"]), keys
+        (env_state, obs, core, prev_done), (traj, ep_info, stats) = (
+            jax.lax.scan(
+                _step,
+                (env_state, obs, carry["core"], carry["prev_done"]),
+                keys,
+            )
         )
     return (
         env_state,
         obs,
-        {"lstm": lstm, "prev_done": prev_done},
+        {"core": core, "prev_done": prev_done},
         traj,
         ep_info,
+        stats,
     )
 
 
@@ -489,12 +537,22 @@ def evaluate(
 
 
 class IterationFns(NamedTuple):
-    """A compiled training program: ``init`` and one fused iteration."""
+    """A compiled training program: ``init`` and one fused iteration.
+
+    ``collect`` and ``block_grads`` (recurrent PPO only, else None) are
+    the iteration's two halves as jitted programs of their own, built
+    from the closures the fused iteration traces: ``collect(state) ->
+    (traj, carry0)`` is the rollout alone, ``block_grads(params, block)
+    -> (loss, parts, grads)`` the loss and gradient of one env block
+    before the optimizer. They exist so that a check can read what the
+    timed path computes rather than a re-composition of it."""
 
     init: Callable[[jax.Array], OnPolicyState]
     iteration: Callable[[OnPolicyState], Tuple[OnPolicyState, Dict[str, jax.Array]]]
     mesh: Mesh
     steps_per_iteration: int
+    collect: Callable | None = None
+    block_grads: Callable | None = None
 
 
 def build_data_parallel_iteration(

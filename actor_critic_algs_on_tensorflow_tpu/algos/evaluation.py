@@ -49,6 +49,12 @@ def _act_fn(algo: str, cfg, aspace, params, stochastic: bool, norm=None,
     """
     norm = norm if norm is not None else (lambda o: o)
     act_state0 = None
+    if getattr(cfg, "torso", None) == "qwen3_next":
+        raise NotImplementedError(
+            "evaluation acts through RecurrentActorCritic's (c, h) carry; "
+            "acting with torso='qwen3_next' (its carry held per lane) is "
+            "not built yet (ROADMAP, Reach)"
+        )
     if algo in ("a2c", "ppo", "impala") and getattr(cfg, "recurrent", False):
         model = RecurrentActorCritic(
             num_actions=aspace.n,

@@ -870,14 +870,14 @@ def make_impala(cfg: ImpalaConfig):
             feed-forward policies; see collect_rollout_recurrent)."""
             if cfg.recurrent:
                 entry = carry
-                env_state, obs, carry, traj, ep_info = (
+                env_state, obs, carry, traj, ep_info, _ = (
                     common.collect_rollout_recurrent(
                         aenv, aparams, seq_dist_value,
                         params, env_state, obs, carry, key,
                         cfg.rollout_length,
                     )
                 )
-                entry_lstm, entry_prev_done = entry["lstm"], entry["prev_done"]
+                entry_lstm, entry_prev_done = entry["core"], entry["prev_done"]
             else:
                 env_state, obs, traj, ep_info = common.collect_rollout(
                     aenv, aparams, policy_fn,
@@ -909,7 +909,7 @@ def make_impala(cfg: ImpalaConfig):
             env_state, obs = aenv.reset(key, aparams)
             if cfg.recurrent:
                 carry = {
-                    "lstm": model.initialize_carry(cfg.envs_per_actor),
+                    "core": model.initialize_carry(cfg.envs_per_actor),
                     "prev_done": jnp.zeros(
                         (cfg.envs_per_actor,), jnp.float32
                     ),
@@ -957,12 +957,12 @@ def make_impala(cfg: ImpalaConfig):
             resets = common.replay_resets(
                 batch.entry_prev_done, batch.dones
             )
-            dist, values, carry_end = seq_dist_value(
+            dist, values, carry_end, _ = seq_dist_value(
                 params, obs, resets, batch.entry_lstm
             )
             # Bootstrap value of last_obs continues the sequence
             # from the replayed end-of-rollout carry.
-            _, last_value_tb, _ = seq_dist_value(
+            _, last_value_tb, _, _ = seq_dist_value(
                 params, last_obs[None], batch.dones[-1][None],
                 carry_end,
             )

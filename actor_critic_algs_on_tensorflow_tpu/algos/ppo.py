@@ -18,15 +18,17 @@ equivalent to large-batch PPO with num_envs spread over devices.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import PartitionSpec as P
 
 from actor_critic_algs_on_tensorflow_tpu import envs as envs_lib
 from actor_critic_algs_on_tensorflow_tpu.algos import common
 from actor_critic_algs_on_tensorflow_tpu.data.rollout import (
+    Trajectory,
     env_block_starts,
     env_blocks,
     flatten_time_batch,
@@ -49,6 +51,8 @@ from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
     device_count,
     make_mesh,
     put_by_specs,
+    shard_batch_specs,
+    shard_map,
 )
 from actor_critic_algs_on_tensorflow_tpu.utils import prng, profiling
 
@@ -60,7 +64,7 @@ class PPOConfig:
     rollout_length: int = 128
     total_env_steps: int = 500_000
     frame_stack: int = 0
-    torso: str = "mlp"              # "mlp" | "nature_cnn"
+    torso: str = "mlp"              # "mlp" | "nature_cnn" | "qwen3_next"
     hidden_sizes: Tuple[int, ...] = (64, 64)
     lr: float = 2.5e-4
     lr_decay: bool = True
@@ -112,6 +116,19 @@ class PPOConfig:
     # throughput".
     lstm_precompute_gates: bool = False
     lstm_unroll: int = 1
+    # torso="qwen3_next" (recurrent only): the sequence-policy core of
+    # models/qwen3_next.py in place of torso + LSTM. ``seq_model`` is
+    # its Qwen3NextConfig — every width, the share of experts and
+    # vocabulary held here, the dispatch buffer's factor
+    # (``--set seq_model.capacity_factor=...``). One episode is one
+    # rollout: the env's ``episode_length`` must equal
+    # ``rollout_length``, so that every sequence the update replays
+    # starts from an empty carry.
+    seq_model: Any = None
+    # The env's params in place of its defaults (a dataclass of the
+    # env's own, e.g. envs.TokenRecallParams; ``--set
+    # env_params.delay=...``). None: the defaults.
+    env_params: Any = None
     # Running mean/std observation normalization (vector obs only) —
     # the VecNormalize-style statistics live in state.extra, frozen
     # within an iteration so update-time log-probs match collection.
@@ -190,14 +207,31 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 "recurrent PPO requires time_limit_bootstrap=False "
                 "(V(final_obs) would need the per-step carry)"
             )
+    if cfg.torso == "qwen3_next" and not cfg.recurrent:
+        raise ValueError(
+            "torso='qwen3_next' is a sequence-policy core: it needs "
+            "recurrent=True (its layers carry state across steps)"
+        )
     common.check_host_env_topology(cfg.env, n_dev)
     env, env_params = envs_lib.make(
-        cfg.env, num_envs=local_envs, frame_stack=cfg.frame_stack
+        cfg.env, num_envs=local_envs, frame_stack=cfg.frame_stack,
+        params=cfg.env_params,
     )
     genv, _ = envs_lib.make(
-        cfg.env, num_envs=cfg.num_envs, frame_stack=cfg.frame_stack
+        cfg.env, num_envs=cfg.num_envs, frame_stack=cfg.frame_stack,
+        params=cfg.env_params,
     )
     action_space = env.action_space(env_params)
+    if cfg.torso == "qwen3_next":
+        episode_length = getattr(env_params, "episode_length", None)
+        if episode_length != cfg.rollout_length:
+            raise ValueError(
+                "torso='qwen3_next' replays every sequence from an empty "
+                "carry, so one episode must be one rollout: the env's "
+                f"episode_length ({episode_length}) must equal "
+                f"rollout_length ({cfg.rollout_length}); resets inside a "
+                "sequence (packed episodes) are not supported"
+            )
     if cfg.recurrent:
         model, seq_dist_value = common.make_recurrent_policy_head(
             action_space,
@@ -207,8 +241,15 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             compute_dtype=cfg.compute_dtype,
             lstm_precompute_gates=cfg.lstm_precompute_gates,
             lstm_unroll=cfg.lstm_unroll,
+            seq_model=cfg.seq_model,
+            cache_len=cfg.rollout_length,
         )
         dist_and_value = None
+        # A core whose sequence form starts from the empty carry needs
+        # no rollout-entry carry in the update.
+        replay_carry = not getattr(
+            model, "replays_from_empty_carry", False
+        )
     else:
         model, dist_and_value = common.make_policy_head(
             action_space,
@@ -252,7 +293,7 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 model.initialize_carry(1),
             )
             carry = {
-                "lstm": model.initialize_carry(cfg.num_envs),
+                "core": model.initialize_carry(cfg.num_envs),
                 "prev_done": jnp.zeros((cfg.num_envs,), jnp.float32),
             }
         else:
@@ -557,35 +598,98 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         )
         return new_state, metrics
 
+    # ---- the recurrent path: its pieces, traced by the fused iteration
+    # and by the two entry points alike --------------------------------
+
+    def iteration_keys(state):
+        dev = jax.lax.axis_index(DATA_AXIS)
+        return jax.random.split(prng.fold(state.key, state.step, dev))
+
+    def obs_norm(state):
+        if cfg.normalize_obs:
+            rms = state.extra
+            return lambda o: rms_normalize(o, rms)
+        return lambda o: o
+
+    def rollout_recurrent(state, k_roll, norm):
+        return common.collect_rollout_recurrent(
+            env, env_params, seq_dist_value, state.params,
+            state.env_state, state.obs, state.carry, k_roll,
+            cfg.rollout_length, norm=norm,
+        )
+
+    def block_loss_grads(params, block, norm):
+        """The PPO loss and its gradient on one whole-trajectory block,
+        before the optimizer: obs/env fields [T, b], resets [T, b],
+        the core's rollout-entry carry [b, ...] (None where the core
+        replays from the empty carry). Advantages are whitened over
+        the block. Returns ``(grads, metrics, the core's counters)``."""
+        adv = block["advantages"].reshape(-1)
+        if cfg.normalize_adv:
+            with jax.named_scope(profiling.ADVANTAGE):
+                adv = common.global_normalize_advantages(adv)
+
+        with jax.named_scope(profiling.MINIBATCH_PREP):
+            obs = prep_obs(block["obs"])
+
+        def loss_fn(p):
+            dist, values_tb, _, core_stats = seq_dist_value(
+                p, norm(obs), block["resets"], block["core"]
+            )
+            with jax.named_scope(profiling.LM_HEAD):
+                log_probs = dist.log_prob(block["actions"]).reshape(-1)
+                ent = dist.entropy().mean()
+            clip = ppo_clip_loss(
+                log_probs,
+                block["old_log_probs"].reshape(-1),
+                adv,
+                clip_eps=cfg.clip_eps,
+            )
+            values = values_tb.reshape(-1)
+            if cfg.vf_clip:
+                vf = clipped_value_loss(
+                    values, block["old_values"].reshape(-1),
+                    block["returns"].reshape(-1), clip_eps=cfg.clip_eps,
+                )
+            else:
+                vf = value_loss(values, block["returns"].reshape(-1))
+            total = (
+                clip.policy_loss + cfg.vf_coef * vf - cfg.ent_coef * ent
+            )
+            return total, (clip, vf, ent, core_stats)
+
+        with jax.named_scope(profiling.LOSS_GRAD):
+            (loss, (clip, vf, ent, core_stats)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(params)
+        m = {
+            "loss": loss,
+            "policy_loss": clip.policy_loss,
+            "value_loss": vf,
+            "entropy": ent,
+            "clip_fraction": clip.clip_fraction,
+            "approx_kl": clip.approx_kl,
+        }
+        return grads, m, core_stats
+
     def local_iteration_recurrent(state: common.OnPolicyState):
         """Recurrent PPO iteration: same rollout -> GAE -> epochs shape,
-        but the policy forward is the time-major LSTM sequence and every
+        but the policy forward is the time-major sequence pass and every
         minibatch is a whole-trajectory env block replayed from the
         rollout-entry carry (truncated BPTT over the rollout window;
         the stored carry goes stale across epochs as params move — the
         standard recurrent-PPO approximation)."""
-        dev = jax.lax.axis_index(DATA_AXIS)
-        it_key = prng.fold(state.key, state.step, dev)
-        k_roll, k_perm = jax.random.split(it_key)
-
-        if cfg.normalize_obs:
-            rms = state.extra
-            norm = lambda o: rms_normalize(o, rms)
-        else:
-            norm = lambda o: o
+        k_roll, k_perm = iteration_keys(state)
+        norm = obs_norm(state)
 
         carry0 = state.carry
-        env_state, obs, carry1, traj, ep_info = (
-            common.collect_rollout_recurrent(
-                env, env_params, seq_dist_value, state.params,
-                state.env_state, state.obs, carry0, k_roll,
-                cfg.rollout_length, norm=norm,
-            )
+        env_state, obs, carry1, traj, ep_info, rollout_stats = (
+            rollout_recurrent(state, k_roll, norm)
         )
         with jax.named_scope(profiling.ADVANTAGE):
-            _, last_value_tb, _ = seq_dist_value(
+            _, last_value_tb, _, _ = seq_dist_value(
                 state.params, norm(obs)[None], carry1["prev_done"][None],
-                carry1["lstm"],
+                carry1["core"],
             )
             advantages, returns = gae_advantages(
                 traj.rewards, traj.values, traj.dones, last_value_tb[0],
@@ -596,6 +700,7 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             )
 
         resets_tb = common.replay_resets(carry0["prev_done"], traj.dones)
+        entry_core = carry0["core"] if replay_carry else None
         env_tb = {
             "actions": traj.actions,
             "old_log_probs": traj.log_probs,
@@ -605,58 +710,14 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         }
 
         def seq_update(carry_po, block):
-            """One optimizer step on a whole-trajectory block: obs/env
-            fields [T, b], resets [T, b], lstm carry (c, h) [b, H]."""
+            """One optimizer step on a whole-trajectory block."""
             params, opt_state = carry_po
-            adv = block["advantages"].reshape(-1)
-            if cfg.normalize_adv:
-                with jax.named_scope(profiling.ADVANTAGE):
-                    adv = common.global_normalize_advantages(adv)
-
-            with jax.named_scope(profiling.MINIBATCH_PREP):
-                obs = prep_obs(block["obs"])
-
-            def loss_fn(p):
-                dist, values_tb, _ = seq_dist_value(
-                    p, norm(obs), block["resets"], block["lstm"]
-                )
-                stats = ppo_clip_loss(
-                    dist.log_prob(block["actions"]).reshape(-1),
-                    block["old_log_probs"].reshape(-1),
-                    adv,
-                    clip_eps=cfg.clip_eps,
-                )
-                values = values_tb.reshape(-1)
-                if cfg.vf_clip:
-                    vf = clipped_value_loss(
-                        values, block["old_values"].reshape(-1),
-                        block["returns"].reshape(-1), clip_eps=cfg.clip_eps,
-                    )
-                else:
-                    vf = value_loss(values, block["returns"].reshape(-1))
-                ent = dist.entropy().mean()
-                total = (
-                    stats.policy_loss + cfg.vf_coef * vf - cfg.ent_coef * ent
-                )
-                return total, (stats, vf, ent)
-
-            with jax.named_scope(profiling.LOSS_GRAD):
-                (loss, (stats, vf, ent)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(params)
+            grads, m, stats = block_loss_grads(params, block, norm)
             with jax.named_scope(profiling.OPTIMIZER):
                 grads = jax.lax.pmean(grads, DATA_AXIS)
                 updates, opt_state = tx.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
-            m = {
-                "loss": loss,
-                "policy_loss": stats.policy_loss,
-                "value_loss": vf,
-                "entropy": ent,
-                "clip_fraction": stats.clip_fraction,
-                "approx_kl": stats.approx_kl,
-            }
-            return (params, opt_state), m
+            return (params, opt_state), (m, stats)
 
         mb_envs = local_envs // cfg.num_minibatches
 
@@ -668,30 +729,35 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 block = {k: cut(v, 1) for k, v in env_tb.items()}
                 block["obs"] = cut(traj.obs, 1)
                 block["resets"] = cut(resets_tb, 1)
-                block["lstm"] = jax.tree_util.tree_map(
-                    lambda x: cut(x, 0), carry0["lstm"]
+                block["core"] = jax.tree_util.tree_map(
+                    lambda x: cut(x, 0), entry_core
                 )
             return seq_update(carry_po, block)
 
         def epoch_step(carry_po, k):
             if cfg.num_minibatches == 1:
                 block = dict(
-                    env_tb, obs=traj.obs, resets=resets_tb,
-                    lstm=carry0["lstm"],
+                    env_tb, obs=traj.obs, resets=resets_tb, core=entry_core,
                 )
-                carry_po, m = seq_update(carry_po, block)
-                return carry_po, jax.tree_util.tree_map(lambda x: x[None], m)
+                carry_po, out = seq_update(carry_po, block)
+                return carry_po, jax.tree_util.tree_map(
+                    lambda x: x[None], out
+                )
             starts = env_block_starts(k, cfg.num_minibatches, mb_envs)
             return jax.lax.scan(env_block_update, carry_po, starts)
 
         with jax.named_scope(profiling.UPDATE):
             epoch_keys = jax.random.split(k_perm, cfg.num_epochs)
-            (params, opt_state), m = jax.lax.scan(
+            (params, opt_state), (m, update_stats) = jax.lax.scan(
                 epoch_step, (state.params, state.opt_state), epoch_keys
             )
         metrics = jax.lax.pmean(
             jax.tree_util.tree_map(jnp.mean, m), DATA_AXIS
         )
+        if update_stats:  # the core's own counters, reduced by the core
+            metrics.update(
+                model.iteration_stats(rollout_stats, update_stats, DATA_AXIS)
+            )
         metrics.update(
             common.guard_metrics(cfg.numerics_guards, (m["loss"], params))
         )
@@ -714,6 +780,47 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         ), metrics
 
     example = jax.eval_shape(init, jax.random.PRNGKey(0))
+    specs = common.state_specs(example)
+
+    def local_collect(state: common.OnPolicyState):
+        """The rollout the next iteration would collect, alone."""
+        k_roll, _ = iteration_keys(state)
+        traj = rollout_recurrent(state, k_roll, obs_norm(state))[3]
+        return traj, state.carry
+
+    def local_block_grads(params, block):
+        grads, m, _ = block_loss_grads(params, block, lambda o: o)
+        parts = {k: m[k] for k in ("policy_loss", "value_loss", "entropy")}
+        return jax.lax.pmean((m["loss"], parts, grads), DATA_AXIS)
+
+    def block_grads(params, block):
+        """``(loss, parts, grads)`` of one env block: fields ``[T, b,
+        ...]`` as ``seq_update`` takes them (``obs``, ``actions``,
+        ``old_log_probs``, ``old_values``, ``advantages``, ``returns``,
+        ``resets``) and ``core``, the entry carry ``[b, ...]`` or None."""
+        block_specs = {
+            k: jax.tree_util.tree_map(
+                lambda _: P(DATA_AXIS) if k == "core" else P(None, DATA_AXIS),
+                v,
+            )
+            for k, v in block.items()
+        }
+        return shard_map(
+            local_block_grads, mesh=mesh, in_specs=(P(), block_specs),
+            out_specs=P(),
+        )(params, block)
+
+    entry_points = {}
+    if cfg.recurrent and not cfg.normalize_obs:
+        by_env = P(None, DATA_AXIS)
+        entry_points = dict(
+            collect=jax.jit(shard_map(
+                local_collect, mesh=mesh, in_specs=(specs,),
+                out_specs=(Trajectory(*[by_env] * 6),
+                           shard_batch_specs(example.carry)),
+            )),
+            block_grads=jax.jit(block_grads),
+        )
     iteration = common.build_data_parallel_iteration(
         local_iteration_recurrent if cfg.recurrent else local_iteration,
         example, mesh,
@@ -723,4 +830,5 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         iteration=iteration,
         mesh=mesh,
         steps_per_iteration=cfg.num_envs * cfg.rollout_length,
+        **entry_points,
     )

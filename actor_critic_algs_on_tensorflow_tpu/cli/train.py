@@ -73,6 +73,43 @@ _PPO_ATARI_SCHEDULE = {
     "compute_dtype": "bfloat16",
 }
 
+def _qwen3_next_config(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.models.qwen3_next import (
+        Qwen3NextConfig,
+    )
+
+    return Qwen3NextConfig(**kw)
+
+
+def _token_recall_params(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.envs.token_recall import (
+        TokenRecallParams,
+    )
+
+    return TokenRecallParams(**kw)
+
+
+# Token-level PPO as RL fine-tuning runs it: one episode one sequence,
+# undiscounted return, no entropy bonus, whole sequences a minibatch.
+_PPO_TOKEN_SCHEDULE = {
+    "recurrent": True,
+    "time_limit_bootstrap": False,
+    "num_epochs": 1,
+    "num_minibatches": 4,
+    "shuffle": "env",
+    "lr": 1e-5,
+    "lr_decay": False,
+    "gamma": 1.0,
+    "gae_lambda": 0.95,
+    "clip_eps": 0.2,
+    "vf_clip": True,
+    "vf_coef": 0.5,
+    "ent_coef": 0.0,
+    "max_grad_norm": 1.0,
+    "normalize_adv": True,
+}
+
+
 PRESETS = {
     # 1. A2C on CartPole-v1: 2-layer MLP, sync actors (BASELINE.json:7)
     "a2c-cartpole": ("a2c", {"env": "CartPole-v1", "total_env_steps": 500_000}),
@@ -320,6 +357,65 @@ PRESETS = {
             "shuffle": "env",
             "lr": 1e-3,
             "lr_decay": True,
+        },
+    ),
+    # 13. Token-level PPO with a Qwen3-Next-80B-A3B policy at the
+    # published widths, cut to one chip's share of a stated deployment:
+    # each layer shared by 16 chips, expert-parallel (32 of the 512
+    # routed experts here, the whole router, shared expert and mixer),
+    # one whole period of the layer pattern (3 Gated DeltaNet + 1 gated
+    # attention of the 48 layers; the rest lie on further chips as
+    # pipeline stages) and 1/8 of the vocabulary: 625.7 M parameters,
+    # 10 GB with gradients and Adam's moments. One episode is one
+    # sequence of 256 tokens on the token-recall env; the schedule
+    # (envs, epochs, minibatches, learning rate) is
+    # perfbench/traffic/recall-128x256-e1mb4.json's.
+    "ppo-qwen3next-recall": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=18_992, delay=64, episode_length=256
+            ),
+            "torso": "qwen3_next",
+            "seq_model": _qwen3_next_config(
+                num_hidden_layers=4, vocab_size=18_992,
+                first_expert=0, experts_held=32, capacity_factor=2.0,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 128,
+            "rollout_length": 256,
+            "compute_dtype": "bfloat16",
+            "total_env_steps": 10_000_000,
+        },
+    ),
+    # The same model and schedule at widths for the CPU tests: hidden
+    # 64, 4 + 2 attention heads of 16, 2 + 4 DeltaNet heads of 16, 8
+    # experts top-2 of width 32 (2 held), 4 layers, vocabulary 64.
+    "ppo-qwen3next-tiny": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=64, delay=4, episode_length=16
+            ),
+            "torso": "qwen3_next",
+            "seq_model": _qwen3_next_config(
+                hidden_size=64, num_hidden_layers=4,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                linear_num_key_heads=2, linear_num_value_heads=4,
+                linear_key_head_dim=16, linear_value_head_dim=16,
+                num_experts=8, num_experts_per_tok=2,
+                moe_intermediate_size=32,
+                shared_expert_intermediate_size=32, vocab_size=64,
+                first_expert=0, experts_held=2, capacity_factor=4.0,
+                chunk_size=8,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 8,
+            "rollout_length": 16,
+            "total_env_steps": 4_096,
+            "num_devices": 1,
         },
     ),
     # 12. Continuous-control PPO (diagonal-Gaussian policy) on the

@@ -38,6 +38,10 @@ from actor_critic_algs_on_tensorflow_tpu.envs.synthetic import (  # noqa: F401
     SyntheticPixelsParams,
     SyntheticPixelsSmall,
 )
+from actor_critic_algs_on_tensorflow_tpu.envs.token_recall import (  # noqa: F401
+    TokenRecall,
+    TokenRecallParams,
+)
 from actor_critic_algs_on_tensorflow_tpu.envs.wrappers import (  # noqa: F401
     AutoReset,
     EpisodeStats,
@@ -57,6 +61,7 @@ _REGISTRY = {
     "ReacherTPU-v0": ReacherTPU,
     "SyntheticPixels-v0": SyntheticPixels,
     "SyntheticPixelsSmall-v0": SyntheticPixelsSmall,
+    "TokenRecallTPU-v0": TokenRecall,
 }
 
 def registered_names():

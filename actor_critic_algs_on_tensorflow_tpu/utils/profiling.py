@@ -40,6 +40,18 @@ UPDATE = "update"                  # everything that trains
 MINIBATCH_PREP = "minibatch_prep"  # in update: slice, convert, relayout
 LOSS_GRAD = "loss_grad"            # in update: forward + backward
 OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
+# Layers of the sequence-policy core (models/qwen3_next.py), inside
+# policy_act and loss_grad; read like the phases, listed apart.
+GDN = "gdn"                        # a Gated DeltaNet mixer
+GATED_ATTN = "gated_attn"          # a gated softmax-attention mixer
+MOE = "moe"                        # the whole expert block
+MOE_ROUTER = "moe_router"          # in moe: product, softmax, top-k
+MOE_DISPATCH = "moe_dispatch"      # in moe: sort, gather, combine
+MOE_EXPERTS = "moe_experts"        # in moe: the grouped products
+MOE_SHARED = "moe_shared"          # in moe: the shared expert
+LM_HEAD = "lm_head"                # final norm, logits, log-prob, entropy
+LAYER_SCOPES = (GDN, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
+                MOE_EXPERTS, MOE_SHARED, LM_HEAD)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)
 
@@ -96,7 +108,7 @@ def phases_of(op_name: str) -> Tuple[str, ...]:
         m = _TRANSFORMED.match(segment)
         if m and not segment.startswith(("jit(", "pjit(")):
             segment = m.group(1)
-        if segment in PHASES and segment not in found:
+        if segment in PHASES + LAYER_SCOPES and segment not in found:
             found.append(segment)
     return tuple(found)
 
@@ -109,9 +121,11 @@ def scope_table(hlo_text: str) -> Dict[str, Tuple[str, ...] | None]:
     the operands' shapes, so print the text with them, or join on less
     of it, where the two are to be joined).
 
-    An instruction the compiler made carries no ``op_name`` — a layout
-    copy, an async start/done pair, a fusion it assembled itself — and
-    on the chip those are 9-15 % of the device's time. Such a fusion
+    An instruction the compiler made carries no ``op_name`` (or, a
+    kernel it put in, one of its own coining with no traced function in
+    it) — a layout copy, an async start/done pair, a fusion it assembled
+    itself, a grouped-product custom call — and on the chip those are
+    9-15 % of the device's time. Such a fusion
     takes the deepest phase list among the instructions it fused; any
     other takes the phases of the instruction it feeds (a copy exists
     for its consumer), failing that of the one that feeds it. What is
@@ -154,9 +168,16 @@ def _parse(hlo_text: str) -> Dict[str, Dict[str, _Instruction]]:
         name, _, rest = key.partition(" = ")
         op_name = _OP_NAME.search(m.group(2) or "")
         calls = tuple(_CALLED.findall(rest))
+        # A kernel the compiler put in under a name of its own coining
+        # (the TPU's grouped products are all `ragged-dot-none`, 9.7 %
+        # of the Qwen3-Next iteration: PERF.md section 6, PR 27) is as
+        # good as unnamed, and inherits like the rest.
+        traced = op_name is not None and not (
+            " custom-call(" in rest and "jit(" not in op_name.group(1)
+        )
         current[name] = _Instruction(
             key=key,
-            phases=phases_of(op_name.group(1)) if op_name else None,
+            phases=phases_of(op_name.group(1)) if traced else None,
             operands=tuple(
                 n for n in _NAME.findall(rest) if n[1:] not in calls
             ),

@@ -348,3 +348,24 @@ def test_scoped_and_unscoped_builds_are_one_program(
     for k in scoped_metrics:
         np.testing.assert_array_equal(scoped_metrics[k], plain_metrics[k])
     assert _instructions(scoped_text) == _instructions(plain_text)
+
+
+def test_a_kernel_under_the_compilers_own_name_inherits_its_consumers_phases():
+    """The TPU's grouped-product kernels reach the compiled text as
+    ``op_name="ragged-dot-none"``: no traced function, so no scope of
+    the program's. They take the phases of what they feed."""
+    text = "\n".join([
+        "HloModule jit_f, is_scheduled=true",
+        "ENTRY %main (a: f32[8,4], w: f32[2,4,4]) -> f32[8,4] {",
+        '  %ragged-dot-none.1 = f32[8,4]{1,0} custom-call(f32[8,4]{1,0} %a, '
+        'f32[2,4,4]{2,1,0} %w), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}',
+        '  ROOT %mul.1 = f32[8,4]{1,0} multiply(f32[8,4]{1,0} '
+        '%ragged-dot-none.1, f32[8,4]{1,0} %a), '
+        'metadata={op_name="jit(f)/update/loss_grad/moe/moe_experts/mul"}',
+        "}",
+    ])
+    table = profiling.scope_table(text)
+    kernel = next(k for k in table if k.startswith("%ragged-dot-none.1"))
+    assert table[kernel] == (UPDATE, LOSS_GRAD, profiling.MOE,
+                             profiling.MOE_EXPERTS)

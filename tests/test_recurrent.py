@@ -177,10 +177,10 @@ def test_ppo_recurrent_carry_threads_across_iterations():
 
     fns = make_ppo(_ppo_cfg(num_epochs=1, num_minibatches=1))
     state = fns.init(jax.random.PRNGKey(0))
-    c0 = np.asarray(jax.device_get(state.carry["lstm"][1]))
+    c0 = np.asarray(jax.device_get(state.carry["core"][1]))
     assert (c0 == 0).all()
     state, _ = fns.iteration(state)
-    c1 = np.asarray(jax.device_get(state.carry["lstm"][1]))
+    c1 = np.asarray(jax.device_get(state.carry["core"][1]))
     assert np.abs(c1).max() > 0  # the carry advanced with the rollout
 
 
@@ -321,7 +321,7 @@ def test_impala_recurrent_carry_not_reset_between_rollouts():
         state.params, env_state, obs, carry, jax.random.PRNGKey(3)
     )
     np.testing.assert_allclose(
-        np.asarray(t2.entry_lstm[1]), np.asarray(carry["lstm"][1])
+        np.asarray(t2.entry_lstm[1]), np.asarray(carry["core"][1])
     )
     assert np.abs(np.asarray(t2.entry_lstm[1])).max() > 0.0
 

@@ -132,3 +132,77 @@ def test_ppo_breakout_minibatch_loop_moves_no_observation(monkeypatch, topo):
     ]
     assert len(conv0_inputs) >= 2, conv0_inputs
     assert all(r.startswith("u8[") for r in conv0_inputs), conv0_inputs
+
+
+# ---- the Qwen3-Next core's two new kernels at published widths (PR 27) ----
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_expert_layer_compiles_to_grouped_product_kernels(one_chip):
+    """One timed minibatch of the ``ppo-qwen3next-recall`` preset
+    through the expert layer, forward and backward, for the described
+    v5e: the grouped products are the TPU's own ragged-dot kernels (no
+    dense loop over the held experts), nine of them — gate, up, down,
+    each forward, input gradient and weight gradient — and the buffer
+    is the stated 2.0 x the expected local pairs."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import qwen3_next as qn
+
+    cfg = PRESETS["ppo-qwen3next-recall"][1]["seq_model"]
+    tokens = 32 * 256
+    assert cfg.moe_capacity(tokens) == 2 * tokens * 10 * 32 // 512 == 10240
+    spec = qn.layer_param_spec(cfg, 0)
+    names = ("router", "w_gate", "w_up", "w_down")
+    p = {n: jax.ShapeDtypeStruct(spec[n][0], jnp.float32, sharding=one_chip)
+         for n in names}
+    x = jax.ShapeDtypeStruct((tokens, cfg.hidden_size), jnp.float32,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        y, stats = qn.routed_experts(p, x, cfg, jnp.bfloat16)
+        return jnp.sum(y * y), stats
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        p, x
+    ).compile().as_text()
+    kernels = re.findall(r"%(ragged-dot-[\w\-]+\.?\d*) = (\S+) custom-call",
+                         text)
+    products = [shape for name, shape in kernels if "metadata" not in name]
+    assert len(products) == 9, kernels
+    assert sum(s.startswith("f32[32,") for s in products) == 3  # dW
+    assert sum(s.startswith("f32[10240,") for s in products) == 6
+
+
+def test_chunked_deltanet_compiles_at_the_timed_shape(one_chip):
+    """The chunked delta rule at a timed minibatch's shape (32 envs, 32
+    value heads, 256 steps, chunk 64), forward and backward: the
+    chip's compiler takes it, the scan over the four chunks stays a
+    loop, and the state it carries is float32."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import qwen3_next as qn
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        o, _ = qn.chunk_gated_delta_rule(q, k, v, g, beta, 64)
+        return jnp.sum(o * o)
+
+    qk, gb = arr(32, 32, 256, 128), arr(32, 32, 256)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, qk, gb, gb
+    ).compile()
+    text = compiled.as_text()
+    assert re.search(r" while\(", text)
+    assert "f32[32,32,128,128]" in text
+    assert "bf16[32,32,128,128]" not in text
+    # well inside the chip beside the weights (activations of one layer)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
