@@ -25,7 +25,13 @@ parameters:
   a key/value cache ``[B, cache_len, kv_heads, head_dim]`` and, for
   all, the position since the episode began. Where ``resets`` is set
   the state, the convolution's tail and the position are zeroed before
-  the step (a cache row beyond the position is never read).
+  the step (a cache row beyond the position is never read). The
+  state's update (``_state_step``) is one Pallas kernel where the
+  program is lowered for a TPU at widths that tile its vector unit
+  (``ops/pallas_delta_step.py``: the state read once and written once,
+  in place, the reset folded into the decay), and the plain
+  ``gated_delta_step`` everywhere else; nothing but the lowering
+  platform and the state's shape chooses.
 * ``T > 1`` — the sequence form, the teacher-forced pass: the chunked
   Gated DeltaNet and causal attention over the whole sequence from an
   EMPTY carry, position 0 at step 0. ``carry`` and ``resets`` are not
@@ -338,8 +344,11 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int):
 def gated_delta_step(S, q, k, v, g, beta):
     """One step of the recurrence on ``S [B, h, d_k, d_v]``: ``q, k [B,
     h, d_k]``, ``v [B, h, d_v]``, ``g, beta [B, h]``. Float32 on the
-    vector unit: two passes over the state (read for both
-    contractions, then read and write), no matrix product."""
+    vector unit, no matrix product. The plain form: what runs off the
+    TPU, what the tests differentiate, and the reference of the TPU's
+    kernel (``ops/pallas_delta_step.py``). Compiled as it stands it is
+    two passes over the state, a read for both contractions and then a
+    read and a write, three transfers where the kernel makes two."""
     S = S * jnp.exp(g)[..., None, None]
     s_k = jnp.sum(S * k[..., :, None], -2)
     s_q = jnp.sum(S * q[..., :, None], -2)
@@ -415,14 +424,40 @@ def gated_deltanet_seq(p, x, cfg, dtype):
     return _gdn_output(p, jnp.moveaxis(o, 2, 0), z, cfg, dtype)
 
 
-def gated_deltanet_step(p, x, state, cfg, dtype):
+def _state_step(S, q, k, v, g, beta, keep):
+    """``gated_delta_step`` on the state less the envs that start over
+    (``keep [B]``, 0 at a reset). Lowered for a TPU, at widths that
+    tile its vector unit, the one-pass kernel with the reset folded
+    into the decay; anywhere else the plain form."""
+    # Imported where it is used: Pallas is ~1.5 s of imports, and
+    # cli/train.py's PRESETS import this module for every preset.
+    from actor_critic_algs_on_tensorflow_tpu.ops import pallas_delta_step
+
+    def plain(S, q, k, v, g, beta, keep):
+        return gated_delta_step(
+            S * keep[:, None, None, None], q, k, v, g, beta
+        )
+
+    if not pallas_delta_step.fits(S):
+        return plain(S, q, k, v, g, beta, keep)
+    return jax.lax.platform_dependent(
+        S, q, k, v, g, beta, keep,
+        tpu=pallas_delta_step.gated_delta_step, default=plain,
+    )
+
+
+def gated_deltanet_step(p, x, state, keep, cfg, dtype):
     """``x [B, H]``; ``state`` ``{"S" [B, nv, d_k, d_v], "conv" [B, K -
-    1, C]}``."""
+    1, C]}``; ``keep [B]``, 0 where the env starts over: its state and
+    convolution history count as empty."""
     qkv, z, beta, g = _gdn_inputs(p, x, cfg, dtype)
-    window = jnp.concatenate([state["conv"], qkv[:, None]], 1)
+    window = jnp.concatenate(
+        [state["conv"] * keep[:, None, None], qkv[:, None]], 1
+    )
     qkv = jax.nn.silu(jnp.sum(window * p["conv"], 1))
     q, k, v = _gdn_heads(qkv, cfg)
-    S, o = gated_delta_step(state["S"], q, k, v, g, beta)
+    with jax.named_scope(profiling.GDN_STATE):
+        S, o = _state_step(state["S"], q, k, v, g, beta, keep)
     return (_gdn_output(p, o, z, cfg, dtype),
             {"S": S, "conv": window[:, 1:]})
 
@@ -572,14 +607,14 @@ def _decoder_layer_seq(p, x, cfg, dtype, attention: bool):
     return x + y.reshape(T, b, H), stats
 
 
-def _decoder_layer_step(p, x, state, pos, cfg, dtype, attention: bool):
+def _decoder_layer_step(p, x, state, pos, keep, cfg, dtype, attention: bool):
     h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
     if attention:
         with jax.named_scope(profiling.GATED_ATTN):
             y, state = gated_attention_step(p, h, state, pos, cfg, dtype)
     else:
         with jax.named_scope(profiling.GDN):
-            y, state = gated_deltanet_step(p, h, state, cfg, dtype)
+            y, state = gated_deltanet_step(p, h, state, keep, cfg, dtype)
     x = x + y
     with jax.named_scope(profiling.MOE):
         h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
@@ -624,17 +659,9 @@ class Qwen3NextActorCritic(nn.Module):
             pos = (carry["pos"] * keep).astype(jnp.int32)
             x, new_layers = x[0], []
             for i, p in enumerate(layers):
-                attention = cfg.is_attention(i)
-                state = carry["layers"][i]
-                if not attention:
-                    state = jax.tree_util.tree_map(
-                        lambda s: s * keep.reshape(
-                            (-1,) + (1,) * (s.ndim - 1)
-                        ).astype(s.dtype),
-                        state,
-                    )
                 x, state, stats = _decoder_layer_step(
-                    p, x, state, pos, cfg, dtype, attention
+                    p, x, carry["layers"][i], pos, keep, cfg, dtype,
+                    cfg.is_attention(i),
                 )
                 new_layers.append(state)
                 all_stats.append(stats)

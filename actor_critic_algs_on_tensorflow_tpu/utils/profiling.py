@@ -43,6 +43,7 @@ OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
 # Layers of the sequence-policy core (models/qwen3_next.py), inside
 # policy_act and loss_grad; read like the phases, listed apart.
 GDN = "gdn"                        # a Gated DeltaNet mixer
+GDN_STATE = "gdn_state"            # in gdn: the step form's state update
 GATED_ATTN = "gated_attn"          # a gated softmax-attention mixer
 MOE = "moe"                        # the whole expert block
 MOE_ROUTER = "moe_router"          # in moe: product, softmax, top-k
@@ -50,7 +51,7 @@ MOE_DISPATCH = "moe_dispatch"      # in moe: sort, gather, combine
 MOE_EXPERTS = "moe_experts"        # in moe: the grouped products
 MOE_SHARED = "moe_shared"          # in moe: the shared expert
 LM_HEAD = "lm_head"                # final norm, logits, log-prob, entropy
-LAYER_SCOPES = (GDN, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
+LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_SHARED, LM_HEAD)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)

@@ -44,3 +44,23 @@ def test_package_import_leaves_backend_uninitialized():
     )
     assert out.returncode == 0, out.stderr
     assert "LAZY_OK" in out.stdout, (out.stdout, out.stderr)
+
+
+def test_the_cli_import_does_not_pay_for_pallas():
+    """``cli/train.py``'s PRESETS import ``models/qwen3_next.py`` for
+    every preset, and a benchmark cell's ``setup_s`` counts the import:
+    the ~1.5 s of Pallas imports belong to the one program that runs
+    the kernel (``qwen3_next._state_step`` imports it where it is
+    used), not to each of them."""
+    probe = (
+        "import sys\n"
+        "import actor_critic_algs_on_tensorflow_tpu.cli.train\n"
+        "assert 'jax.experimental.pallas' not in sys.modules\n"
+        "print('NO_PALLAS')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", probe], capture_output=True, text=True,
+        timeout=180, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr
+    assert "NO_PALLAS" in out.stdout, (out.stdout, out.stderr)

@@ -7,6 +7,7 @@ entry points (``collect``, ``block_grads``).
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -26,6 +27,7 @@ from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (  # noqa: E402
 )
 from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.models import qwen3_next as qn  # noqa: E402
+from actor_critic_algs_on_tensorflow_tpu.ops import pallas_delta_step  # noqa: E402
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
 from perfbench.reference import qwen3_next as ref  # noqa: E402
 
@@ -137,6 +139,19 @@ def test_each_step_below_the_stated_precision_is_another_function(lower):
 # 2. the step form through the carry ---------------------------------------
 
 
+@pytest.fixture(params=["plain", "kernel"])
+def state_step(request, monkeypatch):
+    """The step form's state update as the CPU runs it, and once more
+    through the TPU's one-pass kernel in the Pallas interpreter (what
+    ``qn._state_step`` picks where the program is lowered for a TPU at
+    the published widths)."""
+    if request.param == "kernel":
+        monkeypatch.setattr(qn, "_state_step", functools.partial(
+            pallas_delta_step.gated_delta_step, interpret=True
+        ))
+    return request.param
+
+
 def _stepwise(model, params, tokens, resets):
     carry = model.initialize_carry(tokens.shape[1])
     step = jax.jit(model.apply)
@@ -150,7 +165,7 @@ def _stepwise(model, params, tokens, resets):
     return jnp.stack(logits), jnp.stack(values), carry
 
 
-def test_stepping_through_the_carry_equals_the_sequence_pass():
+def test_stepping_through_the_carry_equals_the_sequence_pass(state_step):
     """DeltaNet state, convolution tail, key/value cache and rotary
     position, over two chunks and a ragged tail."""
     T, B = 2 * CHUNK + 3, 3
@@ -167,7 +182,7 @@ def test_stepping_through_the_carry_equals_the_sequence_pass():
     assert np.asarray(carry["pos"]).tolist() == [T] * B
 
 
-def test_a_reset_mid_way_is_a_fresh_start():
+def test_a_reset_mid_way_is_a_fresh_start(state_step):
     T, B, cut = 2 * CHUNK + 3, 3, CHUNK + 2
     model = _model()
     params, tokens = _init(model), _tokens(T, B)

@@ -206,3 +206,37 @@ def test_chunked_deltanet_compiles_at_the_timed_shape(one_chip):
     assert "bf16[32,32,128,128]" not in text
     # well inside the chip beside the weights (activations of one layer)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+def test_the_decode_step_is_the_one_pass_kernel_in_place(one_chip):
+    """The step form's state update at the timed shape (128 envs, 32
+    value heads of 128 x 128), lowered for the described v5e through
+    the model's own choice (``qn._state_step``): the Pallas kernel is
+    what the TPU gets (no conditional left, no plain reduce pass), the
+    donated state is its output's buffer, and beside the state the
+    program holds no second copy of it."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import qwen3_next as qn
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    B, h, d = 128, 32, 128
+    compiled = jax.jit(qn._state_step, donate_argnums=0).lower(
+        arr(B, h, d, d), arr(B, h, d), arr(B, h, d), arr(B, h, d),
+        arr(B, h), arr(B, h), arr(B),
+    ).compile()
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%(gdn_state_step[\w.]*) = .*custom_call_target=\"tpu_custom_call\"",
+        text,
+    )
+    assert len(kernels) == 1, kernels
+    assert "output_to_operand_aliasing={{0}: (1, {})}" in text
+    assert " conditional(" not in text
+    assert not re.search(r"f32\[128,32,128,128\]\S* (fusion|copy)\(", text)
+    memory = compiled.memory_analysis()
+    state = B * h * d * d * 4
+    assert memory.alias_size_in_bytes == state
+    assert memory.temp_size_in_bytes < state // 16
