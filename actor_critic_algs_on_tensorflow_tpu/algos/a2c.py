@@ -62,21 +62,11 @@ class A2CConfig:
     # off (V(final_obs) would need the per-step carry).
     recurrent: bool = False
     lstm_size: int = 128
-    # Fused LSTM update path: hoist the input-side gate projection out
-    # of the time scan into one batched MXU matmul (identical numerics
-    # and param tree; see models._FusedMaskedLSTM) and unroll the scan
-    # by this factor. Measured on flicker-pong in PERF.md "Recurrent
-    # throughput".
-    lstm_precompute_gates: bool = False
-    lstm_unroll: int = 1
     # Bootstrap truncated (time-limit) episodes from V(final_obs)
     # instead of treating them as terminal (see ops.gae). Costs an
     # extra [T, B, obs] buffer + value forward; disable for image envs.
     time_limit_bootstrap: bool = True
     compute_dtype: str = "float32"  # "bfloat16" runs torsos on the MXU in bf16
-    # Fused Pallas VMEM kernel for GAE: True compiles it (TPU only),
-    # "interpret" runs the Pallas interpreter (CPU-mesh tests).
-    use_pallas_scan: bool | str = False
     # In-graph all-finite guard over loss/grads/params folded into the
     # iteration (one fused reduction, surfaced as ``health_finite``) —
     # the same guard the IMPALA learner carries; ``common.run_loop``'s
@@ -117,8 +107,6 @@ def make_a2c(cfg: A2CConfig) -> common.IterationFns:
             hidden_sizes=cfg.hidden_sizes,
             lstm_size=cfg.lstm_size,
             compute_dtype=cfg.compute_dtype,
-            lstm_precompute_gates=cfg.lstm_precompute_gates,
-            lstm_unroll=cfg.lstm_unroll,
         )
     else:
         model = DiscreteActorCritic(
@@ -192,7 +180,6 @@ def make_a2c(cfg: A2CConfig) -> common.IterationFns:
             gamma=cfg.gamma, lam=cfg.gae_lambda,
             terminations=ep_info["terminated"],
             truncation_values=truncation_values,
-            use_pallas=cfg.use_pallas_scan,
         )
         if cfg.normalize_adv:
             advantages = common.global_normalize_advantages(advantages)
@@ -260,7 +247,6 @@ def make_a2c(cfg: A2CConfig) -> common.IterationFns:
             gamma=cfg.gamma, lam=cfg.gae_lambda,
             terminations=ep_info["terminated"],
             truncation_values=None,
-            use_pallas=cfg.use_pallas_scan,
         )
         if cfg.normalize_adv:
             advantages = common.global_normalize_advantages(advantages)

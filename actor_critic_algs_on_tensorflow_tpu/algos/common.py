@@ -178,7 +178,7 @@ def make_obs_prep(torso, compute_dtype):
     ``minibatch_prep`` scope before the forward pass — the torso then
     finds nothing left to convert. The same operations either way;
     only the phase they are traced under moves."""
-    if torso in ("nature_cnn", "nature_cnn_s2d"):
+    if torso == "nature_cnn":
         return lambda obs: scale_pixels(obs, jnp.dtype(compute_dtype))
     # Every other torso converts its own input, and a token id
     # (torso="qwen3_next") must reach the embedding as the integer it is.
@@ -192,8 +192,6 @@ def make_recurrent_policy_head(
     hidden_sizes,
     lstm_size,
     compute_dtype,
-    lstm_precompute_gates=False,
-    lstm_unroll=1,
     seq_model=None,
     cache_len=0,
 ):
@@ -251,8 +249,6 @@ def make_recurrent_policy_head(
         hidden_sizes=hidden_sizes,
         lstm_size=lstm_size,
         dtype=jnp.dtype(compute_dtype),
-        precompute_gates=lstm_precompute_gates,
-        unroll=lstm_unroll,
     )
 
     def seq_dist_value(params, obs_tb, resets_tb, carry):

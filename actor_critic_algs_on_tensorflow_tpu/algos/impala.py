@@ -364,9 +364,6 @@ class ImpalaConfig:
     shard_step_barrier: bool = True
     shard_barrier_timeout_s: float = 60.0
     compute_dtype: str = "float32"  # "bfloat16" runs the torso on the MXU in bf16
-    # Fused Pallas VMEM kernel for V-trace: True compiles it (TPU only),
-    # "interpret" runs the Pallas interpreter (CPU-mesh tests).
-    use_pallas_scan: bool | str = False
     # Recurrent (LSTM) policy — the IMPALA-paper model family. Actors
     # thread the carry across rollouts like env state; each trajectory
     # ships its ENTRY carry and the learner replays the sequence from
@@ -375,13 +372,6 @@ class ImpalaConfig:
     # time_shards > 1 (the LSTM replay needs the full local time axis).
     recurrent: bool = False
     lstm_size: int = 128
-    # Fused LSTM update path: hoist the input-side gate projection out
-    # of the time scan into one batched MXU matmul (identical numerics
-    # and param tree; see models._FusedMaskedLSTM) and unroll the scan
-    # by this factor. Measured on flicker-pong in PERF.md "Recurrent
-    # throughput".
-    lstm_precompute_gates: bool = False
-    lstm_unroll: int = 1
     # Shard the trajectory TIME axis over this many devices (learner
     # mesh becomes 2-D data x time; V-trace runs sequence-parallel via
     # ops.sequence_parallel). For rollouts too long for one device.
@@ -766,11 +756,6 @@ def make_impala(cfg: ImpalaConfig):
                 f"rollout_length={cfg.rollout_length} not divisible by "
                 f"time_shards={cfg.time_shards}"
             )
-        if cfg.use_pallas_scan:
-            raise ValueError(
-                "use_pallas_scan is the single-device V-trace kernel; "
-                "it cannot combine with time_shards > 1"
-            )
         mesh = Mesh(
             np.asarray(jax.devices()[:n_dev]).reshape(
                 n_dev // cfg.time_shards, cfg.time_shards
@@ -802,8 +787,6 @@ def make_impala(cfg: ImpalaConfig):
             hidden_sizes=cfg.hidden_sizes,
             lstm_size=cfg.lstm_size,
             compute_dtype=cfg.compute_dtype,
-            lstm_precompute_gates=cfg.lstm_precompute_gates,
-            lstm_unroll=cfg.lstm_unroll,
         )
         dist_and_value = None
     else:
@@ -1005,11 +988,7 @@ def make_impala(cfg: ImpalaConfig):
                 return sp_vtrace(
                     *vtrace_args, axis_name=TIME_AXIS, **vtrace_kw
                 )
-            return vtrace(
-                *vtrace_args,
-                use_pallas=cfg.use_pallas_scan,
-                **vtrace_kw,
-            )
+            return vtrace(*vtrace_args, **vtrace_kw)
 
     @jax.named_scope(profiling.UPDATE)
     def local_learner_step(state: LearnerState, batch: ActorTrajectory):

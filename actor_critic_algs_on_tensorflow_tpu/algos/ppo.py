@@ -109,13 +109,6 @@ class PPOConfig:
     # latter would need per-step carries for V(final_obs)).
     recurrent: bool = False
     lstm_size: int = 128
-    # Fused LSTM update path: hoist the input-side gate projection out
-    # of the time scan into one batched MXU matmul (identical numerics
-    # and param tree; see models._FusedMaskedLSTM) and unroll the scan
-    # by this factor. Measured on flicker-pong in PERF.md "Recurrent
-    # throughput".
-    lstm_precompute_gates: bool = False
-    lstm_unroll: int = 1
     # torso="qwen3_next" (recurrent only): the sequence-policy core of
     # models/qwen3_next.py in place of torso + LSTM. ``seq_model`` is
     # its Qwen3NextConfig — every width, the share of experts and
@@ -139,9 +132,6 @@ class PPOConfig:
     # Requires frame_stack >= 2 and time_limit_bootstrap=False.
     compact_frames: bool = False
     compute_dtype: str = "float32"  # "bfloat16" runs torsos on the MXU in bf16
-    # Fused Pallas VMEM kernel for GAE: True compiles it (TPU only),
-    # "interpret" runs the Pallas interpreter (CPU-mesh tests).
-    use_pallas_scan: bool | str = False
     # In-graph all-finite guard over the per-minibatch losses and the
     # final params, folded into the iteration (one fused reduction;
     # surfaced as ``health_finite`` for common.run_loop's sentinel).
@@ -239,8 +229,6 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             hidden_sizes=cfg.hidden_sizes,
             lstm_size=cfg.lstm_size,
             compute_dtype=cfg.compute_dtype,
-            lstm_precompute_gates=cfg.lstm_precompute_gates,
-            lstm_unroll=cfg.lstm_unroll,
             seq_model=cfg.seq_model,
             cache_len=cfg.rollout_length,
         )
@@ -369,7 +357,6 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 gamma=cfg.gamma, lam=cfg.gae_lambda,
                 terminations=ep_info["terminated"],
                 truncation_values=truncation_values,
-                use_pallas=cfg.use_pallas_scan,
             )
 
         time_major = {
@@ -696,7 +683,6 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 gamma=cfg.gamma, lam=cfg.gae_lambda,
                 terminations=ep_info["terminated"],
                 truncation_values=None,
-                use_pallas=cfg.use_pallas_scan,
             )
 
         resets_tb = common.replay_resets(carry0["prev_done"], traj.dones)

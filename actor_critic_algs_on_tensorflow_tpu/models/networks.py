@@ -54,56 +54,6 @@ class MLPTorso(nn.Module):
         return x
 
 
-class _FoldedConv(nn.Module):
-    """VALID strided conv computed via space-to-depth folding.
-
-    A stride-``s`` conv on TPU tiles poorly when ``s > 1`` (the 84x84
-    stride-4/stride-2 Nature-CNN layers reach ~18% MXU utilization;
-    the conv backward is the dominant cost of the PPO update). Folding
-    ``s x s`` spatial blocks into channels turns it into an exactly
-    equivalent stride-1 conv with ``s*s*C`` input channels — larger
-    contractions, regular windows, MXU-friendly forward AND backward.
-
-    The kernel parameter keeps the canonical ``[kh, kw, C, F]`` shape
-    (identical init, param tree, and checkpoints as ``nn.Conv``; pass
-    ``name='Conv_i'`` to keep the flax scope identical); the fold is a
-    pure reshape/transpose inside the call, so gradients flow through
-    it and the module computes the same function bit-for-algebra as the
-    strided ``nn.Conv`` it replaces.
-    """
-
-    features: int
-    kernel: int
-    stride: int
-    dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        B, H, W, C = x.shape
-        s, k, F = self.stride, self.kernel, self.features
-        assert H % s == 0 and W % s == 0 and k % s == 0, (x.shape, k, s)
-        kernel = self.param(
-            "kernel", _orthogonal(), (k, k, C, F), jnp.float32
-        )
-        bias = self.param("bias", nn.initializers.zeros, (F,), jnp.float32)
-
-        # x[b, P*s+ih, Q*s+iw, c] -> x2[b, P, Q, (ih, iw, c)]
-        x2 = x.reshape(B, H // s, s, W // s, s, C)
-        x2 = x2.transpose(0, 1, 3, 2, 4, 5).reshape(B, H // s, W // s, s * s * C)
-        # K[bh*s+ih, bw*s+iw, c, f] -> K2[bh, bw, (ih, iw, c), f]
-        k2 = kernel.reshape(k // s, s, k // s, s, C, F)
-        k2 = k2.transpose(0, 2, 1, 3, 4, 5).reshape(k // s, k // s, s * s * C, F)
-
-        y = jax.lax.conv_general_dilated(
-            x2.astype(self.dtype),
-            k2.astype(self.dtype),
-            window_strides=(1, 1),
-            padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        return y + bias.astype(self.dtype)
-
-
 def scale_pixels(x, dtype):
     """Frames in ``dtype``: uint8 scaled to [0, 1] on the device (the
     host->HBM transfer stays 1 byte/pixel), anything else cast."""
@@ -119,48 +69,25 @@ class NatureCNN(nn.Module):
     ReLU throughout (Mnih et al. 2015). Input ``[..., 84, 84, C]`` in
     [0, 1] or uint8 (uint8 is scaled on-device so the host->HBM transfer
     stays 1 byte/pixel).
-
-    ``space_to_depth=True`` computes the strided layers via
-    ``_FoldedConv`` (exact same function and param tree, MXU-friendly
-    tiling); it requires the spatial dims at each strided layer to be
-    divisible by the stride (true for 84x84) and falls back to
-    ``nn.Conv`` per-layer otherwise.
     """
 
     hidden_size: int = 512
     dtype: Dtype = jnp.float32
-    space_to_depth: bool = False
 
     @nn.compact
     def __call__(self, x):
         x = scale_pixels(x, self.dtype)
         batch_shape = x.shape[:-3]
         x = x.reshape((-1,) + x.shape[-3:])
-        for i, (features, kernel, stride) in enumerate(
-            ((32, 8, 4), (64, 4, 2), (64, 3, 1))
-        ):
-            foldable = (
-                self.space_to_depth
-                and stride > 1
-                and kernel % stride == 0
-                and x.shape[-3] % stride == 0
-                and x.shape[-2] % stride == 0
-            )
-            if foldable:
-                x = _FoldedConv(
-                    features, kernel, stride, dtype=self.dtype,
-                    name=f"Conv_{i}",
-                )(x)
-            else:
-                x = nn.Conv(
-                    features,
-                    (kernel, kernel),
-                    strides=(stride, stride),
-                    padding="VALID",
-                    kernel_init=_orthogonal(),
-                    dtype=self.dtype,
-                    name=f"Conv_{i}",
-                )(x)
+        for features, kernel, stride in ((32, 8, 4), (64, 4, 2), (64, 3, 1)):
+            x = nn.Conv(
+                features,
+                (kernel, kernel),
+                strides=(stride, stride),
+                padding="VALID",
+                kernel_init=_orthogonal(),
+                dtype=self.dtype,
+            )(x)
             x = nn.relu(x)
         x = x.reshape(x.shape[0], -1)
         x = nn.Dense(self.hidden_size, kernel_init=_orthogonal(), dtype=self.dtype)(x)
@@ -287,6 +214,23 @@ class FrameTransformerEncoder(nn.Module):
         )(tokens)
 
 
+def _discrete_torso(name, hidden_sizes, dtype):
+    """The encoder that ``DiscreteActorCritic`` and
+    ``RecurrentActorCritic`` put under their heads for ``torso=name``.
+    Any other name is refused: falling back to the MLP would train a
+    different model than the one asked for."""
+    if name == "nature_cnn":
+        return NatureCNN(dtype=dtype)
+    if name == "frame_transformer":
+        return FrameTransformerEncoder(dtype=dtype)
+    if name == "mlp":
+        return MLPTorso(hidden_sizes, dtype=dtype)
+    raise ValueError(
+        f"unknown torso {name!r}: this module builds 'mlp', 'nature_cnn' "
+        "or 'frame_transformer'"
+    )
+
+
 class _MaskedLSTMCell(nn.Module):
     """LSTM cell step with per-example episode-boundary masking.
 
@@ -314,107 +258,6 @@ class _MaskedLSTMCell(nn.Module):
         return carry, y
 
 
-class _DenseP(nn.Module):
-    """Parameter-only stand-in for one of ``nn.OptimizedLSTMCell``'s
-    per-gate ``DenseParams`` — declares the identical ``kernel`` (and
-    optional ``bias``) leaves without computing anything, so the fused
-    LSTM below shares a checkpoint-compatible param tree with the
-    scan-of-cells path."""
-
-    features: int
-    in_features: int
-    use_bias: bool
-    kernel_init: Callable
-
-    @nn.compact
-    def __call__(self):
-        kernel = self.param(
-            "kernel",
-            self.kernel_init,
-            (self.in_features, self.features),
-            jnp.float32,
-        )
-        if not self.use_bias:
-            return kernel, None
-        bias = self.param(
-            "bias", nn.initializers.zeros_init(), (self.features,), jnp.float32
-        )
-        return kernel, bias
-
-
-class _LSTMParams(nn.Module):
-    """The 8 gate-param sets of ``nn.OptimizedLSTMCell`` (``i{i,f,g,o}``
-    kernels, ``h{i,f,g,o}`` kernels+biases), concatenated gate-major in
-    the cell's own ``[i|f|g|o]`` order. Same names, shapes, and inits as
-    the real cell, so checkpoints interoperate both ways."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self, in_features: int):
-        wi, wh, bh = [], [], []
-        for comp in ("i", "f", "g", "o"):
-            k, _ = _DenseP(
-                self.features,
-                in_features,
-                False,
-                nn.initializers.lecun_normal(),
-                name=f"i{comp}",
-            )()
-            wi.append(k)
-            k, b = _DenseP(
-                self.features,
-                self.features,
-                True,
-                nn.initializers.orthogonal(),
-                name=f"h{comp}",
-            )()
-            wh.append(k)
-            bh.append(b)
-        return (
-            jnp.concatenate(wi, axis=-1),
-            jnp.concatenate(wh, axis=-1),
-            jnp.concatenate(bh, axis=-1),
-        )
-
-
-class _FusedMaskedLSTM(nn.Module):
-    """Masked LSTM over time with the input-side gate projection HOISTED
-    out of the scan.
-
-    The per-step cell math only depends on the input through
-    ``x @ W_i``; that projection — ``[T*B, Z] x [Z, 4H]``, two thirds of
-    the cell FLOPs when ``Z > H`` — is computed as ONE batched MXU
-    matmul before the scan, leaving just the ``[B, H] x [H, 4H]``
-    recurrence + elementwise gates inside. Numerics are identical to
-    ``_MaskedLSTMCell`` (same f32 compute, same gate order, same
-    pre-cell reset masking), and ``_LSTMParams`` keeps the param tree
-    checkpoint-identical, so the two paths are drop-in interchangeable
-    (tested in ``tests/test_recurrent.py``).
-    """
-
-    features: int
-    unroll: int = 1
-
-    @nn.compact
-    def __call__(self, carry, z, resets):
-        w_i, w_h, b_h = _LSTMParams(self.features, name="cell")(z.shape[-1])
-        gx = jnp.dot(z.astype(jnp.float32), w_i)  # [T, B, 4H], one matmul
-
-        def step(carry, xs):
-            gx_t, reset = xs
-            c, h = carry
-            keep = (1.0 - reset)[..., None].astype(c.dtype)
-            c, h = c * keep, h * keep
-            gates = gx_t + jnp.dot(h, w_h) + b_h
-            i, f, g, o = jnp.split(gates, 4, axis=-1)
-            c = nn.sigmoid(f) * c + nn.sigmoid(i) * jnp.tanh(g)
-            h = nn.sigmoid(o) * jnp.tanh(c)
-            return (c, h), h
-
-        return jax.lax.scan(step, carry, (gx, resets), unroll=self.unroll)
-
-
 class RecurrentActorCritic(nn.Module):
     """Recurrent (LSTM) policy + value heads over any discrete torso —
     the IMPALA/R2D2-era recurrent model family for partially observable
@@ -438,39 +281,18 @@ class RecurrentActorCritic(nn.Module):
     hidden_sizes: Sequence[int] = (64, 64)
     lstm_size: int = 128
     dtype: Dtype = jnp.float32
-    # Scan the per-step cell (False) or hoist the input projection into
-    # one pre-scan MXU matmul (True; same numerics + param tree, faster
-    # — see _FusedMaskedLSTM). ``unroll`` is lax.scan's unroll factor
-    # over time for either path.
-    precompute_gates: bool = False
-    unroll: int = 1
 
     @nn.compact
     def __call__(self, obs, resets, carry):
-        if self.torso == "nature_cnn":
-            z = NatureCNN(dtype=self.dtype)(obs)
-        elif self.torso == "nature_cnn_s2d":
-            # Same params/tree as nature_cnn (s2d is a pure relayout),
-            # so checkpoints interoperate between the two torso names.
-            z = NatureCNN(dtype=self.dtype, space_to_depth=True)(obs)
-        elif self.torso == "frame_transformer":
-            z = FrameTransformerEncoder(dtype=self.dtype)(obs)
-        else:
-            z = MLPTorso(self.hidden_sizes, dtype=self.dtype)(obs)
-        if self.precompute_gates:
-            carry, y = _FusedMaskedLSTM(
-                self.lstm_size, unroll=self.unroll, name="lstm"
-            )(carry, z, resets)
-        else:
-            scan = nn.scan(
-                _MaskedLSTMCell,
-                variable_broadcast="params",
-                split_rngs={"params": False},
-                in_axes=0,
-                out_axes=0,
-                unroll=self.unroll,
-            )(self.lstm_size, name="lstm")
-            carry, y = scan(carry, (z, resets))
+        z = _discrete_torso(self.torso, self.hidden_sizes, self.dtype)(obs)
+        scan = nn.scan(
+            _MaskedLSTMCell,
+            variable_broadcast="params",
+            split_rngs={"params": False},
+            in_axes=0,
+            out_axes=0,
+        )(self.lstm_size, name="lstm")
+        carry, y = scan(carry, (z, resets))
         y = y.astype(self.dtype)
         logits = nn.Dense(
             self.num_actions, kernel_init=_orthogonal(0.01), dtype=self.dtype
@@ -504,18 +326,7 @@ class DiscreteActorCritic(nn.Module):
 
     @nn.compact
     def __call__(self, obs):
-        if self.torso == "nature_cnn":
-            z = NatureCNN(dtype=self.dtype)(obs)
-        elif self.torso == "nature_cnn_s2d":
-            # Space-to-depth folded convs: same function and param tree
-            # as nature_cnn (checkpoints interchangeable); measured
-            # slower end-to-end on v5e (PERF.md ledger) but kept
-            # selectable for other backends/shapes.
-            z = NatureCNN(dtype=self.dtype, space_to_depth=True)(obs)
-        elif self.torso == "frame_transformer":
-            z = FrameTransformerEncoder(dtype=self.dtype)(obs)
-        else:
-            z = MLPTorso(self.hidden_sizes, dtype=self.dtype)(obs)
+        z = _discrete_torso(self.torso, self.hidden_sizes, self.dtype)(obs)
         logits = nn.Dense(
             self.num_actions, kernel_init=_orthogonal(0.01), dtype=self.dtype
         )(z)

@@ -28,7 +28,6 @@ def gae_advantages(
     lam: float = 0.95,
     terminations: jax.Array | None = None,
     truncation_values: jax.Array | None = None,
-    use_pallas: bool | str = False,
 ):
     """Compute GAE(lambda) advantages and value targets.
 
@@ -50,10 +49,6 @@ def gae_advantages(
         the classic biased-but-simple convention).
       truncation_values: optional ``[T, ...]`` ``V(final_obs_t)`` used
         as the bootstrap at truncated steps (pre-auto-reset obs).
-      use_pallas: compute the backward recurrence with the fused Pallas
-        VMEM kernel (ops.pallas_scan) instead of ``lax.scan``. ``True``
-        compiles the kernel (TPU only — an error elsewhere);
-        ``"interpret"`` runs it in the Pallas interpreter (tests).
 
     Returns:
       ``(advantages, returns)`` each ``[T, ...]``; ``returns`` are the
@@ -77,28 +72,17 @@ def gae_advantages(
         )
     deltas = rewards + gamma * (1.0 - bootstrap_cut) * values_tp1 - values
 
-    if use_pallas:
-        from actor_critic_algs_on_tensorflow_tpu.ops.pallas_scan import (
-            linear_backward_scan,
-        )
+    def _step(carry, inp):
+        delta, done = inp
+        carry = delta + gamma * lam * (1.0 - done) * carry
+        return carry, carry
 
-        advantages = linear_backward_scan(
-            deltas,
-            gamma * lam * (1.0 - dones),
-            interpret=use_pallas == "interpret",
-        )
-    else:
-        def _step(carry, inp):
-            delta, done = inp
-            carry = delta + gamma * lam * (1.0 - done) * carry
-            return carry, carry
-
-        _, adv_rev = jax.lax.scan(
-            _step,
-            jnp.zeros_like(last_value),
-            (deltas[::-1], dones[::-1]),
-        )
-        advantages = adv_rev[::-1]
+    _, adv_rev = jax.lax.scan(
+        _step,
+        jnp.zeros_like(last_value),
+        (deltas[::-1], dones[::-1]),
+    )
+    advantages = adv_rev[::-1]
     returns = advantages + values
     return advantages, returns
 

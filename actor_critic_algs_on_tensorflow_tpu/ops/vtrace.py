@@ -42,16 +42,12 @@ def vtrace(
     rho_bar: float = 1.0,
     c_bar: float = 1.0,
     pg_rho_bar: float | None = None,
-    use_pallas: bool | str = False,
 ) -> VTraceOutput:
     """Compute V-trace targets and policy-gradient advantages.
 
     All time-major inputs are ``[T, ...]``; ``bootstrap_value`` is
     ``[...]`` = V(s_T) under the target policy.  ``dones`` masks the
     bootstrap across episode boundaries (1.0 where s_{t+1} is a reset).
-    ``use_pallas`` selects the fused Pallas kernel as in
-    ``ops.gae.gae_advantages`` (``True`` compiled, ``"interpret"``
-    interpreted).
     """
     rewards = jnp.asarray(rewards)
     values = jnp.asarray(values)
@@ -66,26 +62,17 @@ def vtrace(
     discounts = gamma * (1.0 - dones)
     deltas = clipped_rhos * (rewards + discounts * values_tp1 - values)
 
-    if use_pallas:
-        from actor_critic_algs_on_tensorflow_tpu.ops.pallas_scan import (
-            linear_backward_scan,
-        )
+    def _step(acc, inp):
+        delta, discount, c = inp
+        acc = delta + discount * c * acc
+        return acc, acc
 
-        vs_minus_v = linear_backward_scan(
-            deltas, discounts * cs, interpret=use_pallas == "interpret"
-        )
-    else:
-        def _step(acc, inp):
-            delta, discount, c = inp
-            acc = delta + discount * c * acc
-            return acc, acc
-
-        _, acc_rev = jax.lax.scan(
-            _step,
-            jnp.zeros_like(bootstrap_value),
-            (deltas[::-1], discounts[::-1], cs[::-1]),
-        )
-        vs_minus_v = acc_rev[::-1]
+    _, acc_rev = jax.lax.scan(
+        _step,
+        jnp.zeros_like(bootstrap_value),
+        (deltas[::-1], discounts[::-1], cs[::-1]),
+    )
+    vs_minus_v = acc_rev[::-1]
     vs = values + vs_minus_v
 
     vs_tp1 = jnp.concatenate([vs[1:], bootstrap_value[None]], axis=0)

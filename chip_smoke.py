@@ -15,8 +15,6 @@ process owns. Sizes come from the presets; only the step budget is cut.
      two env-shim processes, until the InferenceServer has answered a
      few hundred ``act()`` requests. Every child must come up on the
      CPU: one process owns the chip.
-  D  the Pallas backward-recurrence kernel COMPILED at ``[128, 1024]``
-     f32, against the ``lax.scan`` branch of ``ops.gae``.
 
 Exits non-zero unless ``jax.devices()[0].platform == "tpu"``; a failed
 check in any leg raises. Each leg prints its wall time, its compile
@@ -281,40 +279,6 @@ def leg_c_serving() -> dict:
     )
 
 
-def leg_d_pallas(jax) -> dict:
-    import functools
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from actor_critic_algs_on_tensorflow_tpu.ops import gae_advantages
-
-    phase = _Phase("D pallas kernel")
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    rewards = jax.random.normal(keys[0], (128, 1024), jnp.float32)
-    values = jax.random.normal(keys[1], (128, 1024), jnp.float32)
-    dones = (jax.random.uniform(keys[2], (128, 1024)) < 0.05).astype(
-        jnp.float32
-    )
-    last_value = jax.random.normal(keys[3], (1024,), jnp.float32)
-    adv_scan, ret_scan = jax.jit(gae_advantages)(
-        rewards, values, dones, last_value
-    )
-    adv, ret = jax.jit(
-        functools.partial(gae_advantages, use_pallas=True)
-    )(rewards, values, dones, last_value)
-    _check(adv.shape == (128, 1024) and adv.dtype == jnp.float32,
-           f"shape {adv.shape} {adv.dtype}")
-    _check(bool(jnp.all(jnp.isfinite(adv))), "non-finite advantages")
-    np.testing.assert_allclose(adv, adv_scan, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(ret, ret_scan, rtol=1e-5, atol=1e-5)
-    err = float(jnp.max(jnp.abs(adv - adv_scan)))
-    return phase.done(
-        f"Mosaic-compiled kernel == lax.scan at [128, 1024] f32, "
-        f"max abs diff {err:.3g}"
-    )
-
-
 def main() -> int:
     # A hang must end inside the driver's 1200 s, with a traceback.
     faulthandler.dump_traceback_later(1150, exit=True)
@@ -346,7 +310,6 @@ def main() -> int:
         "A": leg_a_ppo(jax),
         "B": leg_b_impala(),
         "C": leg_c_serving(),
-        "D": leg_d_pallas(jax),
     }
     device = {
         "platform": devices[0].platform,

@@ -12,7 +12,7 @@ Usage:
 
 Knobs (key=value): num_envs, rollout, epochs, minibatches, lstm_size,
 recurrent, frame_stack, dtype, shuffle, windows, iters_per_window,
-lstm_unroll, lstm_precompute_gates, torso.
+torso.
 
 Prints one line per window plus a summary {best, median, spread}.
 """
@@ -37,8 +37,6 @@ def main() -> int:
     shuffle = knobs.get("shuffle", "env")
     windows = int(knobs.get("windows", 5))
     iters_per_window = int(knobs.get("iters_per_window", 5))
-    unroll = int(knobs.get("lstm_unroll", 1))
-    precompute = bool(int(knobs.get("lstm_precompute_gates", 0)))
 
     import jax
 
@@ -60,8 +58,6 @@ def main() -> int:
         lr=1e-3,
         recurrent=recurrent,
         lstm_size=lstm_size,
-        lstm_unroll=unroll,
-        lstm_precompute_gates=precompute,
         time_limit_bootstrap=False,
         compute_dtype=dtype,
         num_devices=len(jax.devices()),

@@ -44,8 +44,6 @@ def main() -> int:
         lr=1e-3,
         recurrent=bool(int(knobs.get("recurrent", 1))),
         lstm_size=int(knobs.get("lstm_size", 256)),
-        lstm_precompute_gates=bool(int(knobs.get("lstm_precompute_gates", 0))),
-        lstm_unroll=int(knobs.get("lstm_unroll", 1)),
         time_limit_bootstrap=False,
         compute_dtype=knobs.get("dtype", "bfloat16"),
         num_devices=len(jax.devices()),

@@ -1,6 +1,8 @@
 """GAE / discounted-return scans vs. slow O(T^2) numpy oracles
 (SURVEY.md §4.1)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,10 +14,18 @@ from actor_critic_algs_on_tensorflow_tpu.ops import (
 )
 
 
-def _gae_oracle(rewards, values, dones, last_value, gamma, lam):
+def _gae_oracle(rewards, values, dones, last_value, gamma, lam,
+                terminations=None, truncation_values=None):
     T = len(rewards)
     values_tp1 = np.concatenate([values[1:], [last_value]])
-    deltas = rewards + gamma * (1 - dones) * values_tp1 - values
+    cut = dones
+    if terminations is not None:
+        # a truncated step bootstraps from V(final_obs), a terminal one
+        # from nothing; either way the recursion stops at ``dones``
+        cut = terminations
+        truncated = (dones == 1) & (terminations == 0)
+        values_tp1 = np.where(truncated, truncation_values, values_tp1)
+    deltas = rewards + gamma * (1 - cut) * values_tp1 - values
     adv = np.zeros(T + 1)
     for t in reversed(range(T)):
         adv[t] = deltas[t] + gamma * lam * (1 - dones[t]) * adv[t + 1]
@@ -42,6 +52,35 @@ def test_gae_matches_oracle(seed):
     adv_np, ret_np = _gae_oracle(rewards, values, dones, last_value, 0.99, 0.95)
     np.testing.assert_allclose(np.asarray(adv), adv_np, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(ret), ret_np, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("T,B", [(1, 1), (7, 3), (128, 5)])
+def test_gae_batched_matches_oracle_columnwise(T, B):
+    """``[T, B]`` under ``jax.jit`` with ``terminations`` and
+    ``truncation_values`` as ``make_ppo`` passes them: every column is
+    the oracle's answer for that env alone."""
+    rng = np.random.default_rng(T * 31 + B)
+    rewards = rng.normal(size=(T, B)).astype(np.float32)
+    values = rng.normal(size=(T, B)).astype(np.float32)
+    dones = (rng.random((T, B)) < 0.2).astype(np.float32)
+    terms = dones * (rng.random((T, B)) < 0.5).astype(np.float32)
+    trunc_v = rng.normal(size=(T, B)).astype(np.float32)
+    last_value = rng.normal(size=B).astype(np.float32)
+
+    adv, ret = jax.jit(
+        functools.partial(gae_advantages, gamma=0.99, lam=0.95)
+    )(
+        rewards, values, dones, last_value,
+        terminations=terms, truncation_values=trunc_v,
+    )
+    assert adv.shape == (T, B) and adv.dtype == jnp.float32
+    for b in range(B):
+        adv_np, ret_np = _gae_oracle(
+            rewards[:, b], values[:, b], dones[:, b], last_value[b],
+            0.99, 0.95, terms[:, b], trunc_v[:, b],
+        )
+        np.testing.assert_allclose(adv[:, b], adv_np, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(ret[:, b], ret_np, rtol=1e-4, atol=1e-5)
 
 
 def test_gae_batched_shapes():

@@ -3,12 +3,14 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from actor_critic_algs_on_tensorflow_tpu.models import (
     DeterministicActor,
     DiscreteActorCritic,
     GaussianActorCritic,
     NatureCNN,
+    RecurrentActorCritic,
     SquashedGaussianActor,
     TwinQCritic,
 )
@@ -74,26 +76,17 @@ def test_sac_actor_bounds():
     )
 
 
-def test_nature_cnn_space_to_depth_equivalent():
-    # _FoldedConv keeps the canonical kernel shapes: identical param
-    # tree and init, same function to float tolerance (fwd and grads).
-    import jax.tree_util as jtu
-    from actor_critic_algs_on_tensorflow_tpu.models.networks import NatureCNN
-
-    ref = NatureCNN(space_to_depth=False)
-    s2d = NatureCNN(space_to_depth=True)
-    x = jax.random.uniform(jax.random.PRNGKey(1), (4, 84, 84, 4))
-    p_ref = ref.init(jax.random.PRNGKey(0), x)
-    p_s2d = s2d.init(jax.random.PRNGKey(0), x)
-    assert jtu.tree_structure(p_ref) == jtu.tree_structure(p_s2d)
-    for a, b in zip(jtu.tree_leaves(p_ref), jtu.tree_leaves(p_s2d)):
-        np.testing.assert_allclose(a, b)
-
-    y_ref = ref.apply(p_ref, x)
-    y_s2d = s2d.apply(p_ref, x)
-    np.testing.assert_allclose(y_ref, y_s2d, atol=1e-4)
-
-    g_ref = jax.grad(lambda p: ref.apply(p, x).sum())(p_ref)
-    g_s2d = jax.grad(lambda p: s2d.apply(p, x).sum())(p_ref)
-    for a, b in zip(jtu.tree_leaves(g_ref), jtu.tree_leaves(g_s2d)):
-        np.testing.assert_allclose(a, b, atol=5e-3, rtol=1e-4)
+@pytest.mark.parametrize("torso", ["nature_cnn_s2d", "resnet_deep"])
+@pytest.mark.parametrize("recurrent", [False, True], ids=["ff", "lstm"])
+def test_unknown_torso_is_refused_by_name(torso, recurrent):
+    """A torso name the module does not build must not fall through to
+    the MLP (which would train an MLP on pixels, in silence)."""
+    obs = jnp.zeros((2, 3, 84, 84, 4), jnp.uint8)
+    if recurrent:
+        model = RecurrentActorCritic(num_actions=6, torso=torso)
+        args = (obs, jnp.zeros((2, 3)), model.initialize_carry(3))
+    else:
+        model = DiscreteActorCritic(num_actions=6, torso=torso)
+        args = (obs,)
+    with pytest.raises(ValueError, match=f"unknown torso '{torso}'.*'mlp'"):
+        model.init(jax.random.PRNGKey(0), *args)

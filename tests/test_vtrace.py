@@ -1,6 +1,9 @@
 """V-trace scan vs. a direct numpy transcription of the IMPALA paper
 recursion (SURVEY.md §4.1)."""
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -61,6 +64,33 @@ def test_vtrace_matches_oracle(seed, rho_bar, c_bar):
     np.testing.assert_allclose(
         np.asarray(out.pg_advantages), pg_np, rtol=1e-4, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("T,B", [(1, 1), (7, 3), (128, 5)])
+def test_vtrace_batched_matches_oracle_columnwise(T, B):
+    """``[T, B]`` under ``jax.jit``, as the learner calls it: every
+    column is the oracle's answer for that env alone."""
+    rng = np.random.default_rng(T * 31 + B)
+    b_logp = rng.normal(size=(T, B)).astype(np.float32) * 0.3
+    t_logp = rng.normal(size=(T, B)).astype(np.float32) * 0.3
+    rewards = rng.normal(size=(T, B)).astype(np.float32)
+    values = rng.normal(size=(T, B)).astype(np.float32)
+    dones = (rng.random((T, B)) < 0.2).astype(np.float32)
+    bootstrap = rng.normal(size=B).astype(np.float32)
+
+    out = jax.jit(
+        functools.partial(vtrace, gamma=0.99, lam=0.97, rho_bar=2.0, c_bar=0.9)
+    )(b_logp, t_logp, rewards, values, dones, bootstrap)
+    assert out.vs.shape == (T, B) and out.vs.dtype == jnp.float32
+    for b in range(B):
+        vs_np, pg_np = _vtrace_oracle(
+            b_logp[:, b], t_logp[:, b], rewards[:, b], values[:, b],
+            dones[:, b], bootstrap[b], 0.99, 0.97, 2.0, 0.9,
+        )
+        np.testing.assert_allclose(out.vs[:, b], vs_np, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(
+            out.pg_advantages[:, b], pg_np, rtol=1e-4, atol=1e-5
+        )
 
 
 def test_vtrace_on_policy_reduces_to_gae_lambda1():
