@@ -177,7 +177,13 @@ def make_obs_prep(torso, compute_dtype):
     (``scale_pixels``), for an update to run under its
     ``minibatch_prep`` scope before the forward pass — the torso then
     finds nothing left to convert. The same operations either way;
-    only the phase they are traced under moves."""
+    only the phase they are traced under moves. Call it on the
+    observations as the torso's first layer will read them (PPO's
+    minibatch, IMPALA's merged ``[T * B]`` batch): the compiler then
+    fuses the conversion into that layer's input and the frames are
+    read at one byte a pixel; ahead of a reshape that moves bytes, the
+    converted copy is what gets moved (PERF.md section 6, PRs 26 and
+    30)."""
     if torso == "nature_cnn":
         return lambda obs: scale_pixels(obs, jnp.dtype(compute_dtype))
     # Every other torso converts its own input, and a token id

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from flax import struct
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from actor_critic_algs_on_tensorflow_tpu import envs as envs_lib
@@ -643,6 +644,32 @@ class ImpalaActor(threading.Thread):
             self.error = e
 
 
+def _merge_time_batch(x: jax.Array, conv_frames: bool) -> jax.Array:
+    """``[T, B, ...] -> [T * B, ...]``: what a feed-forward torso is
+    called on. A free reshape for vectors; for the uint8 frames of a
+    convolutional torso (``conv_frames``) on the TPU it is the one pass
+    over the batch the learner pays for (PERF.md section 6, PR 30).
+
+    There a frame array lies batch-minor (``[84][84][C][B]``, the
+    layout Conv_0 reads), so a ``[T, B]`` batch arrives as ``T`` such
+    slices and only moving ``T`` inside costs anything. Left to itself
+    the compiler converts first and moves the widened copy (two bf16
+    passes over the batch); held behind ``optimization_barrier`` the
+    uint8 value gets a tiling Conv_0 is slower on, in two passes (worse
+    than either). Told the layout, it moves the bytes once, as uint8,
+    and Conv_0's forward pass and weight gradient convert in place.
+    """
+    merged = x.reshape((-1,) + x.shape[2:])
+    if not conv_frames or x.dtype != jnp.uint8 or merged.ndim != 4:
+        return merged
+    batch_minor = Layout(major_to_minor=(1, 2, 3, 0))
+    return jax.lax.platform_dependent(
+        merged,
+        tpu=lambda m: with_layout_constraint(m, batch_minor),
+        default=lambda m: m,
+    )
+
+
 def make_impala(cfg: ImpalaConfig):
     """Build the compiled IMPALA programs (``ImpalaPrograms``; unpacks
     as the legacy ``(init, learner_step, make_actor_programs, mesh)``).
@@ -933,9 +960,21 @@ def make_impala(cfg: ImpalaConfig):
         """The learner's forward pass over one ``[T_local, B_local]``
         batch: ``(dist, values, last_value, target_log_probs)`` —
         shared by the loss, the fused device iteration (through the
-        loss), and the standalone ``vtrace_targets`` probe."""
+        loss), and the standalone ``vtrace_targets`` probe.
+
+        A feed-forward torso is called on the merged ``[T * B, ...]``
+        observations and its outputs are given their ``[T, B]`` axes
+        back: the merge happens here, on the bytes as the actor wrote
+        them (``_merge_time_batch``), not inside the torso on the
+        converted copy. ``prep_obs`` comes after it, so the compiler
+        fuses the conversion into the first layer's input. The
+        recurrent core takes ``[T, B, ...]`` by contract and flattens
+        inside ``RecurrentActorCritic``: not changed (ROADMAP S1)."""
         with jax.named_scope(profiling.MINIBATCH_PREP):
-            obs, last_obs = prep_obs(batch.obs), prep_obs(batch.last_obs)
+            obs = batch.obs if cfg.recurrent else _merge_time_batch(
+                batch.obs, conv_frames=cfg.torso == "nature_cnn"
+            )
+            obs, last_obs = prep_obs(obs), prep_obs(batch.last_obs)
         if cfg.recurrent:
             resets = common.replay_resets(
                 batch.entry_prev_done, batch.dones
@@ -951,7 +990,11 @@ def make_impala(cfg: ImpalaConfig):
             )
             last_value = last_value_tb[0]
         else:
-            dist, values = dist_and_value(params, obs)
+            time_batch = batch.rewards.shape
+            dist, values = jax.tree_util.tree_map(
+                lambda x: x.reshape(time_batch + x.shape[1:]),
+                dist_and_value(params, obs),
+            )
             _, last_value = dist_and_value(params, last_obs)
         target_log_probs = dist.log_prob(batch.actions)
         return dist, values, last_value, target_log_probs

@@ -293,3 +293,158 @@ def test_impala_normalize_advantages():
     assert np.isfinite(float(metrics["loss"])), metrics
     after = np.asarray(jax.tree_util.tree_leaves(state.params)[0])
     assert not np.allclose(before, after)
+
+
+# ---- the learner's forward pass on the merged [T * B] batch (PR 30) ----
+
+
+def _seeded_batch(cfg, obs_shape, obs_dtype, num_actions, seed):
+    import jax.numpy as jnp
+
+    T, B = cfg.rollout_length, cfg.batch_trajectories * cfg.envs_per_actor
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+
+    def obs(key, lead):
+        if obs_dtype == jnp.uint8:
+            return jax.random.randint(
+                key, lead + obs_shape, 0, 256, dtype=jnp.int32
+            ).astype(jnp.uint8)
+        return jax.random.normal(key, lead + obs_shape, obs_dtype)
+
+    return impala.ActorTrajectory(
+        obs=obs(k[0], (T, B)),
+        actions=jax.random.randint(k[1], (T, B), 0, num_actions),
+        rewards=jax.random.normal(k[2], (T, B)),
+        dones=(jax.random.uniform(k[3], (T, B)) < 0.2).astype(jnp.float32),
+        behaviour_log_probs=-jax.random.uniform(
+            k[4], (T, B), minval=0.5, maxval=2.5
+        ),
+        last_obs=obs(k[5], (B,)),
+    )
+
+
+def _the_parents_way(cfg, state, batch):
+    """Loss, aux terms, the first optimizer step and the V-trace
+    targets as the learner computed them before PR 30: the observations
+    converted on ``[T, B]`` and the torso flattening its own input."""
+    import jax.numpy as jnp
+    import optax
+
+    from actor_critic_algs_on_tensorflow_tpu import envs as envs_lib
+    from actor_critic_algs_on_tensorflow_tpu.algos import common
+    from actor_critic_algs_on_tensorflow_tpu.ops import (
+        entropy_loss,
+        value_loss,
+        vtrace,
+    )
+
+    env, env_params = envs_lib.make(
+        cfg.env, num_envs=cfg.envs_per_actor, frame_stack=cfg.frame_stack
+    )
+    _, dist_and_value = common.make_policy_head(
+        env.action_space(env_params), torso=cfg.torso,
+        hidden_sizes=cfg.hidden_sizes, compute_dtype=cfg.compute_dtype,
+    )
+    prep = common.make_obs_prep(cfg.torso, cfg.compute_dtype)
+    sg = jax.lax.stop_gradient
+
+    def forward(params):
+        dist, values = dist_and_value(params, prep(batch.obs))
+        _, last_value = dist_and_value(params, prep(batch.last_obs))
+        log_probs = dist.log_prob(batch.actions)
+        vt = vtrace(
+            batch.behaviour_log_probs, sg(log_probs), batch.rewards,
+            sg(values), batch.dones, sg(last_value), gamma=cfg.gamma,
+            lam=cfg.vtrace_lam, rho_bar=cfg.rho_bar, c_bar=cfg.c_bar,
+        )
+        return dist, values, log_probs, vt
+
+    def loss_fn(params):
+        dist, values, log_probs, vt = forward(params)
+        pg = -jnp.mean(log_probs * sg(vt.pg_advantages))
+        vf = value_loss(values, sg(vt.vs))
+        ent = dist.entropy().mean()
+        total = pg + cfg.vf_coef * vf + cfg.ent_coef * entropy_loss(ent)
+        return total, (pg, vf, ent, jnp.mean(vt.rhos))
+
+    def step(state):
+        (loss, (pg, vf, ent, rho)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True
+        )(state.params)
+        tx = optax.chain(
+            optax.clip_by_global_norm(cfg.max_grad_norm),
+            optax.adam(cfg.lr, eps=1e-5),
+        )
+        _, opt_state = tx.update(grads, state.opt_state, state.params)
+        metrics = {
+            "loss": loss, "policy_loss": pg, "value_loss": vf,
+            "entropy": ent, "mean_rho": rho,
+            "grad_norm": optax.global_norm(grads),
+        }
+        return metrics, opt_state, forward(state.params)[3]
+
+    return jax.jit(step)(state)
+
+
+@pytest.mark.parametrize(
+    "env,torso,dtype,T,B",
+    [
+        ("PongTPU-v0", "nature_cnn", "bfloat16", 3, 5),
+        ("PongTPU-v0", "nature_cnn", "float32", 4, 4),
+        ("PongTPU-v0", "nature_cnn", "float32", 3, 5),
+        ("CartPole-v1", "mlp", "float32", 8, 6),
+    ],
+    ids=["frames-bf16-3x5", "frames-f32-4x4", "frames-f32-3x5",
+         "vectors-f32-8x6"],
+)
+def test_learner_forward_on_the_merged_batch_is_the_parents(
+    env, torso, dtype, T, B
+):
+    """The feed-forward learner merges ``[T, B]`` before the torso and
+    gives the torso's outputs their axes back (PR 30): same numbers as
+    the forward pass over ``[T, B]`` — bit for bit for float32 frames
+    — for the loss, its terms, the gradient the first Adam step holds
+    (``mu = (1 - b1) * clipped gradient``) and the V-trace targets.
+    A square batch would hide values returned in ``[B, T]`` order from
+    the shapes; its numbers would not match."""
+    import jax.numpy as jnp
+
+    frames = torso == "nature_cnn"
+    cfg = impala.ImpalaConfig(
+        env=env, torso=torso, compute_dtype=dtype,
+        frame_stack=4 if frames else 0,
+        num_actors=1, envs_per_actor=B, rollout_length=T,
+        batch_trajectories=1, total_env_steps=T * B, num_devices=1,
+    )
+    progs = impala.make_impala(cfg)
+    state = progs.init(jax.random.PRNGKey(3))
+    batch = _seeded_batch(
+        cfg, (84, 84, 4) if frames else (4,),
+        jnp.uint8 if frames else jnp.float32, progs.num_actions, seed=11,
+    )
+    want_metrics, want_opt, want_vt = _the_parents_way(cfg, state, batch)
+    got_vt = progs.vtrace_targets(state.params, batch)
+    state1, got_metrics = progs.learner_step(state, batch)
+
+    if frames and dtype == "float32":
+        same = np.testing.assert_array_equal
+    else:
+        # bfloat16, and the float32 MLP: XLA:CPU runs a Dense layer
+        # over [T, B, D] and over [T * B, D] as two different dot
+        # kernels, equal to rounding (2e-7 here); the convolutions
+        # always saw [T * B] frames.
+        same = lambda a, b: np.testing.assert_allclose(  # noqa: E731
+            a, b, rtol=1e-5, atol=1e-6
+        )
+    for field in ("vs", "pg_advantages", "rhos"):
+        got = np.asarray(getattr(got_vt, field))
+        assert got.shape == (T, B), (field, got.shape)
+        same(got, np.asarray(getattr(want_vt, field)))
+    for key, want in want_metrics.items():
+        same(np.asarray(got_metrics[key]), np.asarray(want))
+    got_mu = jax.tree_util.tree_leaves(state1.opt_state[1][0].mu)
+    want_mu = jax.tree_util.tree_leaves(want_opt[1][0].mu)
+    assert len(got_mu) == len(want_mu) > 0
+    for got, want in zip(got_mu, want_mu):
+        assert np.abs(np.asarray(want)).max() > 0
+        same(np.asarray(got), np.asarray(want))

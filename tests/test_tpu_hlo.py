@@ -1,6 +1,7 @@
 """What the TPU's compiler makes of the main path, read off the chip:
-the ``ppo-breakout`` iteration compiled for a described v5e at the
-preset's real size (nothing runs, ~13 s), and its instruction text.
+the ``ppo-breakout`` iteration and the ``impala-pong`` learner step
+compiled for a described v5e at their real sizes (nothing runs, ~13 s
+and ~10 s), and their instruction text.
 
 Keep every test that describes a topology in THIS file: the worker that
 describes one holds the TPU library until it exits, and a second file
@@ -41,17 +42,22 @@ def topo():
     compilation_cache.reset_cache()
 
 
-def iteration_text(monkeypatch, topo, preset):
-    """The preset's fused iteration on one described chip, as compiled
-    text. The program builds its mesh from ``jax.devices()``: hand it
-    the described chip while it does."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
+def on_described_chip(monkeypatch, topo):
+    """A program builds its mesh from ``jax.devices()``: hand it the
+    described chip while it does (until ``monkeypatch.undo()``)."""
     real = jax.devices
     monkeypatch.setattr(
         jax, "devices",
         lambda *a, **k: [topo.devices[0]] if not a else real(*a, **k),
     )
+
+
+def iteration_text(monkeypatch, topo, preset):
+    """The preset's fused iteration on one described chip, as compiled
+    text."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    on_described_chip(monkeypatch, topo)
     _, base = PRESETS[preset]
     fns = make_ppo(PPOConfig(**base, num_devices=1))
     monkeypatch.undo()
@@ -94,6 +100,23 @@ def unfused(text):
     return {k: v for k, v in comps.items() if k not in fused}
 
 
+def conv0_frame_inputs(rows):
+    """Results of the frame operands (``84,84``) of Conv_0's ``kOutput``
+    fusions (forward passes and weight gradient) among ``rows``, one
+    computation's instructions."""
+    results = {name: result for name, result, *_ in rows}
+    return [
+        results[operand]
+        for _, _, opcode, op_name, line in rows
+        if opcode == "fusion" and "kind=kOutput" in line
+        and "Conv_0/conv_general_dilated" in op_name
+        for operand in re.findall(
+            r"%([\w.\-]+)", line[line.index("fusion("):line.index("kind=")]
+        )
+        if "84,84" in results.get(operand, "")
+    ]
+
+
 def test_ppo_breakout_minibatch_loop_moves_no_observation(monkeypatch, topo):
     # PR 26: the env-sliced update reads a block-major arrangement made
     # once an iteration (data.rollout.env_blocks). Before it, each of
@@ -119,19 +142,82 @@ def test_ppo_breakout_minibatch_loop_moves_no_observation(monkeypatch, topo):
     # ... because Conv_0 converts for itself: its forward pass and its
     # weight gradient read the uint8 arrangement in place.
     (body,) = bodies
-    results = {name: result for name, result, *_ in comps[body]}
-    conv0_inputs = [
-        results[operand]
-        for _, _, opcode, op_name, line in comps[body]
-        if opcode == "fusion" and "kind=kOutput" in line
-        and "Conv_0/conv_general_dilated" in op_name
-        for operand in re.findall(
-            r"%([\w.\-]+)", line[line.index("fusion("):line.index("kind=")]
-        )
-        if "84,84" in results.get(operand, "")
-    ]
+    conv0_inputs = conv0_frame_inputs(comps[body])
     assert len(conv0_inputs) >= 2, conv0_inputs
     assert all(r.startswith("u8[") for r in conv0_inputs), conv0_inputs
+
+
+def learner_step_text(monkeypatch, topo, preset, **overrides):
+    """The preset's donated IMPALA ``learner_step`` on one described
+    chip, as compiled text, fed the trajectory batch its own actor
+    program produces (shapes only)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from actor_critic_algs_on_tensorflow_tpu.algos.impala import (
+        ActorTrajectory,
+        ImpalaConfig,
+        make_impala,
+    )
+
+    on_described_chip(monkeypatch, topo)
+    _, base = PRESETS[preset]
+    progs = make_impala(ImpalaConfig(**{**base, **overrides}, num_devices=1))
+    rollout, env_reset = progs.make_actor_programs(0)
+    monkeypatch.undo()
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree,
+        )
+
+    key = jax.random.PRNGKey(0)
+    state = jax.eval_shape(progs.init, key)
+    env_state, obs, carry = jax.eval_shape(env_reset, key)
+    traj = jax.eval_shape(
+        rollout, *on_chip((state.params, env_state, obs, carry, key))
+    )[3]
+    assert isinstance(traj, ActorTrajectory)
+    return progs.learner_step_donated.lower(
+        on_chip(state), on_chip(traj)
+    ).compile().as_text()
+
+
+def test_impala_learner_reads_its_batch_at_one_byte_a_pixel(monkeypatch, topo):
+    """PR 30: ``impala-pong``'s learner step at the benchmark cell's
+    ``[32, 512]`` batch. The ``[T, B]`` axes of the frames are merged
+    while they are uint8 and Conv_0 converts its own input. On the
+    parent (PR 29) this test finds exactly two offenders, both bf16
+    passes over the whole batch that compute nothing:
+    ``multiply_bitcast_fusion -> bf16[32,84,84,1,4,512]`` (the batch
+    written out converted) and ``copy.8`` of the same shape (the merge
+    done on that copy), 16.7 % of the chip's time (PERF.md section 6)."""
+    text = learner_step_text(
+        monkeypatch, topo, "impala-pong", envs_per_actor=512
+    )
+    (rows,) = [
+        rows for rows in unfused(text).values()
+        if any(op_name == "batch.obs" for *_, op_name, _ in rows)
+    ]
+    frames = [
+        (name, result, opcode) for name, result, opcode, _, _ in rows
+        if "84,84" in result and opcode not in FREE
+    ]
+    # (i) no pass over the frames in the compute dtype, or wider
+    wide = [f for f in frames if re.match(r"\(*(bf16|f32)\[", f[1])]
+    assert not wide, wide
+    # (ii) Conv_0 converts for itself: the forward pass over the batch,
+    # the one over the bootstrap observation and the weight gradient
+    # read uint8.
+    conv0_inputs = conv0_frame_inputs(rows)
+    assert len(conv0_inputs) >= 3, conv0_inputs
+    assert all(r.startswith("u8[") for r in conv0_inputs), conv0_inputs
+    # (iii) at most one uint8 pass besides the convolutions' own reads:
+    # what lands in fast memory (``S(1)``: the bootstrap observation's
+    # slices and their concatenation) is a prefetch, not a pass.
+    movers = [f for f in frames if "S(1)" not in f[1]]
+    assert len(movers) <= 1, movers
 
 
 # ---- the Qwen3-Next core's two new kernels at published widths (PR 27) ----
