@@ -24,9 +24,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from actor_critic_algs_on_tensorflow_tpu.data.rollout import Trajectory
 from actor_critic_algs_on_tensorflow_tpu.models import (
+    SEQUENCE_CORES,
     DiscreteActorCritic,
     GaussianActorCritic,
     RecurrentActorCritic,
+    sequence_core,
 )
 from actor_critic_algs_on_tensorflow_tpu.models.networks import scale_pixels
 from actor_critic_algs_on_tensorflow_tpu.ops import Categorical, DiagGaussian
@@ -66,8 +68,8 @@ class OnPolicyState:
     # step, every leaf with the env axis leading (sharded like obs). The
     # core owns its carry's shape: an LSTM's (c, h) each [B, lstm], or
     # per layer a DeltaNet state, a convolution's tail, a key/value
-    # cache and the position (models/qwen3_next.py). None for
-    # feed-forward policies.
+    # cache or a cache of latents, and the position (the sequence cores,
+    # models.SEQUENCE_CORES). None for feed-forward policies.
     carry: Any = None
 
 
@@ -186,8 +188,8 @@ def make_obs_prep(torso, compute_dtype):
     30)."""
     if torso == "nature_cnn":
         return lambda obs: scale_pixels(obs, jnp.dtype(compute_dtype))
-    # Every other torso converts its own input, and a token id
-    # (torso="qwen3_next") must reach the embedding as the integer it is.
+    # Every other torso converts its own input, and a token id (the
+    # sequence cores) must reach the embedding as the integer it is.
     return lambda obs: obs
 
 
@@ -213,12 +215,12 @@ def make_recurrent_policy_head(
     expert layer's; ``{}`` for the LSTM).
 
     The core is the LSTM over a torso (``RecurrentActorCritic``, carry
-    ``(c, h)``) or, with ``torso="qwen3_next"``, the model
-    ``seq_model`` describes (``models/qwen3_next.py``), whose carry
-    holds per layer a DeltaNet state or a key/value cache of
-    ``cache_len`` steps. ``model.replays_from_empty_carry`` says that
-    the sequence form (``T > 1``) starts every sequence from the empty
-    carry and reads neither ``carry`` nor ``resets``.
+    ``(c, h)``) or, with ``torso`` a name of ``models.SEQUENCE_CORES``,
+    that core's model as ``seq_model`` (its config) describes it, whose
+    carry holds per layer a state or a cache of ``cache_len`` steps.
+    ``model.replays_from_empty_carry`` says that the sequence form (``T
+    > 1``) starts every sequence from the empty carry and reads neither
+    ``carry`` nor ``resets``.
     """
     if not hasattr(action_space, "n"):
         raise ValueError(
@@ -226,18 +228,17 @@ def make_recurrent_policy_head(
             "(the continuous head is the MLP GaussianActorCritic); "
             "use recurrent=False for continuous-control envs"
         )
-    if torso == "qwen3_next":
-        from actor_critic_algs_on_tensorflow_tpu.models.qwen3_next import (
-            Qwen3NextActorCritic,
-        )
-
-        if seq_model is None or seq_model.vocab_size != action_space.n:
+    if torso in SEQUENCE_CORES:
+        core, core_config = sequence_core(torso)
+        if not isinstance(seq_model, core_config) or (
+            seq_model.vocab_size != action_space.n
+        ):
             raise ValueError(
-                "torso='qwen3_next' needs seq_model (a Qwen3NextConfig) "
-                "whose vocab_size is the env's number of actions "
-                f"({action_space.n}); got {seq_model!r}"
+                f"torso={torso!r} (models.SEQUENCE_CORES) needs seq_model, "
+                f"a {core_config.__name__} whose vocab_size is the env's "
+                f"number of actions ({action_space.n}); got {seq_model!r}"
             )
-        model = Qwen3NextActorCritic(
+        model = core(
             cfg=seq_model, cache_len=cache_len,
             dtype=jnp.dtype(compute_dtype),
         )

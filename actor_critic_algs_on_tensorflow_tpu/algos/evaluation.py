@@ -23,6 +23,7 @@ import numpy as np
 from actor_critic_algs_on_tensorflow_tpu import envs as envs_lib
 from actor_critic_algs_on_tensorflow_tpu.algos import common
 from actor_critic_algs_on_tensorflow_tpu.models import (
+    SEQUENCE_CORES,
     DeterministicActor,
     DiscreteActorCritic,
     GaussianActorCritic,
@@ -49,11 +50,12 @@ def _act_fn(algo: str, cfg, aspace, params, stochastic: bool, norm=None,
     """
     norm = norm if norm is not None else (lambda o: o)
     act_state0 = None
-    if getattr(cfg, "torso", None) == "qwen3_next":
+    if getattr(cfg, "torso", None) in SEQUENCE_CORES:
         raise NotImplementedError(
             "evaluation acts through RecurrentActorCritic's (c, h) carry; "
-            "acting with torso='qwen3_next' (its carry held per lane) is "
-            "not built yet (ROADMAP, Reach)"
+            f"acting with a sequence core (torso={cfg.torso!r}, one of "
+            f"models.SEQUENCE_CORES {sorted(SEQUENCE_CORES)}: its carry "
+            "held per lane) is not built yet (ROADMAP, Reach)"
         )
     if algo in ("a2c", "ppo", "impala") and getattr(cfg, "recurrent", False):
         model = RecurrentActorCritic(

@@ -37,6 +37,7 @@ from actor_critic_algs_on_tensorflow_tpu.data.rollout import (
     minibatch_iter_indices,
     take_minibatch,
 )
+from actor_critic_algs_on_tensorflow_tpu.models import SEQUENCE_CORES
 from actor_critic_algs_on_tensorflow_tpu.ops import (
     clipped_value_loss,
     gae_advantages,
@@ -109,12 +110,12 @@ class PPOConfig:
     # latter would need per-step carries for V(final_obs)).
     recurrent: bool = False
     lstm_size: int = 128
-    # torso="qwen3_next" (recurrent only): the sequence-policy core of
-    # models/qwen3_next.py in place of torso + LSTM. ``seq_model`` is
-    # its Qwen3NextConfig — every width, the share of experts and
-    # vocabulary held here, the dispatch buffer's factor
-    # (``--set seq_model.capacity_factor=...``). One episode is one
-    # rollout: the env's ``episode_length`` must equal
+    # A torso of models.SEQUENCE_CORES (recurrent only): that
+    # sequence-policy core in place of torso + LSTM. ``seq_model`` is
+    # its config (a Qwen3NextConfig, a KimiVLConfig) — every width, the
+    # share of experts and vocabulary held here, the dispatch buffer's
+    # factor (``--set seq_model.capacity_factor=...``). One episode is
+    # one rollout: the env's ``episode_length`` must equal
     # ``rollout_length``, so that every sequence the update replays
     # starts from an empty carry.
     seq_model: Any = None
@@ -197,10 +198,11 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 "recurrent PPO requires time_limit_bootstrap=False "
                 "(V(final_obs) would need the per-step carry)"
             )
-    if cfg.torso == "qwen3_next" and not cfg.recurrent:
+    if cfg.torso in SEQUENCE_CORES and not cfg.recurrent:
         raise ValueError(
-            "torso='qwen3_next' is a sequence-policy core: it needs "
-            "recurrent=True (its layers carry state across steps)"
+            f"torso={cfg.torso!r} is a sequence-policy core "
+            f"(models.SEQUENCE_CORES): it needs recurrent=True (its "
+            f"layers carry state across steps)"
         )
     common.check_host_env_topology(cfg.env, n_dev)
     env, env_params = envs_lib.make(
@@ -212,16 +214,6 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         params=cfg.env_params,
     )
     action_space = env.action_space(env_params)
-    if cfg.torso == "qwen3_next":
-        episode_length = getattr(env_params, "episode_length", None)
-        if episode_length != cfg.rollout_length:
-            raise ValueError(
-                "torso='qwen3_next' replays every sequence from an empty "
-                "carry, so one episode must be one rollout: the env's "
-                f"episode_length ({episode_length}) must equal "
-                f"rollout_length ({cfg.rollout_length}); resets inside a "
-                "sequence (packed episodes) are not supported"
-            )
     if cfg.recurrent:
         model, seq_dist_value = common.make_recurrent_policy_head(
             action_space,
@@ -238,6 +230,16 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
         replay_carry = not getattr(
             model, "replays_from_empty_carry", False
         )
+        episode_length = getattr(env_params, "episode_length", None)
+        if not replay_carry and episode_length != cfg.rollout_length:
+            raise ValueError(
+                f"torso={cfg.torso!r} replays every sequence from an "
+                "empty carry (its model's replays_from_empty_carry), so "
+                "one episode must be one rollout: the env's "
+                f"episode_length ({episode_length}) must equal "
+                f"rollout_length ({cfg.rollout_length}); resets inside a "
+                "sequence (packed episodes) are not supported"
+            )
     else:
         model, dist_and_value = common.make_policy_head(
             action_space,

@@ -81,6 +81,33 @@ def _qwen3_next_config(**kw):
     return Qwen3NextConfig(**kw)
 
 
+def _kimi_vl_config(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.models.kimi_vl import (
+        KimiVLConfig,
+    )
+
+    return KimiVLConfig(**kw)
+
+
+def _snapshot_fits(fns, cfg) -> bool:
+    """Whether a device has room for the sentinel's rollback target, a
+    second copy of the train state: three times the state within the
+    device's memory (the state, its copy, and for the iteration's own
+    temporaries, gradients first, as much again). The sequence-core
+    presets hold 8 GB of parameters and Adam moments on a 16 GB chip
+    and do not. A backend that reports no capacity (the CPU) has room."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    if not limit:
+        return True
+    state = jax.eval_shape(fns.init, jax.random.PRNGKey(cfg.seed))
+    nbytes = sum(x.size * x.dtype.itemsize
+                 for x in jax.tree_util.tree_leaves(state))
+    return 3 * nbytes <= limit
+
+
 def _token_recall_params(**kw):
     from actor_critic_algs_on_tensorflow_tpu.envs.token_recall import (
         TokenRecallParams,
@@ -410,6 +437,65 @@ PRESETS = {
                 shared_expert_intermediate_size=32, vocab_size=64,
                 first_expert=0, experts_held=2, capacity_factor=4.0,
                 chunk_size=8,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 8,
+            "rollout_length": 16,
+            "total_env_steps": 4_096,
+            "num_devices": 1,
+        },
+    ),
+    # 14. Token-level PPO with the language model of Kimi-VL-A3B as
+    # the policy, at the published widths, cut to one chip's share of a
+    # stated deployment: each layer shared by 8 chips, expert-parallel
+    # (8 of the 64 routed experts here, the whole router with its
+    # selection bias, both shared experts and the whole latent
+    # attention), the leading dense layer and 5 of the 26 expert layers
+    # (the rest lie on further chips as pipeline stages) and 1/8 of the
+    # vocabulary: 668.9 M parameters, 10.7 GB with gradients and Adam's
+    # moments. One episode is one sequence of 512 tokens on the
+    # token-recall env; the schedule (envs, epochs, minibatches,
+    # learning rate) is perfbench/traffic/recall-128x512-e1mb8.json's.
+    "ppo-kimivl-recall": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=20_480, delay=64, episode_length=512
+            ),
+            "torso": "kimi_vl",
+            "seq_model": _kimi_vl_config(
+                num_hidden_layers=6, vocab_size=20_480,
+                first_expert=0, experts_held=8, capacity_factor=2.0,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 128,
+            "rollout_length": 512,
+            "num_minibatches": 8,
+            "compute_dtype": "bfloat16",
+            "total_env_steps": 10_000_000,
+        },
+    ),
+    # The same model and schedule at widths for the CPU tests: hidden
+    # 64, 4 heads of 16 + 8 (rope) and 16 (value) over a latent of 32,
+    # a dense layer of width 128, then 2 expert layers of 8 experts
+    # top-2 of width 32 (2 held) with two shared experts, vocabulary 64.
+    "ppo-kimivl-tiny": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=64, delay=4, episode_length=16
+            ),
+            "torso": "kimi_vl",
+            "seq_model": _kimi_vl_config(
+                hidden_size=64, intermediate_size=128,
+                moe_intermediate_size=32, num_hidden_layers=3,
+                num_attention_heads=4, n_routed_experts=8,
+                num_experts_per_tok=2, kv_lora_rank=32,
+                qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16,
+                vocab_size=64, first_expert=0, experts_held=2,
+                capacity_factor=4.0,
             ),
             **_PPO_TOKEN_SCHEDULE,
             "num_envs": 8,
@@ -1765,7 +1851,13 @@ def _run(args, algo, cfg, writer) -> int:
     # on a trip instead of training — and checkpointing — NaNs. The
     # delayed check hides the guard fetch behind dispatch run-ahead.
     sentinel = None
-    if getattr(cfg, "numerics_guards", False):
+    if getattr(cfg, "numerics_guards", False) and not _snapshot_fits(
+        fns, cfg
+    ):
+        print("[train] sentinel: the train state does not fit a device "
+              "beside a rollback copy of itself; the health_finite guard "
+              "is logged, nothing is rolled back", flush=True)
+    elif getattr(cfg, "numerics_guards", False):
         import jax
 
         from actor_critic_algs_on_tensorflow_tpu.utils import (

@@ -1,0 +1,438 @@
+"""Kimi-VL-A3B's language model as a sequence-policy core: multi-head
+latent attention (MLA) as the mixer, a leading dense SwiGLU layer, then
+sigmoid-routed experts with two shared experts as every layer's MLP, a
+language-model head as the policy and a linear value head.
+
+Source of the layer equations: the published ``config.json`` of
+moonshotai/Kimi-VL-A3B-Instruct (the language model's keys; the
+DeepSeek-V3 layout) and the loader that reads it
+(``modeling_deepseek.py``: ``DeepseekV3Attention``, ``MoEGate``,
+``DeepseekV3MoE``, ``DeepseekV3RMSNorm``); DeepSeek-AI 2024,
+"DeepSeek-V2", arXiv:2405.04434 section 2.1 for latent attention and
+its absorbed form. Not here: the vision tower and its projector (the
+catalog gives no width of theirs), the auxiliary sequence-balance loss
+(``seq_aux``: the config gives no coefficient; the loss is the
+trainer's), and the update of the routing bias (below).
+
+The carry interface is ``models/qwen3_next.py``'s
+(``algos/common.py::make_recurrent_policy_head``): ``__call__(tokens
+[T, B] int32, resets [T, B], carry)`` returns ``(logits [T, B, V],
+values [T, B], carry, stats)``. The carry holds per layer ONE cache of
+latents ``[B, cache_len, kv_lora_rank + qk_rope_head_dim]`` in the
+compute dtype — a token's normalised latent ``c`` and its rotated rope
+key ``k^r``, shared by all heads: 576 numbers a token a layer where
+per-head keys and values would be 5,120 — and ``pos [B]``, the position
+since the episode began. Two forms of one layer share the parameters:
+
+* ``T == 1`` — the step form, decode: ``(c, k^r)`` is written at
+  ``pos`` and the query attends over the latents themselves, the key-up
+  projection absorbed into the query (``q^ = q^n W_UK^T``) and the
+  value-up projection applied after the weighted sum (``o = (sum_j a_j
+  c_j) W_UV``). No key or value of the cache is ever rebuilt, and the
+  cache is read as one operand for all heads. Where ``resets`` is set
+  the position is zeroed before the step (a row beyond the position is
+  never read).
+* ``T > 1`` — the sequence form, the teacher-forced pass: the latents
+  are expanded into per-head keys and values (``[k^n; v] = c W_kvb``)
+  and causal attention runs over the whole sequence from an EMPTY
+  carry, position 0 at step 0. ``carry`` and ``resets`` are not read
+  and the carry is handed back as it came
+  (``replays_from_empty_carry``).
+
+Rotary embedding: over the ``qk_rope_head_dim`` rope dimensions the
+INTERLEAVED pairs ``(x[2i], x[2i + 1])`` are rotated by ``pos *
+rope_theta^(-2i / d)``, as the published loader pairs them; the result
+is laid out as halves (all first elements, then all second ones) for
+queries and keys alike, which no dot product sees.
+
+Parameters are float32. Matrix products run in ``dtype`` with float32
+accumulation, and the cache, an input of such products, is held in
+``dtype``; the norms, the router (product, sigmoid, top-k, weights),
+the softmax and both heads' outputs are float32 whatever ``dtype``
+says. RMSNorm is the plain ``w * x / rms(x)``, ``w`` from 1.
+
+The expert layer is ``models/moe.py``'s, shared with Qwen3-Next; this
+model's ``route`` is the published ``noaux_tc`` gate at ``n_group =
+topk_group = 1``: sigmoid scores, the top-k of ``score + bias``, the
+weights the scores WITHOUT the bias, renormalised and scaled by
+``routed_scaling_factor``. The bias (``e_score_correction_bias``) is a
+parameter that no gradient reaches (it enters the selection only) and
+that nothing updates: the balancing rule's speed is a recipe value the
+config does not give. The two shared experts are one ungated SwiGLU of
+twice the routed width.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from actor_critic_algs_on_tensorflow_tpu.models import moe
+from actor_critic_algs_on_tensorflow_tpu.models.moe import mm as _mm
+from actor_critic_algs_on_tensorflow_tpu.utils import profiling
+
+_F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+_INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiVLConfig:
+    """The published keys of ``config.json`` (defaults: the A3B
+    language model's) and what this chip holds of them.
+    ``num_hidden_layers``, ``vocab_size`` and ``experts_held`` are the
+    held share; no other default differs from the source."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 11264
+    moe_intermediate_size: int = 1408
+    num_hidden_layers: int = 27
+    num_attention_heads: int = 16
+    n_shared_experts: int = 2
+    n_routed_experts: int = 64
+    routed_scaling_factor: float = 2.446
+    kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    qk_nope_head_dim: int = 128
+    topk_method: str = "noaux_tc"
+    n_group: int = 1
+    topk_group: int = 1
+    num_experts_per_tok: int = 6
+    moe_layer_freq: int = 1
+    first_k_dense_replace: int = 1
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 800000.0
+    vocab_size: int = 163840
+    # The share of the expert layer held here.
+    first_expert: int = 0
+    experts_held: int = 64
+    capacity_factor: float = 2.0
+
+    def __post_init__(self):
+        built = {"q_lora_rank": None, "topk_method": "noaux_tc",
+                 "n_group": 1, "topk_group": 1, "scoring_func": "sigmoid"}
+        for key, value in built.items():
+            if getattr(self, key) != value:
+                raise ValueError(
+                    f"KimiVLConfig.{key}={getattr(self, key)!r}: only "
+                    f"{value!r} is built (the published value)"
+                )
+        if not any(map(self.is_expert_layer, range(self.num_hidden_layers))):
+            raise ValueError("no expert layer among the layers held")
+
+    def is_expert_layer(self, layer: int) -> bool:
+        return (layer >= self.first_k_dense_replace
+                and layer % self.moe_layer_freq == 0)
+
+    @property
+    def expert_spec(self) -> moe.ExpertSpec:
+        return moe.ExpertSpec(
+            num_experts=self.n_routed_experts,
+            top_k=self.num_experts_per_tok,
+            first_expert=self.first_expert, experts_held=self.experts_held,
+            capacity_factor=self.capacity_factor,
+        )
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+def layer_param_spec(cfg: KimiVLConfig, layer: int):
+    """``{name: (shape, init)}`` of one decoder layer."""
+    H, w = cfg.hidden_size, nn.initializers.normal(_INIT_STD)
+    ones = nn.initializers.ones_init()
+    nh, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    spec = {
+        "input_norm": ((H,), ones), "post_norm": ((H,), ones),
+        "q_proj": ((H, nh * (dn + dr)), w),
+        "kv_a_proj": ((H, rank + dr), w), "kv_a_norm": ((rank,), ones),
+        "kv_b_proj": ((rank, nh * (dn + dv)), w),
+        "o_proj": ((nh * dv, H), w),
+    }
+    if not cfg.is_expert_layer(layer):
+        I = cfg.intermediate_size
+        spec.update(mlp_gate=((H, I), w), mlp_up=((H, I), w),
+                    mlp_down=((I, H), w))
+        return spec
+    I, E = cfg.moe_intermediate_size, cfg.experts_held
+    Is = cfg.n_shared_experts * I
+    spec.update(
+        router=((H, cfg.n_routed_experts), w),
+        e_score_correction_bias=((cfg.n_routed_experts,), w),
+        shared_w_gate=((H, Is), w), shared_w_up=((H, Is), w),
+        shared_w_down=((Is, H), w),
+        w_gate=((E, H, I), w), w_up=((E, H, I), w), w_down=((E, I, H), w),
+    )
+    return spec
+
+
+# ---- pieces ------------------------------------------------------------
+
+
+def rms_norm(x, w, eps):
+    """RMSNorm, float32: ``x * rsqrt(mean(x^2) + eps) * w``."""
+    x = x.astype(_F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def _angles(positions, cfg: KimiVLConfig):
+    """``positions [...]`` -> the rotation angles ``[..., d_rope / 2]``."""
+    d = cfg.qk_rope_head_dim
+    inv_freq = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    return positions.astype(_F32)[..., None] * inv_freq
+
+
+def _rope(x, angles):
+    """Rotate the interleaved pairs of ``x [..., d_rope]`` by ``angles``
+    (broadcast against ``[..., d_rope / 2]``); halves come out."""
+    first, second = x[..., 0::2], x[..., 1::2]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return jnp.concatenate(
+        [first * cos - second * sin, second * cos + first * sin], -1
+    )
+
+
+# ---- latent attention ---------------------------------------------------
+
+
+def _dot(spec, a, b, dtype):
+    """A product of attention (``einsum``) in ``dtype``, float32 sums."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=_F32)
+
+
+def _mla_project(p, x, angles, cfg, dtype):
+    """``x [..., H]``, ``angles [..., d_rope / 2]`` -> the query's two
+    parts ``q^n [..., nh, d_nope]`` and ``q^r [..., nh, d_rope]``
+    (rotated), the normalised latent ``c [..., rank]`` and the rotated
+    rope key ``k^r [..., d_rope]``, float32."""
+    nh, rank, dn = (cfg.num_attention_heads, cfg.kv_lora_rank,
+                    cfg.qk_nope_head_dim)
+    q = _mm(x, p["q_proj"], dtype).reshape(x.shape[:-1] + (nh, -1))
+    kva = _mm(x, p["kv_a_proj"], dtype)
+    c = rms_norm(kva[..., :rank], p["kv_a_norm"], cfg.rms_norm_eps)
+    return (q[..., :dn], _rope(q[..., dn:], angles[..., None, :]), c,
+            _rope(kva[..., rank:], angles))
+
+
+def _softmax_scale(cfg):
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def mla_seq(p, x, cfg, dtype):
+    """The expanded form: ``x [T, b, H]``, causal over the sequence,
+    position = step; per-head keys and values from the latents."""
+    T, b, _ = x.shape
+    nh, dn = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    q_nope, q_rope, c, k_rope = _mla_project(
+        p, jnp.swapaxes(x, 0, 1), _angles(jnp.arange(T), cfg), cfg, dtype
+    )
+    kv = _mm(c, p["kv_b_proj"], dtype).reshape(b, T, nh, -1)
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+
+    # q . [k^n; k^r] with the one rope key shared by the heads
+    scores = _softmax_scale(cfg) * (
+        _dot("bqhd,bshd->bhqs", q_nope, k_nope, dtype)
+        + _dot("bqhd,bsd->bhqs", q_rope, k_rope, dtype)
+    )
+    scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = _dot("bhqs,bshd->bqhd", probs, v, dtype)
+    return jnp.swapaxes(_mm(out.reshape(b, T, -1), p["o_proj"], dtype), 0, 1)
+
+
+def mla_step(p, x, cache, pos, cfg, dtype):
+    """The absorbed form, one token an env: ``x [B, H]``, ``cache [B,
+    L, rank + d_rope]``, ``pos [B]``. The token's latent and rope key
+    are written at ``pos``; the query, carried into the latent space,
+    attends over the cache's rows up to ``pos``; rows beyond it are
+    masked."""
+    B, L, _ = cache.shape
+    nh, rank, dn = (cfg.num_attention_heads, cfg.kv_lora_rank,
+                    cfg.qk_nope_head_dim)
+    q_nope, q_rope, c, k_rope = _mla_project(
+        p, x, _angles(pos, cfg), cfg, dtype
+    )
+
+    with jax.named_scope(profiling.MLA_ABSORBED):
+        row = jnp.concatenate([c, k_rope], -1).astype(cache.dtype)
+        cache = cache.at[jnp.arange(B), pos].set(
+            row, indices_are_sorted=True, unique_indices=True
+        )
+        w_kvb = p["kv_b_proj"].reshape(rank, nh, -1)
+        w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
+        q_latent = _dot("bhn,chn->bhc", q_nope, w_uk, dtype)
+        # one operand for all heads: [q^; q^r] . [c_j; k^r_j]
+        scores = _dot(
+            "bhc,blc->bhl", jnp.concatenate([q_latent, q_rope], -1), cache,
+            dtype,
+        ) * _softmax_scale(cfg)
+        visible = jnp.arange(L)[None, :] <= pos[:, None]
+        scores = jnp.where(visible[:, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        # The weighted sum over the whole row; the rope key's columns
+        # come out too and are dropped (a slice of the cache would be a
+        # copy of it).
+        o_latent = _dot("bhl,blc->bhc", probs, cache, dtype)[..., :rank]
+        out = _dot("bhc,chv->bhv", o_latent, w_uv, dtype)
+    return _mm(out.reshape(B, -1), p["o_proj"], dtype), cache
+
+
+# ---- the expert block --------------------------------------------------
+
+
+def route(p, x, cfg):
+    """``x [N, H]`` -> the top-k experts ``[N, k]`` of ALL
+    ``n_routed_experts`` and their weights, float32 throughout: the
+    bias chooses, the scores weigh."""
+    logits = jnp.dot(x.astype(_F32), p["router"], precision=_HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    bias = jax.lax.stop_gradient(p["e_score_correction_bias"])
+    _, experts = jax.lax.top_k(scores + bias, cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, experts, -1)
+    if cfg.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return experts, weights * cfg.routed_scaling_factor
+
+
+def routed_experts(p, x, cfg: KimiVLConfig, dtype):
+    """``x [N, H]`` -> ``(y [N, H], stats)``: the held experts' terms
+    of the routed sum under this model's ``route``."""
+    return moe.routed_experts(
+        p, x, cfg.expert_spec, dtype, functools.partial(route, cfg=cfg)
+    )
+
+
+def shared_experts(p, x, dtype):
+    """The ``n_shared_experts`` as one ungated SwiGLU of their summed
+    width: what every chip computes alike."""
+    with jax.named_scope(profiling.MOE_SHARED):
+        return moe.swiglu(x, p["shared_w_gate"], p["shared_w_up"],
+                          p["shared_w_down"], dtype)
+
+
+def moe_block(p, x, cfg: KimiVLConfig, dtype):
+    """``x [N, H]`` -> ``(y [N, H], stats)``: the held experts' terms
+    of the routed sum plus the shared experts."""
+    routed, stats = routed_experts(p, x, cfg, dtype)
+    return routed + shared_experts(p, x, dtype), stats
+
+
+# ---- the model ---------------------------------------------------------
+
+
+def _feed_forward(p, x, cfg, dtype, expert: bool):
+    """``x + F(N(x))`` on ``x [..., H]``: the expert block (with its
+    counters) or the leading dense SwiGLU (``None``)."""
+    if expert:
+        with jax.named_scope(profiling.MOE):
+            h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+            y, stats = moe_block(p, h.reshape(-1, x.shape[-1]), cfg, dtype)
+        return x + y.reshape(x.shape), stats
+    with jax.named_scope(profiling.DENSE_MLP):
+        h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+        y = moe.swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"], dtype)
+    return x + y, None
+
+
+def _decoder_layer_seq(p, x, cfg, dtype, expert: bool):
+    with jax.named_scope(profiling.MLA):
+        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+        x = x + mla_seq(p, h, cfg, dtype)
+    return _feed_forward(p, x, cfg, dtype, expert)
+
+
+def _decoder_layer_step(p, x, cache, pos, cfg, dtype, expert: bool):
+    with jax.named_scope(profiling.MLA):
+        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+        y, cache = mla_step(p, h, cache, pos, cfg, dtype)
+    x, stats = _feed_forward(p, x + y, cfg, dtype, expert)
+    return x, cache, stats
+
+
+class KimiVLActorCritic(nn.Module):
+    """The policy over ``cfg.vocab_size`` tokens and the value."""
+
+    cfg: KimiVLConfig
+    cache_len: int
+    dtype: Any = jnp.float32
+    # The sequence form reads neither carry nor resets (see above).
+    replays_from_empty_carry = True
+    # (rollout rows, update rows, axis) -> an iteration's counters
+    iteration_stats = staticmethod(moe.iteration_moe_stats)
+
+    @nn.compact
+    def __call__(self, tokens, resets, carry):
+        cfg, dtype = self.cfg, jnp.dtype(self.dtype)
+        H = cfg.hidden_size
+        w = nn.initializers.normal(_INIT_STD)
+        embedding = self.param("embedding", w, (cfg.vocab_size, H), _F32)
+        layers = [
+            moe.Params(tuple(layer_param_spec(cfg, i).items()),
+                       name=f"layer_{i}")()
+            for i in range(cfg.num_hidden_layers)
+        ]
+        final_norm = self.param(
+            "final_norm", nn.initializers.ones_init(), (H,), _F32
+        )
+        lm_head = self.param("lm_head", w, (H, cfg.vocab_size), _F32)
+        value_w = self.param("value_w", w, (H,), _F32)
+        value_b = self.param(
+            "value_b", nn.initializers.zeros_init(), (), _F32
+        )
+
+        x = jnp.take(embedding, tokens.astype(jnp.int32), axis=0)
+        all_stats = []
+        if tokens.shape[0] == 1:
+            keep = 1.0 - resets[0].astype(_F32)
+            pos = (carry["pos"] * keep).astype(jnp.int32)
+            x, caches = x[0], []
+            for i, p in enumerate(layers):
+                x, cache, stats = _decoder_layer_step(
+                    p, x, carry["layers"][i], pos, cfg, dtype,
+                    cfg.is_expert_layer(i),
+                )
+                caches.append(cache)
+                all_stats.append(stats)
+            x = x[None]
+            carry = {"layers": caches, "pos": pos + 1}
+        else:
+            for i, p in enumerate(layers):
+                # Each layer is recomputed in the backward pass, as in
+                # qwen3_next.py: kept, the activations of 8,192 tokens
+                # do not fit beside the weights and Adam's moments.
+                layer = jax.checkpoint(
+                    lambda p, x, e=cfg.is_expert_layer(i): (
+                        _decoder_layer_seq(p, x, cfg, dtype, e)
+                    )
+                )
+                x, stats = layer(p, x)
+                all_stats.append(stats)
+        with jax.named_scope(profiling.LM_HEAD):
+            h = rms_norm(x, final_norm, cfg.rms_norm_eps)
+            logits = _mm(h, lm_head, dtype)
+        values = jnp.dot(h, value_w, precision=_HIGHEST) + value_b
+        stats = moe.stack_layer_stats(
+            [s for s in all_stats if s is not None]
+        )
+        return logits, values, carry, stats
+
+    def initialize_carry(self, batch: int) -> Dict[str, Any]:
+        """The empty carry for ``batch`` environments."""
+        shape = (batch, self.cache_len, self.cfg.cache_width)
+        return {
+            "layers": [jnp.zeros(shape, jnp.dtype(self.dtype))
+                       for _ in range(self.cfg.num_hidden_layers)],
+            "pos": jnp.zeros((batch,), jnp.int32),
+        }

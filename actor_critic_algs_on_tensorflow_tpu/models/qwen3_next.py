@@ -43,28 +43,30 @@ accumulation; the norms, the router (product, softmax, top-k), the
 DeltaNet's gates, state and chunk products, the attention's softmax
 and both heads' outputs are float32 whatever ``dtype`` says.
 
-The expert layer is told which experts it holds (``first_expert``,
-``experts_held`` of ``num_experts``): it routes over all of them,
-keeps the published top-k and its renormalisation, and computes only
-the terms of its own experts; what the absent ones would add is left
-out. The (token, expert) pairs that land here are sorted by expert
-into one buffer of ``moe_capacity(tokens)`` rows, shared by the held
-experts, the products run grouped over it (``lax.ragged_dot``), and the
-pairs that did not fit are counted (``stats["moe_overflow_pairs"]``),
-as are the held experts a call gave no row at all
-(``stats["moe_experts_touched_share"]``: their weights are not read).
+The expert layer is ``models/moe.py``'s, shared with the other sequence
+cores: it is told which experts it holds (``first_expert``,
+``experts_held`` of ``num_experts``), routes over all of them with this
+model's ``route`` (softmax, the published top-k and its
+renormalisation), computes only the terms of its own experts in one
+dropless dispatch buffer and counts what did not fit; the sigmoid-gated
+shared expert is this model's own.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from actor_critic_algs_on_tensorflow_tpu.models import moe
+from actor_critic_algs_on_tensorflow_tpu.models.moe import (
+    mm as _mm,
+    swiglu as _expert_ffn,
+)
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
@@ -110,12 +112,16 @@ class Qwen3NextConfig:
     def is_attention(self, layer: int) -> bool:
         return (layer + 1) % self.full_attention_interval == 0
 
+    @property
+    def expert_spec(self) -> moe.ExpertSpec:
+        return moe.ExpertSpec(
+            num_experts=self.num_experts, top_k=self.num_experts_per_tok,
+            first_expert=self.first_expert, experts_held=self.experts_held,
+            capacity_factor=self.capacity_factor,
+        )
+
     def moe_capacity(self, tokens: int) -> int:
-        expected = (tokens * self.num_experts_per_tok * self.experts_held
-                    / self.num_experts)
-        rows = min(math.ceil(self.capacity_factor * expected),
-                   tokens * self.num_experts_per_tok)
-        return max(8, -(-rows // 8) * 8)
+        return self.expert_spec.moe_capacity(tokens)
 
 
 # ---- parameters --------------------------------------------------------
@@ -163,24 +169,7 @@ def layer_param_spec(cfg: Qwen3NextConfig, layer: int):
     return spec
 
 
-class _Params(nn.Module):
-    """A named group of float32 parameters."""
-
-    spec: Any  # {name: (shape, init)}, hashable as a tuple of items
-
-    @nn.compact
-    def __call__(self):
-        return {name: self.param(name, init, shape, _F32)
-                for name, (shape, init) in self.spec}
-
-
 # ---- pieces ------------------------------------------------------------
-
-
-def _mm(x, w, dtype):
-    """A matrix product in ``dtype`` with float32 accumulation."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=_F32)
 
 
 def rms_norm(x, w, eps):
@@ -476,62 +465,12 @@ def route(p, x, cfg):
     return experts, weights
 
 
-def _expert_ffn(x, w_gate, w_up, w_down, dtype):
-    return _mm(jax.nn.silu(_mm(x, w_gate, dtype)) * _mm(x, w_up, dtype),
-               w_down, dtype)
-
-
 def routed_experts(p, x, cfg: Qwen3NextConfig, dtype):
     """``x [N, H]`` -> ``(y [N, H], stats)``: the held experts' terms
-    of the routed sum, dropless within the dispatch buffer."""
-    N, k, held = x.shape[0], cfg.num_experts_per_tok, cfg.experts_held
-    with jax.named_scope(profiling.MOE_ROUTER):
-        experts, weights = route(p, x, cfg)
-    with jax.named_scope(profiling.MOE_DISPATCH):
-        # Pairs sorted by local expert, the other chips' last; the
-        # first `rows` of that order are the buffer.
-        local = experts.reshape(-1) - cfg.first_expert
-        mine = (local >= 0) & (local < held)
-        local = jnp.where(mine, local, held)
-        rows = cfg.moe_capacity(N)
-        order = jnp.argsort(local, stable=True)[:rows]
-        row_expert = local[order]
-        row_valid = row_expert < held
-        row_token = order // k
-        group_sizes = jnp.bincount(row_expert, length=held + 1)[:held]
-        group_sizes = group_sizes.astype(jnp.int32)
-        row_weight = jnp.where(row_valid, weights.reshape(-1)[order], 0.0)
-        # What ragged_dot leaves in rows past the last group is not
-        # specified: they go in as zeros and come out masked, so that
-        # neither they nor their gradient reach a token.
-        xs = jnp.where(
-            row_valid[:, None], jnp.take(x, row_token, axis=0), 0.0
-        ).astype(dtype)
-        n_mine = jnp.sum(mine)
-        kept = jnp.sum(row_valid)
-    with jax.named_scope(profiling.MOE_EXPERTS):
-        def grouped(a, w):
-            return jax.lax.ragged_dot(
-                a.astype(dtype), w.astype(dtype), group_sizes,
-                preferred_element_type=_F32,
-            )
-
-        h = jax.nn.silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
-        ys = grouped(h, p["w_down"])
-        ys = jnp.where(row_valid[:, None], ys, 0.0) * row_weight[:, None]
-    with jax.named_scope(profiling.MOE_DISPATCH):
-        routed = jnp.zeros((N, x.shape[1]), _F32).at[row_token].add(ys)
-    load = group_sizes.astype(_F32)
-    stats = {
-        "moe_local_pairs_per_token": n_mine.astype(_F32) / N,
-        "moe_expert_load_max_over_mean":
-            jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
-        "moe_overflow_pairs": (n_mine - kept).astype(_F32),
-        # the held experts this call gave a row: the ones whose weights
-        # the grouped products read
-        "moe_experts_touched_share": jnp.mean((group_sizes > 0).astype(_F32)),
-    }
-    return routed, stats
+    of the routed sum under this model's ``route``."""
+    return moe.routed_experts(
+        p, x, cfg.expert_spec, dtype, functools.partial(route, cfg=cfg)
+    )
 
 
 def shared_expert(p, x, dtype):
@@ -548,45 +487,6 @@ def moe_block(p, x, cfg: Qwen3NextConfig, dtype):
     of the routed sum plus the shared expert."""
     routed, stats = routed_experts(p, x, cfg, dtype)
     return routed + shared_expert(p, x, dtype), stats
-
-
-def reduce_moe_stats(stats):
-    """One row of counters from many (layers, steps, minibatches, any
-    leading axes): mean pairs a token, max imbalance, summed overflow,
-    mean share of the held experts a call touched."""
-    return {
-        "moe_local_pairs_per_token":
-            jnp.mean(stats["moe_local_pairs_per_token"]),
-        "moe_expert_load_max_over_mean":
-            jnp.max(stats["moe_expert_load_max_over_mean"]),
-        "moe_overflow_pairs": jnp.sum(stats["moe_overflow_pairs"]),
-        "moe_experts_touched_share":
-            jnp.mean(stats["moe_experts_touched_share"]),
-    }
-
-
-def iteration_moe_stats(rollout_stats, update_stats, axis_name):
-    """The expert layer's counters of one training iteration,
-    replicated over ``axis_name``: pairs a token and load imbalance as
-    the update saw them, overflow summed over the rollout's steps and
-    the update's blocks (it must be 0), the held experts touched as
-    the rollout's steps saw it (nearly every call of the grouped
-    products is one of them)."""
-    roll, upd = map(reduce_moe_stats, (rollout_stats, update_stats))
-    return {
-        "moe_local_pairs_per_token": jax.lax.pmean(
-            upd["moe_local_pairs_per_token"], axis_name
-        ),
-        "moe_expert_load_max_over_mean": jax.lax.pmax(
-            upd["moe_expert_load_max_over_mean"], axis_name
-        ),
-        "moe_overflow_pairs": jax.lax.psum(
-            roll["moe_overflow_pairs"] + upd["moe_overflow_pairs"], axis_name
-        ),
-        "moe_experts_touched_share": jax.lax.pmean(
-            roll["moe_experts_touched_share"], axis_name
-        ),
-    }
 
 
 # ---- the model ---------------------------------------------------------
@@ -631,7 +531,7 @@ class Qwen3NextActorCritic(nn.Module):
     # The sequence form reads neither carry nor resets (see above).
     replays_from_empty_carry = True
     # (rollout rows, update rows, axis) -> an iteration's counters
-    iteration_stats = staticmethod(iteration_moe_stats)
+    iteration_stats = staticmethod(moe.iteration_moe_stats)
 
     @nn.compact
     def __call__(self, tokens, resets, carry):
@@ -642,7 +542,7 @@ class Qwen3NextActorCritic(nn.Module):
             "embedding", _normal(std), (cfg.vocab_size, H), _F32
         )
         layers = [
-            _Params(tuple(layer_param_spec(cfg, i).items()),
+            moe.Params(tuple(layer_param_spec(cfg, i).items()),
                     name=f"layer_{i}")()
             for i in range(cfg.num_hidden_layers)
         ]
@@ -683,8 +583,7 @@ class Qwen3NextActorCritic(nn.Module):
             h = rms_norm(x, final_norm, cfg.rms_norm_eps)
             logits = _mm(h, lm_head, dtype)
         values = jnp.dot(h, value_w, precision=_HIGHEST) + value_b
-        stats = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *all_stats)
-        return logits, values, carry, reduce_moe_stats(stats)
+        return logits, values, carry, moe.stack_layer_stats(all_stats)
 
     def initialize_carry(self, batch: int) -> Dict[str, Any]:
         """The empty carry for ``batch`` environments."""

@@ -40,7 +40,8 @@ UPDATE = "update"                  # everything that trains
 MINIBATCH_PREP = "minibatch_prep"  # in update: slice, convert, relayout
 LOSS_GRAD = "loss_grad"            # in update: forward + backward
 OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
-# Layers of the sequence-policy core (models/qwen3_next.py), inside
+# Layers of the sequence-policy cores (models/qwen3_next.py,
+# models/kimi_vl.py; the expert block is models/moe.py's), inside
 # policy_act and loss_grad; read like the phases, listed apart.
 GDN = "gdn"                        # a Gated DeltaNet mixer
 GDN_STATE = "gdn_state"            # in gdn: the step form's state update
@@ -51,8 +52,12 @@ MOE_DISPATCH = "moe_dispatch"      # in moe: sort, gather, combine
 MOE_EXPERTS = "moe_experts"        # in moe: the grouped products
 MOE_SHARED = "moe_shared"          # in moe: the shared expert
 LM_HEAD = "lm_head"                # final norm, logits, log-prob, entropy
+MLA = "mla"                        # a latent-attention mixer, both forms
+MLA_ABSORBED = "mla_absorbed"      # in mla: the step form over the cache
+DENSE_MLP = "dense_mlp"            # a leading dense layer's feed-forward
 LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
-                MOE_EXPERTS, MOE_SHARED, LM_HEAD)
+                MOE_EXPERTS, MOE_SHARED, LM_HEAD, MLA, MLA_ABSORBED,
+                DENSE_MLP)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)
 

@@ -531,3 +531,38 @@ def test_eval_return_hist_formatting():
     assert format_return_hist(np.asarray([-7.0, -7.0])) == (
         "[eval] return_hist -7:2"
     )
+
+
+@pytest.mark.parametrize("limit,fits,says", [
+    (None, True, False),            # a backend that reports no capacity
+    (2**40, True, False),           # room for the state three times
+    (2**16, False, True),           # the state alone is more than that
+])
+def test_sentinel_snapshot_only_where_the_state_fits_twice(
+    monkeypatch, capsys, limit, fits, says
+):
+    """The rollback target is a second copy of the train state: where
+    the device cannot hold it (the sequence-core presets on one chip)
+    the run goes on with the guard bit logged and no snapshot, instead
+    of dying in ``sentinel.seed``."""
+    import types
+
+    import jax
+
+    device = types.SimpleNamespace(memory_stats=lambda: (
+        None if limit is None else {"bytes_limit": limit}
+    ))
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [device])
+    rc = cli.main(["--preset", "a2c-cartpole", "--total-steps", "640",
+                   "--set", "num_envs=8"])
+    out = capsys.readouterr().out
+    assert rc == 0 and "[train] done" in out
+    assert ("nothing is rolled back" in out) == says
+    assert ("health_finite=1" in out)
+    from actor_critic_algs_on_tensorflow_tpu.algos.a2c import (
+        A2CConfig,
+        make_a2c,
+    )
+
+    cfg = A2CConfig(env="CartPole-v1", num_envs=8)
+    assert cli._snapshot_fits(make_a2c(cfg), cfg) == fits

@@ -219,6 +219,41 @@ def test_compiled_program_carries_its_phases(
     assert not any("NatureCNN" in n for n in bare)
 
 
+def test_the_kimi_vl_iteration_carries_its_layer_scopes(
+    metadata_in_cache_key
+):
+    """The layers ``models/kimi_vl.py`` names — the latent-attention
+    mixer, its absorbed step inside it, the leading dense layer's
+    feed-forward — and the shared expert layer's four, in the compiled
+    text of the tiny preset's fused iteration, nested as declared."""
+    from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
+    from actor_critic_algs_on_tensorflow_tpu.utils.profiling import (
+        DENSE_MLP, LM_HEAD, MLA, MLA_ABSORBED, MOE, MOE_DISPATCH,
+        MOE_EXPERTS, MOE_ROUTER, MOE_SHARED,
+    )
+
+    assert {MLA, MLA_ABSORBED, DENSE_MLP} <= set(profiling.LAYER_SCOPES)
+    fns = make_ppo(PPOConfig(**PRESETS["ppo-kimivl-tiny"][1]))
+    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
+    table = profiling.scope_table(
+        fns.iteration.lower(state).compile().as_text()
+    )
+    lists = {p for p in table.values() if p}
+    found = {phase for p in lists for phase in p}
+    assert {MLA, MLA_ABSORBED, DENSE_MLP, MOE, MOE_ROUTER, MOE_DISPATCH,
+            MOE_EXPERTS, MOE_SHARED, LM_HEAD} <= found
+    for phases in lists:
+        if MLA_ABSORBED in phases:
+            # the step form: in the mixer, in an acting pass, never in
+            # the update's differentiated pass
+            assert MLA in phases[:phases.index(MLA_ABSORBED)], phases
+            assert LOSS_GRAD not in phases, phases
+        if DENSE_MLP in phases:
+            assert MLA not in phases and MOE not in phases, phases
+    assert any(MLA in p and LOSS_GRAD in p for p in lists)
+    assert any(MLA_ABSORBED in p and POLICY_ACT in p for p in lists)
+
+
 @pytest.mark.parametrize("op_name,phases", [
     ("jit(local_iteration)/rollout/while/body/closed_call/env_step/add",
      (ROLLOUT, ENV_STEP)),

@@ -326,3 +326,43 @@ def test_the_decode_step_is_the_one_pass_kernel_in_place(one_chip):
     state = B * h * d * d * 4
     assert memory.alias_size_in_bytes == state
     assert memory.temp_size_in_bytes < state // 16
+
+
+def test_the_absorbed_step_reads_the_latents_and_rebuilds_no_key(one_chip):
+    """Latent attention's step form at the timed shape (128 envs, a
+    cache of 512 rows of 512 + 64, 16 heads), lowered for the described
+    v5e: no instruction holds a per-head key or value of the cache
+    (``[128, 512, 16, ...]`` in any order), the donated cache is
+    written in place (a scatter of 128 rows, no pass over it), and
+    beside it the program holds less than a tenth of it."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import kimi_vl as kv
+
+    cfg = PRESETS["ppo-kimivl-recall"][1]["seq_model"]
+    B, L, H = 128, 512, cfg.hidden_size
+    assert cfg.cache_width == 576
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    spec = kv.layer_param_spec(cfg, 1)
+    names = ("q_proj", "kv_a_proj", "kv_a_norm", "kv_b_proj", "o_proj")
+    p = {n: arr(spec[n][0]) for n in names}
+
+    def step(p, x, cache, pos):
+        return kv.mla_step(p, x, cache, pos, cfg, jnp.bfloat16)
+
+    compiled = jax.jit(step, donate_argnums=2).lower(
+        p, arr((B, H)), arr((B, L, 576), jnp.bfloat16), arr((B,), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    shapes = {tuple(map(int, dims.split(",")))
+              for dims in re.findall(r"[a-z]\d*\[([\d,]+)\]", text)}
+    per_head = [s for s in shapes if len(s) >= 4 and {128, 512, 16} <= set(s)]
+    assert not per_head, per_head
+    assert re.search(r"bf16\[128,512,576\]\S* scatter\(", text)
+    cache = B * L * 576 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == cache
+    assert memory.temp_size_in_bytes < cache // 10
