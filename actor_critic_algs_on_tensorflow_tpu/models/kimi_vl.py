@@ -22,16 +22,27 @@ latents ``[B, cache_len, kv_lora_rank + qk_rope_head_dim]`` in the
 compute dtype — a token's normalised latent ``c`` and its rotated rope
 key ``k^r``, shared by all heads: 576 numbers a token a layer where
 per-head keys and values would be 5,120 — and ``pos [B]``, the position
-since the episode began. Two forms of one layer share the parameters:
+since the episode began. The layers' caches are ONE array ``[B,
+layers, cache_len, 576]`` (``carry["layers"][:, i]`` is layer ``i``'s;
+the env axis leads, as the trainer shards every leaf of a carry): each
+layer writes its row into it in place and reads its own part. As six
+arrays of 75 MB the TPU's compiler staged each whole through VMEM and
+back around its scatter, every step (PERF.md section 6, PR 32); the
+one array is larger than VMEM and stays where it is. Two forms of one
+layer share the parameters:
 
 * ``T == 1`` — the step form, decode: ``(c, k^r)`` is written at
   ``pos`` and the query attends over the latents themselves, the key-up
   projection absorbed into the query (``q^ = q^n W_UK^T``) and the
   value-up projection applied after the weighted sum (``o = (sum_j a_j
   c_j) W_UV``). No key or value of the cache is ever rebuilt, and the
-  cache is read as one operand for all heads. Where ``resets`` is set
-  the position is zeroed before the step (a row beyond the position is
-  never read).
+  cache is read as one operand for all heads: lowered for a TPU at the
+  published widths by one Pallas kernel that reads each row up to
+  ``pos`` once and none beyond its chunk (``ops/pallas_mla_step.py``),
+  anywhere else by two plain products over the whole cache; nothing
+  but the lowering platform and the cache's shape chooses. Where
+  ``resets`` is set the position is zeroed before the step (a row
+  beyond the position is never read).
 * ``T > 1`` — the sequence form, the teacher-forced pass: the latents
   are expanded into per-head keys and values (``[k^n; v] = c W_kvb``)
   and causal attention runs over the whole sequence from an EMPTY
@@ -79,6 +90,9 @@ from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 _INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
+# The step form's counter: the share of a cache's rows a step read, the
+# same in every layer (1 where the plain form ran).
+CACHE_ROWS_READ = "mla_cache_rows_read_share"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -252,13 +266,79 @@ def mla_seq(p, x, cfg, dtype):
     return jnp.swapaxes(_mm(out.reshape(b, T, -1), p["o_proj"], dtype), 0, 1)
 
 
-def mla_step(p, x, cache, pos, cfg, dtype):
-    """The absorbed form, one token an env: ``x [B, H]``, ``cache [B,
-    L, rank + d_rope]``, ``pos [B]``. The token's latent and rope key
-    are written at ``pos``; the query, carried into the latent space,
+def latent_attention(q, cache, pos, scale, rank, dtype):
+    """The core of the absorbed form in plain array operations: ``q
+    [B, h, rank + d_rope]`` over ``cache [B, L, rank + d_rope]``'s rows
+    ``0..pos [B]``, rows beyond masked: ``[B, h, rank]`` float32. Two
+    passes over the whole cache (scores, then the weighted sum)."""
+    L = cache.shape[1]
+    # one operand for all heads: [q^; q^r] . [c_j; k^r_j]
+    scores = _dot("bhc,blc->bhl", q, cache, dtype) * scale
+    visible = jnp.arange(L)[None, :] <= pos[:, None]
+    scores = jnp.where(visible[:, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    # The weighted sum over the whole row; the rope key's columns come
+    # out too and are dropped (a slice of the cache would be a copy of
+    # it).
+    return _dot("bhl,blc->bhc", probs, cache, dtype)[..., :rank]
+
+
+def _kernel_or_plain(caches, rank, kernel, plain, *operands):
+    """``kernel(pallas_mla_step, *operands)`` where the program is
+    lowered for a TPU and ``caches [B, layers, L, rank + d_rope]`` take
+    the one-pass kernel (the latent part whole lane tiles, the rows
+    whole chunks), ``plain(*operands)`` anywhere else: nothing but the
+    lowering platform and the caches' shape chooses."""
+    # Imported where it is used: Pallas is ~1.5 s of imports, and
+    # cli/train.py's PRESETS import this module for every preset.
+    from actor_critic_algs_on_tensorflow_tpu.ops import pallas_mla_step
+
+    if not pallas_mla_step.fits(caches, rank):
+        return plain(*operands)
+    return jax.lax.platform_dependent(
+        *operands, tpu=functools.partial(kernel, pallas_mla_step),
+        default=plain,
+    )
+
+
+def _latent_attention(q, caches, layer, pos, scale, rank, dtype):
+    """``latent_attention`` over layer ``layer``'s cache in ``caches``:
+    the one-pass kernel over the rows that exist
+    (``ops/pallas_mla_step.py``), or the plain form, which reads every
+    row."""
+
+    def kernel(ops, q, caches, pos):
+        return ops.latent_attention(
+            q, caches, layer, pos, scale=scale, rank=rank
+        )
+
+    def plain(q, caches, pos):
+        return latent_attention(q, caches[:, layer], pos, scale, rank, dtype)
+
+    return _kernel_or_plain(caches, rank, kernel, plain, q, caches, pos)
+
+
+def _rows_read_share(caches, pos, rank):
+    """The share of a cache's rows that a step at ``pos`` read, the same
+    in every layer: what the kernel's index map fetches, or every row."""
+
+    def kernel(ops, pos):
+        return ops.rows_read_share(pos, caches.shape[2])
+
+    def plain(pos):
+        return jnp.ones((), _F32)
+
+    return _kernel_or_plain(caches, rank, kernel, plain, pos)
+
+
+def mla_step(p, x, caches, layer, pos, cfg, dtype):
+    """The absorbed form, one token an env: ``x [B, H]``, ``caches
+    [B, layers, L, rank + d_rope]`` of which this is layer ``layer`` (a
+    Python int), ``pos [B]``. The token's latent and rope key are
+    written at ``pos``; the query, carried into the latent space,
     attends over the cache's rows up to ``pos``; rows beyond it are
-    masked."""
-    B, L, _ = cache.shape
+    never read into the result."""
+    B = caches.shape[0]
     nh, rank, dn = (cfg.num_attention_heads, cfg.kv_lora_rank,
                     cfg.qk_nope_head_dim)
     q_nope, q_rope, c, k_rope = _mla_project(
@@ -266,27 +346,19 @@ def mla_step(p, x, cache, pos, cfg, dtype):
     )
 
     with jax.named_scope(profiling.MLA_ABSORBED):
-        row = jnp.concatenate([c, k_rope], -1).astype(cache.dtype)
-        cache = cache.at[jnp.arange(B), pos].set(
+        row = jnp.concatenate([c, k_rope], -1).astype(caches.dtype)
+        caches = caches.at[jnp.arange(B), layer, pos].set(
             row, indices_are_sorted=True, unique_indices=True
         )
         w_kvb = p["kv_b_proj"].reshape(rank, nh, -1)
         w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
         q_latent = _dot("bhn,chn->bhc", q_nope, w_uk, dtype)
-        # one operand for all heads: [q^; q^r] . [c_j; k^r_j]
-        scores = _dot(
-            "bhc,blc->bhl", jnp.concatenate([q_latent, q_rope], -1), cache,
-            dtype,
-        ) * _softmax_scale(cfg)
-        visible = jnp.arange(L)[None, :] <= pos[:, None]
-        scores = jnp.where(visible[:, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        # The weighted sum over the whole row; the rope key's columns
-        # come out too and are dropped (a slice of the cache would be a
-        # copy of it).
-        o_latent = _dot("bhl,blc->bhc", probs, cache, dtype)[..., :rank]
+        o_latent = _latent_attention(
+            jnp.concatenate([q_latent, q_rope], -1), caches, layer, pos,
+            _softmax_scale(cfg), rank, dtype,
+        )
         out = _dot("bhc,chv->bhv", o_latent, w_uv, dtype)
-    return _mm(out.reshape(B, -1), p["o_proj"], dtype), cache
+    return _mm(out.reshape(B, -1), p["o_proj"], dtype), caches
 
 
 # ---- the expert block --------------------------------------------------
@@ -353,12 +425,23 @@ def _decoder_layer_seq(p, x, cfg, dtype, expert: bool):
     return _feed_forward(p, x, cfg, dtype, expert)
 
 
-def _decoder_layer_step(p, x, cache, pos, cfg, dtype, expert: bool):
+def _decoder_layer_step(p, x, caches, layer, pos, cfg, dtype, expert: bool):
     with jax.named_scope(profiling.MLA):
         h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-        y, cache = mla_step(p, h, cache, pos, cfg, dtype)
+        y, caches = mla_step(p, h, caches, layer, pos, cfg, dtype)
     x, stats = _feed_forward(p, x + y, cfg, dtype, expert)
-    return x, cache, stats
+    return x, caches, stats
+
+
+def iteration_stats(rollout_stats, update_stats, axis_name):
+    """The expert layer's counters of one training iteration and, of
+    the rollout's steps (the update runs the expanded form, which has
+    no cache), the mean share of the cache's rows a step read."""
+    stats = moe.iteration_moe_stats(rollout_stats, update_stats, axis_name)
+    stats[CACHE_ROWS_READ] = jax.lax.pmean(
+        jnp.mean(rollout_stats[CACHE_ROWS_READ]), axis_name
+    )
+    return stats
 
 
 class KimiVLActorCritic(nn.Module):
@@ -370,7 +453,7 @@ class KimiVLActorCritic(nn.Module):
     # The sequence form reads neither carry nor resets (see above).
     replays_from_empty_carry = True
     # (rollout rows, update rows, axis) -> an iteration's counters
-    iteration_stats = staticmethod(moe.iteration_moe_stats)
+    iteration_stats = staticmethod(iteration_stats)
 
     @nn.compact
     def __call__(self, tokens, resets, carry):
@@ -397,13 +480,12 @@ class KimiVLActorCritic(nn.Module):
         if tokens.shape[0] == 1:
             keep = 1.0 - resets[0].astype(_F32)
             pos = (carry["pos"] * keep).astype(jnp.int32)
-            x, caches = x[0], []
+            x, caches = x[0], carry["layers"]
             for i, p in enumerate(layers):
-                x, cache, stats = _decoder_layer_step(
-                    p, x, carry["layers"][i], pos, cfg, dtype,
+                x, caches, stats = _decoder_layer_step(
+                    p, x, caches, i, pos, cfg, dtype,
                     cfg.is_expert_layer(i),
                 )
-                caches.append(cache)
                 all_stats.append(stats)
             x = x[None]
             carry = {"layers": caches, "pos": pos + 1}
@@ -426,13 +508,17 @@ class KimiVLActorCritic(nn.Module):
         stats = moe.stack_layer_stats(
             [s for s in all_stats if s is not None]
         )
+        if tokens.shape[0] == 1:
+            stats[CACHE_ROWS_READ] = _rows_read_share(
+                carry["layers"], pos, cfg.kv_lora_rank
+            )
         return logits, values, carry, stats
 
     def initialize_carry(self, batch: int) -> Dict[str, Any]:
         """The empty carry for ``batch`` environments."""
-        shape = (batch, self.cache_len, self.cfg.cache_width)
+        shape = (batch, self.cfg.num_hidden_layers, self.cache_len,
+                 self.cfg.cache_width)
         return {
-            "layers": [jnp.zeros(shape, jnp.dtype(self.dtype))
-                       for _ in range(self.cfg.num_hidden_layers)],
+            "layers": jnp.zeros(shape, jnp.dtype(self.dtype)),
             "pos": jnp.zeros((batch,), jnp.int32),
         }

@@ -31,6 +31,7 @@ from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (  # noqa: E402
 )
 from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.models import kimi_vl as kv  # noqa: E402
+from actor_critic_algs_on_tensorflow_tpu.ops import pallas_mla_step  # noqa: E402
 from perfbench.reference import kimi_vl as ref  # noqa: E402
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
 
@@ -135,23 +136,56 @@ def test_each_step_below_the_stated_precision_is_another_function(lower):
 # 2. the step form through the cache of latents -----------------------------
 
 
-def _stepwise(model, params, tokens, resets):
+# The kernel's rows a grid step and envs a block in these tests, and a
+# cache of whole chunks that holds the T rows.
+CHUNK, BLOCK, CACHE_LEN = 8, 2, 24
+
+
+@pytest.fixture(params=["plain", "kernel"])
+def attention(request, monkeypatch):
+    """The step form's scores, softmax and weighted sum as the CPU runs
+    them, and once more through the TPU's one-pass kernel in the Pallas
+    interpreter (what ``kv._latent_attention`` picks where the program
+    is lowered for a TPU at the published widths). Gives the cache's
+    length to build the model with."""
+    if request.param == "plain":
+        return T
+
+    def kernel(q, caches, layer, pos, scale, rank, dtype):
+        return pallas_mla_step.latent_attention(
+            q, caches, layer, pos, scale=scale, rank=rank,
+            block_envs=BLOCK, chunk=CHUNK, interpret=True,
+        )
+
+    def rows_read(caches, pos, rank):
+        return pallas_mla_step.rows_read_share(
+            pos, caches.shape[2], block_envs=BLOCK, chunk=CHUNK
+        )
+
+    monkeypatch.setattr(kv, "_latent_attention", kernel)
+    monkeypatch.setattr(kv, "_rows_read_share", rows_read)
+    return CACHE_LEN
+
+
+def _stepwise(model, params, tokens, resets, stats=None):
     carry = model.initialize_carry(tokens.shape[1])
     step = jax.jit(model.apply)
     logits, values = [], []
     for t in range(tokens.shape[0]):
-        lg, v, carry, _ = step(
+        lg, v, carry, row = step(
             params, tokens[t:t + 1], resets[t:t + 1], carry
         )
         logits.append(lg[0])
         values.append(v[0])
+        if stats is not None:
+            stats.append(row)
     return jnp.stack(logits), jnp.stack(values), carry
 
 
-def test_stepping_through_the_cache_equals_the_sequence_pass():
+def test_stepping_through_the_cache_equals_the_sequence_pass(attention):
     """The absorbed form over the cache of latents, a token at a time,
     is the expanded causal pass: float32, tight."""
-    model = _model()
+    model = _model(cache_len=attention)
     params, tokens = _init(model), _tokens()
     seq_logits, seq_values, _, _ = model.apply(
         params, tokens, jnp.zeros((T, B)), None
@@ -162,15 +196,18 @@ def test_stepping_through_the_cache_equals_the_sequence_pass():
     np.testing.assert_allclose(logits, seq_logits, atol=2e-5)
     np.testing.assert_allclose(values, seq_values, atol=2e-5)
     assert np.asarray(carry["pos"]).tolist() == [T] * B
-    # a layer's cache: T rows of latent + rope key an env, no more
-    cache = carry["layers"][0]
-    assert cache.shape == (B, T, CFG.kv_lora_rank + CFG.qk_rope_head_dim)
-    assert len(carry["layers"]) == CFG.num_hidden_layers
+    # a layer's cache: rows of latent + rope key an env, no more; the
+    # layers' caches are one array
+    # array, the envs leading as the trainer shards a carry
+    assert carry["layers"].shape == (
+        B, CFG.num_hidden_layers, attention,
+        CFG.kv_lora_rank + CFG.qk_rope_head_dim,
+    )
 
 
-def test_a_reset_mid_way_is_a_fresh_start():
+def test_a_reset_mid_way_is_a_fresh_start(attention):
     cut = 8
-    model = _model()
+    model = _model(cache_len=attention)
     params, tokens = _init(model), _tokens()
     resets = jnp.zeros((T, B)).at[cut, 1].set(1.0)
     logits, values, _ = _stepwise(model, params, tokens, resets)
@@ -190,6 +227,37 @@ def test_a_reset_mid_way_is_a_fresh_start():
     assert float(jnp.max(jnp.abs(logits[cut:, 1] - whole_logits[cut:, 1]))) > 1e-3
 
 
+def test_the_step_form_counts_the_rows_it_read(attention):
+    """``mla_cache_rows_read_share`` of a step's counters: 1 where the
+    plain form ran (it reads every row), and through the kernel what its
+    index map fetches, at lock-step positions the chunks up to the one
+    that holds ``pos`` over the cache's length; a reset env reads one
+    chunk again, but its block still fetches what its neighbour needs."""
+    model = _model(cache_len=attention)
+    params, tokens = _init(model, batch=4), _tokens(B=4)
+    resets = jnp.zeros((T, 4)).at[10, 1].set(1.0).at[17, 2:].set(1.0)
+    stats = []
+    _stepwise(model, params, tokens, resets, stats)
+    shares = [float(row[kv.CACHE_ROWS_READ]) for row in stats]
+    assert all(0.0 < share <= 1.0 for share in shares)
+    assert all(set(row) >= {kv.CACHE_ROWS_READ, "moe_overflow_pairs"}
+               for row in stats)
+    if attention == T:
+        assert shares == [1.0] * T
+        return
+    chunks = [t // CHUNK + 1 for t in range(T)]
+    # env 1 starts over at step 10 but shares its block with env 0;
+    # envs 2 and 3, a block of their own, start over at step 17
+    chunks[17:] = [(t // CHUNK + 1 + (t - 17) // CHUNK + 1) / 2
+                   for t in range(17, T)]
+    np.testing.assert_allclose(
+        shares, np.asarray(chunks) * CHUNK / CACHE_LEN, rtol=1e-6
+    )
+    # the sequence form has no cache and no such counter
+    _, _, _, seq_stats = model.apply(params, tokens, jnp.zeros((T, 4)), None)
+    assert kv.CACHE_ROWS_READ not in seq_stats
+
+
 def _shapes(jaxpr):
     """Every intermediate's shape, through nested jaxprs."""
     for eqn in jaxpr.eqns:
@@ -202,11 +270,11 @@ def _shapes(jaxpr):
 @pytest.mark.parametrize("form", ["step", "sequence"])
 def test_the_step_form_never_rebuilds_a_key_or_a_value(form):
     """Nothing the step form computes has a per-head key or value of
-    the cache in it: no intermediate is larger than the cache of
-    latents itself, where a rebuilt key ``[B, L, heads, d_nope]`` would
-    be ``heads * d_nope / (rank + d_rope)`` = 1.6 times it (3.6 times at
-    the published widths). The expanded form, for comparison, does hold
-    them."""
+    the cache in it: no intermediate but the layers' caches themselves
+    (one array) is larger than a layer's cache of latents, where a
+    rebuilt key ``[B, L, heads, d_nope]`` would be ``heads * d_nope /
+    (rank + d_rope)`` = 1.6 times it (3.6 times at the published
+    widths). The expanded form, for comparison, does hold them."""
     L, batch = 32, 5
     model = _model(cache_len=L)
     params = _init(model, batch=batch)
@@ -218,7 +286,8 @@ def test_the_step_form_never_rebuilds_a_key_or_a_value(form):
     else:
         args = (_tokens(L, batch), jnp.zeros((L, batch)), None)
     shapes = list(_shapes(jax.make_jaxpr(model.apply)(params, *args).jaxpr))
-    largest = max(int(np.prod(s)) for s in shapes)
+    caches = (batch, CFG.num_hidden_layers, L, CFG.cache_width)
+    largest = max(int(np.prod(s)) for s in shapes if s != caches)
     per_head = [s for s in shapes if len(s) == 4 and set(s[:3]) == {batch, L, nh}]
     if form == "step":
         assert largest <= cache, largest
@@ -434,6 +503,8 @@ def test_a_short_run_trains_and_counts():
     assert float(metrics["moe_overflow_pairs"]) == 0.0
     assert 0.0 < float(metrics["moe_local_pairs_per_token"]) < 2.0
     assert 0.0 < float(metrics["moe_experts_touched_share"]) <= 1.0
+    # the plain form ran (the CPU, a narrow cache): every row, every step
+    assert float(metrics[kv.CACHE_ROWS_READ]) == 1.0
     assert float(metrics["episodes"]) == cfg.num_envs
     assert fns.steps_per_iteration == cfg.num_envs * cfg.rollout_length
     moved = jax.tree_util.tree_map(
@@ -446,6 +517,31 @@ def test_a_short_run_trains_and_counts():
             assert (change == 0.0) == (leaf == "e_score_correction_bias"), (
                 name, leaf, change
             )
+
+
+def test_the_carry_is_sharded_by_env_on_two_devices():
+    """The trainer shards every leaf of a carry on its leading axis as
+    the env axis (``common.state_specs``): on two devices each holds
+    its half of the envs, ALL layers of their caches, and ``init`` and
+    an iteration run (the tiny preset pins one device, and the
+    benchmark's cell has one chip: nothing else sees a mesh)."""
+    cfg = PPOConfig(**dict(TINY, num_devices=2))
+    fns = make_ppo(cfg)
+    state = fns.init(jax.random.PRNGKey(2))
+    model = cfg.seq_model
+    whole = (cfg.num_envs, model.num_hidden_layers, cfg.rollout_length,
+             model.cache_width)
+    for _ in range(2):
+        core = state.carry["core"]
+        assert core["layers"].shape == whole
+        assert core["layers"].sharding.shard_shape(whole) == (
+            cfg.num_envs // 2,) + whole[1:]
+        assert core["pos"].sharding.shard_shape(core["pos"].shape) == (
+            cfg.num_envs // 2,)
+        state, metrics = fns.iteration(state)
+    assert np.isfinite(float(metrics["loss"]))
+    assert float(metrics["episodes"]) == cfg.num_envs
+    assert float(metrics[kv.CACHE_ROWS_READ]) == 1.0
 
 
 # 6. dispatch by capability --------------------------------------------------

@@ -329,40 +329,105 @@ def test_the_decode_step_is_the_one_pass_kernel_in_place(one_chip):
 
 
 def test_the_absorbed_step_reads_the_latents_and_rebuilds_no_key(one_chip):
-    """Latent attention's step form at the timed shape (128 envs, a
-    cache of 512 rows of 512 + 64, 16 heads), lowered for the described
-    v5e: no instruction holds a per-head key or value of the cache
-    (``[128, 512, 16, ...]`` in any order), the donated cache is
-    written in place (a scatter of 128 rows, no pass over it), and
-    beside it the program holds less than a tenth of it."""
+    """Latent attention's step form at the timed shape (128 envs, six
+    layers' caches of 512 rows of 512 + 64 in one array, 16 heads) as
+    the rollout runs it: in a loop that carries the donated caches,
+    lowered for the described v5e. No instruction holds a per-head key
+    or value of the cache (``[128, 512, 16, ...]`` in any order), the
+    caches are written in place (a scatter of 128 rows, no pass over
+    them), and beside them the program holds less than a tenth of one
+    layer's. Scores, softmax and weighted sum are the one-pass kernel
+    and nothing else: one custom call, no conditional left of the
+    choice by platform, no ``[128, 16, 512]`` float32 scores, and
+    beside the scatter and the kernel no instruction that takes the
+    caches: no copy, no staging through VMEM.
+
+    The caches come and go in the layout the loop holds them in, rows
+    of 576 minor: the chip's own choice for a parameter of this shape
+    puts the 512 rows minor, which is one transposing copy in and one
+    out of the PROGRAM (an iteration, not a step), outside the loop."""
     import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
 
     from actor_critic_algs_on_tensorflow_tpu.models import kimi_vl as kv
 
     cfg = PRESETS["ppo-kimivl-recall"][1]["seq_model"]
-    B, L, H = 128, 512, cfg.hidden_size
-    assert cfg.cache_width == 576
+    B, L, H, layers = 128, 512, cfg.hidden_size, cfg.num_hidden_layers
+    steps = 3
+    assert cfg.cache_width == 576 and layers == 6
 
-    def arr(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    def arr(shape, dtype=jnp.float32, sharding=one_chip):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     spec = kv.layer_param_spec(cfg, 1)
     names = ("q_proj", "kv_a_proj", "kv_a_norm", "kv_b_proj", "o_proj")
     p = {n: arr(spec[n][0]) for n in names}
+    rows_minor = Format(
+        Layout(major_to_minor=(0, 1, 2, 3), tiling=((8, 128), (2, 1))),
+        one_chip,
+    )
 
-    def step(p, x, cache, pos):
-        return kv.mla_step(p, x, cache, pos, cfg, jnp.bfloat16)
+    def rollout(p, xs, caches, pos):
+        def step(carry, x):
+            caches, pos = carry
+            y, caches = kv.mla_step(p, x, caches, 1, pos, cfg, jnp.bfloat16)
+            return (caches, pos + 1), y
 
-    compiled = jax.jit(step, donate_argnums=2).lower(
-        p, arr((B, H)), arr((B, L, 576), jnp.bfloat16), arr((B,), jnp.int32)
+        (caches, pos), ys = jax.lax.scan(step, (caches, pos), xs)
+        return ys, caches, pos
+
+    compiled = jax.jit(
+        rollout, donate_argnums=2,
+        out_shardings=(one_chip, rows_minor, one_chip),
+    ).lower(
+        p, arr((steps, B, H)),
+        arr((B, layers, L, 576), jnp.bfloat16, rows_minor),
+        arr((B,), jnp.int32),
     ).compile()
     text = compiled.as_text()
+    assert " while(" in text
     shapes = {tuple(map(int, dims.split(",")))
               for dims in re.findall(r"[a-z]\d*\[([\d,]+)\]", text)}
     per_head = [s for s in shapes if len(s) >= 4 and {128, 512, 16} <= set(s)]
     assert not per_head, per_head
-    assert re.search(r"bf16\[128,512,576\]\S* scatter\(", text)
+    assert re.search(r"bf16\[128,6,512,576\]\S* scatter\(", text)
     cache = B * L * 576 * 2
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes == cache
+    # the caches as the chip holds them: rows of 576 in 5 lane tiles
+    assert memory.alias_size_in_bytes == layers * cache * 640 // 576
     assert memory.temp_size_in_bytes < cache // 10
+
+    kernels = re.findall(
+        r"%(mla_absorbed_step[\w.]*) = .*custom_call_target=\"tpu_custom_call\"",
+        text,
+    )
+    assert len(kernels) == 1, kernels
+    assert " conditional(" not in text
+    # The plain form's scores [B, heads, L] (L = the latent's width
+    # here, so the kernel's own result has the shape; no fusion, dot or
+    # reduce may).
+    scores = [
+        (name, op) for name, shape, op in INSTRUCTION.findall(text)
+        if shape.startswith("f32[128,16,512]") and op not in FREE
+    ]
+    assert [op for _, op in scores] == ["custom-call"], scores
+    # Who takes the caches, outside fused computations, in the whole
+    # program: the scatter's fusion (in place) and the kernel, in the
+    # loop's body. A layer's own 75 MB array went to VMEM and back
+    # around them every step; an array of six layers is larger than
+    # VMEM.
+    takers = sorted(
+        (opcode, comp.startswith("main"))
+        for comp, rows in unfused(text).items()
+        for _, _, opcode, _, line in rows
+        if opcode not in FREE | {"while"} and any(
+            result.startswith("bf16[128,6,512,576]")
+            for name, result, *_ in rows
+            if re.search(rf"%{re.escape(name)}\b",
+                         line[line.index(opcode + "("):])
+        )
+    )
+    assert takers == [("custom-call", False), ("fusion", False)], takers
+    staged = [line for line in text.splitlines()
+              if "-start(" in line and "[128,6,512,576]" in line]
+    assert not staged, staged
