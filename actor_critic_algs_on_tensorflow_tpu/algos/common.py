@@ -31,7 +31,11 @@ from actor_critic_algs_on_tensorflow_tpu.models import (
     sequence_core,
 )
 from actor_critic_algs_on_tensorflow_tpu.models.networks import scale_pixels
-from actor_critic_algs_on_tensorflow_tpu.ops import Categorical, DiagGaussian
+from actor_critic_algs_on_tensorflow_tpu.ops import (
+    BlockReveal,
+    Categorical,
+    DiagGaussian,
+)
 from actor_critic_algs_on_tensorflow_tpu.parallel.mesh import (
     DATA_AXIS,
     device_count,
@@ -217,10 +221,14 @@ def make_recurrent_policy_head(
     The core is the LSTM over a torso (``RecurrentActorCritic``, carry
     ``(c, h)``) or, with ``torso`` a name of ``models.SEQUENCE_CORES``,
     that core's model as ``seq_model`` (its config) describes it, whose
-    carry holds per layer a state or a cache of ``cache_len`` steps.
+    carry holds per layer a state or a cache of ``cache_len`` tokens.
     ``model.replays_from_empty_carry`` says that the sequence form (``T
     > 1``) starts every sequence from the empty carry and reads neither
-    ``carry`` nor ``resets``.
+    ``carry`` nor ``resets``. ``model.reveals_blocks`` says that a step
+    is a denoising pass over a block of tokens: observations and actions
+    are ``[T, B, block_length]``, the logits ``[T, B, block_length, V]``,
+    and the distribution is ``BlockReveal`` over the observed block (the
+    action space is then a ``TokenBlock`` of the model's block).
     """
     if not hasattr(action_space, "n"):
         raise ValueError(
@@ -230,24 +238,40 @@ def make_recurrent_policy_head(
         )
     if torso in SEQUENCE_CORES:
         core, core_config = sequence_core(torso)
-        if not isinstance(seq_model, core_config) or (
-            seq_model.vocab_size != action_space.n
+        # What the model acts on (one token, or a block of them with a
+        # mask id) is what the env's action space holds.
+        def acts_on(x):
+            return (getattr(x, "block_length", None),
+                    getattr(x, "mask_token_id", None))
+
+        if (
+            not isinstance(seq_model, core_config)
+            or seq_model.vocab_size != action_space.n
+            or acts_on(seq_model) != acts_on(action_space)
         ):
             raise ValueError(
                 f"torso={torso!r} (models.SEQUENCE_CORES) needs seq_model, "
                 f"a {core_config.__name__} whose vocab_size is the env's "
-                f"number of actions ({action_space.n}); got {seq_model!r}"
+                f"number of actions ({action_space.n}) and whose block and "
+                f"mask id, if it acts on blocks, are the action space's "
+                f"({action_space!r}); got {seq_model!r}"
             )
         model = core(
             cfg=seq_model, cache_len=cache_len,
             dtype=jnp.dtype(compute_dtype),
         )
+        reveals_blocks = getattr(model, "reveals_blocks", False)
 
         def seq_dist_value(params, obs_tb, resets_tb, carry):
             logits, values, carry, stats = model.apply(
                 params, obs_tb, resets_tb, carry
             )
-            return Categorical(logits), values, carry, stats
+            if reveals_blocks:
+                dist = BlockReveal(logits, obs_tb, seq_model.reveal,
+                                   seq_model.mask_token_id)
+            else:
+                dist = Categorical(logits)
+            return dist, values, carry, stats
 
         return model, seq_dist_value
     model = RecurrentActorCritic(

@@ -222,7 +222,12 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
             lstm_size=cfg.lstm_size,
             compute_dtype=cfg.compute_dtype,
             seq_model=cfg.seq_model,
-            cache_len=cfg.rollout_length,
+            # a cache row a token: one a step, unless the env says that
+            # an episode commits another number (a step that is a pass
+            # over a block: envs/block_turns.py)
+            cache_len=getattr(
+                env_params, "tokens_per_episode", cfg.rollout_length
+            ),
         )
         dist_and_value = None
         # A core whose sequence form starts from the empty carry needs

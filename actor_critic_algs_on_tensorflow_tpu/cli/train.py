@@ -89,6 +89,12 @@ def _kimi_vl_config(**kw):
     return KimiVLConfig(**kw)
 
 
+def _sdar_config(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.models.sdar import SDARConfig
+
+    return SDARConfig(**kw)
+
+
 def _snapshot_fits(fns, cfg) -> bool:
     """Whether a device has room for the sentinel's rollback target, a
     second copy of the train state: three times the state within the
@@ -114,6 +120,14 @@ def _token_recall_params(**kw):
     )
 
     return TokenRecallParams(**kw)
+
+
+def _block_turns_params(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.envs.block_turns import (
+        BlockTurnsParams,
+    )
+
+    return BlockTurnsParams(**kw)
 
 
 # Token-level PPO as RL fine-tuning runs it: one episode one sequence,
@@ -500,6 +514,72 @@ PRESETS = {
             **_PPO_TOKEN_SCHEDULE,
             "num_envs": 8,
             "rollout_length": 16,
+            "total_env_steps": 4_096,
+            "num_devices": 1,
+        },
+    ),
+    # 15. Token-level PPO with SDAR-30B-A3B-Chat as the policy, a
+    # language model that generates by diffusion over blocks, at the
+    # published widths, cut to one chip's share of a stated deployment:
+    # each layer shared by 8 chips, expert-parallel (16 of the 128
+    # routed experts here, the whole router and the whole grouped-query
+    # attention), 6 of the 48 identical layers (the rest lie on further
+    # chips as pipeline stages) and 1/8 of the vocabulary, its last row
+    # the mask token: 645.6 M parameters, 10.3 GB with gradients and
+    # Adam's moments. An env step is one pass over a block of 4
+    # positions: 24 turns of 6 passes (the env's block, 4 denoising
+    # passes over the policy's, its commit) are one episode, one
+    # sequence of 144 passes that commits 192 tokens; the schedule is
+    # perfbench/traffic/turns-128x144-b4k4-e1mb8.json's.
+    "ppo-sdar-turns": (
+        "ppo",
+        {
+            "env": "BlockTurnsTPU-v0",
+            "env_params": _block_turns_params(
+                vocab_size=18_992, block_length=4, denoise_steps=4,
+                turns=24, delay_turns=4,
+            ),
+            "torso": "sdar",
+            "seq_model": _sdar_config(
+                num_hidden_layers=6, vocab_size=18_992,
+                mask_token_id=18_991, first_expert=0, experts_held=16,
+                # the update's buffer (the rollout's holds every pair):
+                # 10 of a turn's 24 positions are mask tokens, which
+                # route alike; 4.0 holds them at all 8 of their experts
+                # (PERF.md section 6, PR 33)
+                capacity_factor=4.0, block_length=4, denoising_steps=4,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 128,
+            "rollout_length": 144,
+            "num_minibatches": 8,
+            "compute_dtype": "bfloat16",
+            "total_env_steps": 10_000_000,
+        },
+    ),
+    # The same model and schedule at widths for the CPU tests: hidden
+    # 64, 4 query / 2 key-value heads of 16, 2 layers of 8 experts
+    # top-2 of width 32 (4 held), vocabulary 64, 4 turns.
+    "ppo-sdar-tiny": (
+        "ppo",
+        {
+            "env": "BlockTurnsTPU-v0",
+            "env_params": _block_turns_params(
+                vocab_size=64, block_length=4, denoise_steps=4, turns=4,
+                delay_turns=1,
+            ),
+            "torso": "sdar",
+            "seq_model": _sdar_config(
+                hidden_size=64, intermediate_size=192, num_hidden_layers=2,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                num_experts=8, num_experts_per_tok=2,
+                moe_intermediate_size=32, vocab_size=64, mask_token_id=63,
+                first_expert=0, experts_held=4, capacity_factor=4.0,
+                block_length=4, denoising_steps=4,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 8,
+            "rollout_length": 24,
             "total_env_steps": 4_096,
             "num_devices": 1,
         },

@@ -4,6 +4,10 @@
 for a named environment.
 """
 
+from actor_critic_algs_on_tensorflow_tpu.envs.block_turns import (  # noqa: F401
+    BlockTurns,
+    BlockTurnsParams,
+)
 from actor_critic_algs_on_tensorflow_tpu.envs.breakout import (  # noqa: F401
     BreakoutParams,
     BreakoutTPU,
@@ -17,6 +21,7 @@ from actor_critic_algs_on_tensorflow_tpu.envs.core import (  # noqa: F401
     Box,
     Discrete,
     JaxEnv,
+    TokenBlock,
 )
 from actor_critic_algs_on_tensorflow_tpu.envs.pendulum import (  # noqa: F401
     Pendulum,
@@ -51,6 +56,7 @@ from actor_critic_algs_on_tensorflow_tpu.envs.wrappers import (  # noqa: F401
 )
 
 _REGISTRY = {
+    "BlockTurnsTPU-v0": BlockTurns,
     "BreakoutTPU-v0": BreakoutTPU,
     "CartPole-v1": CartPole,
     "CartPoleMasked-v1": CartPoleMasked,

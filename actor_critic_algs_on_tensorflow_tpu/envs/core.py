@@ -57,6 +57,21 @@ class Discrete:
         return jax.random.randint(key, (), 0, self.n)
 
 
+@struct.dataclass
+class TokenBlock:
+    """Blocks of ``block_length`` token ids of ``{0, ..., n-1}``,
+    ``mask_token_id`` marking a position not yet revealed: what a
+    policy that generates by diffusion over blocks observes and hands
+    back (field names as ``models/sdar.py::SDARConfig`` has them)."""
+
+    n: int = struct.field(pytree_node=False, default=2)
+    block_length: int = struct.field(pytree_node=False, default=1)
+    mask_token_id: int = struct.field(pytree_node=False, default=1)
+
+    def sample(self, key: jax.Array) -> jax.Array:
+        return jax.random.randint(key, (self.block_length,), 0, self.n)
+
+
 class JaxEnv(Generic[TEnvState, TParams]):
     """Base class for pure-functional environments."""
 

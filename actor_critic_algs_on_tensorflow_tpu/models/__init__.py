@@ -24,6 +24,7 @@ from actor_critic_algs_on_tensorflow_tpu.models.networks import (  # noqa: F401
 SEQUENCE_CORES = {
     "qwen3_next": ("qwen3_next", "Qwen3NextActorCritic", "Qwen3NextConfig"),
     "kimi_vl": ("kimi_vl", "KimiVLActorCritic", "KimiVLConfig"),
+    "sdar": ("sdar", "SDARActorCritic", "SDARConfig"),
 }
 
 

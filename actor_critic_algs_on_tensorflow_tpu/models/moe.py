@@ -1,8 +1,8 @@
 """The expert layer the sequence cores share (``models/qwen3_next.py``,
-``models/kimi_vl.py``): a chip's share of a routed mixture of SwiGLU
-experts, dropless within one dispatch buffer, with its counters; and
-the two small pieces both cores build their layers from (``mm``,
-``Params``).
+``models/kimi_vl.py``, ``models/sdar.py``): a chip's share of a routed
+mixture of SwiGLU experts, dropless within one dispatch buffer, with its
+counters; and the small pieces the cores build their layers from
+(``mm``, ``Params``, the softmax-top-k routing two of them bind).
 
 The layer is told which experts it holds (``ExpertSpec``:
 ``first_expert``, ``experts_held`` of ``num_experts``). It routes over
@@ -17,7 +17,8 @@ are counted (``stats["moe_overflow_pairs"]``), as are the held experts a
 call gave no row at all (``stats["moe_experts_touched_share"]``: their
 weights are not read). Each model keeps its routing function and its
 shared branch (Qwen3-Next: softmax scores, a sigmoid-gated shared
-expert; Kimi-VL: sigmoid scores with a selection bias, an ungated one).
+expert; Kimi-VL: sigmoid scores with a selection bias, an ungated one;
+SDAR: Qwen3-Next's routing, ``route_softmax_top_k``, no shared expert).
 """
 
 from __future__ import annotations
@@ -33,6 +34,7 @@ from flax import linen as nn
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +76,18 @@ def mm(x, w, dtype):
 def swiglu(x, w_gate, w_up, w_down, dtype):
     return mm(jax.nn.silu(mm(x, w_gate, dtype)) * mm(x, w_up, dtype),
               w_down, dtype)
+
+
+def route_softmax_top_k(p, x, top_k: int, renormalise: bool):
+    """``x [N, H]`` -> the ``top_k`` experts ``[N, k]`` of ALL the
+    router's outputs by softmax probability and their weights (summing
+    to 1 where ``renormalise``), float32 throughout."""
+    logits = jnp.dot(x.astype(_F32), p["router"], precision=_HIGHEST)
+    probs = jax.nn.softmax(logits, -1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return experts, weights
 
 
 def routed_experts(p, x, spec: ExpertSpec, dtype, route):

@@ -457,12 +457,9 @@ def gated_deltanet_step(p, x, state, keep, cfg, dtype):
 def route(p, x, cfg):
     """``x [N, H]`` -> the top-k experts ``[N, k]`` of ALL
     ``num_experts`` and their weights, float32 throughout."""
-    logits = jnp.dot(x.astype(_F32), p["router"], precision=_HIGHEST)
-    probs = jax.nn.softmax(logits, -1)
-    weights, experts = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    if cfg.norm_topk_prob:
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
-    return experts, weights
+    return moe.route_softmax_top_k(
+        p, x, cfg.num_experts_per_tok, cfg.norm_topk_prob
+    )
 
 
 def routed_experts(p, x, cfg: Qwen3NextConfig, dtype):

@@ -1,6 +1,7 @@
 """Pure-function temporal ops: GAE, V-trace, distributions, losses, noise."""
 
 from actor_critic_algs_on_tensorflow_tpu.ops.distributions import (  # noqa: F401
+    BlockReveal,
     Categorical,
     DiagGaussian,
     TanhGaussian,

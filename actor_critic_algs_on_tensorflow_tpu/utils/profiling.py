@@ -41,8 +41,9 @@ MINIBATCH_PREP = "minibatch_prep"  # in update: slice, convert, relayout
 LOSS_GRAD = "loss_grad"            # in update: forward + backward
 OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
 # Layers of the sequence-policy cores (models/qwen3_next.py,
-# models/kimi_vl.py; the expert block is models/moe.py's), inside
-# policy_act and loss_grad; read like the phases, listed apart.
+# models/kimi_vl.py, models/sdar.py; the expert block is
+# models/moe.py's), inside policy_act and loss_grad; read like the
+# phases, listed apart.
 GDN = "gdn"                        # a Gated DeltaNet mixer
 GDN_STATE = "gdn_state"            # in gdn: the step form's state update
 GATED_ATTN = "gated_attn"          # a gated softmax-attention mixer
@@ -55,9 +56,11 @@ LM_HEAD = "lm_head"                # final norm, logits, log-prob, entropy
 MLA = "mla"                        # a latent-attention mixer, both forms
 MLA_ABSORBED = "mla_absorbed"      # in mla: the step form over the cache
 DENSE_MLP = "dense_mlp"            # a leading dense layer's feed-forward
+GQA = "gqa"                        # a grouped-query mixer, both forms
+GQA_BLOCK_STEP = "gqa_block_step"  # in gqa: a block's pass over the cache
 LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_SHARED, LM_HEAD, MLA, MLA_ABSORBED,
-                DENSE_MLP)
+                DENSE_MLP, GQA, GQA_BLOCK_STEP)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)
 

@@ -566,3 +566,45 @@ def test_sentinel_snapshot_only_where_the_state_fits_twice(
 
     cfg = A2CConfig(env="CartPole-v1", num_envs=8)
     assert cli._snapshot_fits(make_a2c(cfg), cfg) == fits
+
+
+def test_the_sdar_presets_resolve_and_need_no_rollback_copy(monkeypatch):
+    """Both block-diffusion presets are PPO configs whose env and model
+    agree on the block; one episode is one rollout; and the cell's
+    train state (646 M parameters with Adam's moments, 7.5 GiB) does not
+    fit a 16 GB chip beside a rollback copy of itself, so the sentinel's
+    snapshot is left out as for the other sequence-core presets."""
+    import types
+
+    import jax
+
+    from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (
+        PPOConfig,
+        make_ppo,
+    )
+
+    for name in ("ppo-sdar-turns", "ppo-sdar-tiny"):
+        algo, base = cli.PRESETS[name]
+        cfg = PPOConfig(**dict(base, num_devices=1))
+        env, model = cfg.env_params, cfg.seq_model
+        assert algo == "ppo" and cfg.torso == "sdar" and cfg.recurrent
+        assert env.episode_length == cfg.rollout_length
+        assert (env.vocab_size, env.block_length, env.mask_id) == (
+            model.vocab_size, model.block_length, model.mask_token_id
+        )
+        assert env.denoise_steps == model.denoising_steps
+    fns = make_ppo(cfg)  # the tiny one
+    assert cli._snapshot_fits(fns, cfg)  # the CPU reports no capacity
+    algo, base = cli.PRESETS["ppo-sdar-turns"]
+    cfg = PPOConfig(**dict(base, num_devices=1))
+    fns = make_ppo(cfg)
+    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
+    assert n_params == 645_625_345
+    assert state.carry["core"]["layers"].shape == (128, 6, 192, 1024)
+    assert state.obs.shape == (128, 4)
+    chip = types.SimpleNamespace(
+        memory_stats=lambda: {"bytes_limit": int(15.75 * 2**30)}
+    )
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [chip])
+    assert not cli._snapshot_fits(fns, cfg)

@@ -185,6 +185,8 @@ def test_registered_env_anakin_stack(name):
     def sample_actions(key):
         if isinstance(space, Discrete):
             return jax.random.randint(key, (n_envs,), 0, space.n)
+        if isinstance(space, envs.TokenBlock):
+            return jax.vmap(space.sample)(jax.random.split(key, n_envs))
         assert isinstance(space, Box)
         return jax.random.uniform(
             key, (n_envs,) + space.shape,
@@ -440,3 +442,100 @@ def test_pong_serve_env_reset_mixture():
         jax.tree_util.tree_leaves(out_a), jax.tree_util.tree_leaves(out_b)
     ):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# BlockTurns: a step is one pass over a block (envs/block_turns.py)
+
+
+def _block_turns(turns=3, delay_turns=1):
+    from actor_critic_algs_on_tensorflow_tpu.envs.block_turns import (
+        BlockTurns,
+        BlockTurnsParams,
+        _env_block,
+    )
+
+    params = BlockTurnsParams(vocab_size=32, block_length=4,
+                              denoise_steps=4, turns=turns,
+                              delay_turns=delay_turns)
+    return BlockTurns(), params, _env_block
+
+
+def test_block_turns_schedule_is_one_four_one():
+    """A turn: the env's clean block, four denoising steps over the
+    policy's block (all mask at first), the finished block shown once
+    more; 6 x turns steps, then truncated."""
+    env, params, env_block = _block_turns()
+    mask = params.mask_id
+    assert (params.episode_length, params.tokens_per_episode) == (18, 24)
+    space = env.action_space(params)
+    assert (space.n, space.block_length, space.mask_token_id) == (32, 4, mask)
+    key = jax.random.PRNGKey(3)
+    state, obs = env.reset(key, params)
+    step = jax.jit(lambda s, a: env.step(None, s, a, params))
+    seen, dones = [np.asarray(obs)], []
+    for t in range(params.episode_length):
+        block = np.asarray(obs)
+        action = block.copy()
+        if (block == mask).any():  # reveal the lowest masked position
+            action[np.flatnonzero(block == mask)[0]] = 5
+        state, obs, reward, done, info = step(state, jnp.asarray(action))
+        seen.append(np.asarray(obs))
+        dones.append(float(done))
+        assert float(info["terminated"]) == 0.0
+        assert float(info["truncated"]) == float(done)
+    assert dones == [0.0] * 17 + [1.0]
+    for turn in range(params.turns):
+        shown = seen[6 * turn: 6 * turn + 6]
+        np.testing.assert_array_equal(shown[0], env_block(key, turn, params))
+        assert (shown[0] < mask).all()
+        masked = [(b == mask).sum() for b in shown]
+        assert masked == [0, 4, 3, 2, 1, 0]
+        assert shown[5].tolist() == [5, 5, 5, 5]
+
+
+def test_block_turns_rewards_revealed_ids_that_match_the_delayed_block():
+    env, params, env_block = _block_turns(turns=3, delay_turns=1)
+    mask = params.mask_id
+    key = jax.random.PRNGKey(4)
+    state, obs = env.reset(key, params)
+    step = jax.jit(lambda s, a: env.step(None, s, a, params))
+    rewards = []
+    for t in range(params.episode_length):
+        turn, phase = divmod(t, 6)
+        block = np.asarray(obs)
+        action = block.copy()
+        target = np.asarray(env_block(key, max(turn - 1, 0), params))
+        if phase == 1:    # two right ids at once
+            action[:2] = target[:2]
+        elif phase == 2:  # a wrong id
+            action[2] = (target[2] + 1) % mask
+        elif phase == 3:  # an id already shown is not revealed again
+            action[0] = (target[0] + 1) % mask
+        # phase 4: nothing revealed, so the env fills position 3 with 0
+        if phase in (0, 5):
+            action = np.full(4, 9)  # ignored
+        state, obs, reward, done, _ = step(state, jnp.asarray(action))
+        rewards.append(float(reward))
+        if phase == 3:
+            assert np.asarray(obs)[0] == target[0]  # kept, not overwritten
+        if phase == 4:
+            assert np.asarray(obs)[3] == 0 and (np.asarray(obs) != mask).all()
+    # turn 0 lies before the delay; turns 1 and 2 earn 2 at their first
+    # denoising step and nothing else
+    assert rewards == [0.0] * 6 + [0.0, 2.0, 0.0, 0.0, 0.0, 0.0] * 2
+
+
+def test_block_turns_is_a_function_of_its_key():
+    env, params, _ = _block_turns()
+
+    def episode(key):
+        state, obs = env.reset(key, params)
+        out = [obs]
+        for _ in range(7):
+            state, obs, *_ = env.step(None, state, obs, params)
+            out.append(obs)
+        return np.stack(out)
+
+    a, b = episode(jax.random.PRNGKey(5)), episode(jax.random.PRNGKey(5))
+    np.testing.assert_array_equal(a, b)
+    assert (a != episode(jax.random.PRNGKey(6))).any()

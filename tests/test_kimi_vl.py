@@ -548,7 +548,7 @@ def test_the_carry_is_sharded_by_env_on_two_devices():
 
 
 def test_the_sequence_cores_are_a_table():
-    assert set(models.SEQUENCE_CORES) == {"qwen3_next", "kimi_vl"}
+    assert set(models.SEQUENCE_CORES) == {"qwen3_next", "kimi_vl", "sdar"}
     for torso in models.SEQUENCE_CORES:
         core, config = models.sequence_core(torso)
         assert core.replays_from_empty_carry is True
@@ -561,8 +561,9 @@ def test_the_sequence_cores_are_a_table():
 
 @pytest.mark.parametrize("torso", sorted(models.SEQUENCE_CORES))
 def test_refusals_name_the_table_and_not_a_model(torso):
-    preset = {"qwen3_next": "ppo-qwen3next-tiny",
-              "kimi_vl": "ppo-kimivl-tiny"}[torso]
+    presets = {"qwen3_next": "ppo-qwen3next-tiny",
+               "kimi_vl": "ppo-kimivl-tiny", "sdar": "ppo-sdar-tiny"}
+    preset = presets[torso]
     tiny = PRESETS[preset][1]
     with pytest.raises(ValueError, match="episode_length"):
         make_ppo(PPOConfig(**dict(tiny, rollout_length=8)))
@@ -570,9 +571,8 @@ def test_refusals_name_the_table_and_not_a_model(torso):
         make_ppo(PPOConfig(**dict(tiny, recurrent=False)))
     # the other core's config is not this core's
     other = next(PRESETS[p][1]["seq_model"]
-                 for p in ("ppo-qwen3next-tiny", "ppo-kimivl-tiny")
-                 if p != preset)
-    env, env_params = envs.make("TokenRecallTPU-v0", num_envs=1,
+                 for p in presets.values() if p != preset)
+    env, env_params = envs.make(tiny["env"], num_envs=1,
                                 params=tiny["env_params"])
     with pytest.raises(ValueError, match="SEQUENCE_CORES"):
         common.make_recurrent_policy_head(

@@ -431,3 +431,73 @@ def test_the_absorbed_step_reads_the_latents_and_rebuilds_no_key(one_chip):
     staged = [line for line in text.splitlines()
               if "-start(" in line and "[128,6,512,576]" in line]
     assert not staged, staged
+
+
+def test_a_blocks_pass_writes_its_rows_in_place_and_copies_no_cache(one_chip):
+    """The block-diffusion core's step form at the timed shape (128
+    envs, six layers' key/value caches of 192 rows of 1,024 in one
+    array, blocks of 4) as the rollout runs it: in a loop that carries
+    the donated caches, lowered for the described v5e. The block's rows
+    are written in place (a scatter of 4 x 128 rows into the aliased
+    array), beside the caches the program holds less than a tenth of
+    one layer's in HBM, nothing but that scatter produces an array of
+    the caches' shape (no copy of them, none in flight), and the
+    layer's rows are read through ONE slice of them: the compiler
+    stages that layer's 50 MB through VMEM once a pass and cuts the
+    eight key and value heads' columns there (whole lane tiles). No
+    instruction holds a key or value half ``[128, 192, 512]`` or its
+    per-head reshape: that was the first formulation, a 50 MB copy and
+    two transposes a pass (PERF.md section 6, PR 33)."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import sdar
+
+    cfg = PRESETS["ppo-sdar-turns"][1]["seq_model"]
+    B, L, H, layers, n = 128, 192, cfg.hidden_size, cfg.num_hidden_layers, 4
+    steps = 3
+    assert cfg.cache_width == 1024 and layers == 6 and cfg.block_length == n
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    spec = sdar.layer_param_spec(cfg)
+    names = ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm", "k_norm")
+    p = {name: arr(spec[name][0]) for name in names}
+
+    def rollout(p, xs, caches, pos):
+        def step(carry, x):
+            caches, pos = carry
+            y, caches = sdar.gqa_block_step(
+                p, x, caches, 1, pos, cfg, jnp.bfloat16
+            )
+            return (caches, pos + n), y
+
+        (caches, pos), ys = jax.lax.scan(step, (caches, pos), xs)
+        return ys, caches, pos
+
+    compiled = jax.jit(rollout, donate_argnums=2).lower(
+        p, arr((steps, B, n, H)), arr((B, layers, L, 1024), jnp.bfloat16),
+        arr((B,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    assert re.search(r"bf16\[128,6,192,1024\]\S* scatter\(", text)
+    cache = B * L * 1024 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == layers * cache
+    assert memory.temp_size_in_bytes < cache // 10
+    rows = [row for comp in unfused(text).values() for row in comp]
+    made = [(name, opcode) for name, result, opcode, *_ in rows
+            if result.startswith("bf16[128,6,192,1024]")
+            and opcode not in FREE | {"while"}]
+    assert [opcode for _, opcode in made] == ["fusion"], made  # the scatter
+    assert not [line for line in text.splitlines()
+                if "-start(" in line and "[128,6,192,1024]" in line]
+    halves = [(name, result) for name, result, opcode, *_ in rows
+              if opcode not in FREE and re.match(
+                  r"bf16\[128,192,(512|4,128)", result)]
+    assert not halves, halves
+    layer_rows = [name for name, result, opcode, *_ in rows
+                  if result.startswith("bf16[128,192,1024]")
+                  and opcode not in FREE]
+    assert len(layer_rows) == 1, layer_rows
