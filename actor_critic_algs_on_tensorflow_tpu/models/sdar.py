@@ -59,7 +59,17 @@ an episode commits. Two forms of one layer share the parameters:
   and pass ``s`` was a commit. That is the block-diffusion training mask
   (a noisy copy sees its own block and the clean earlier blocks, a clean
   block the clean blocks) with the rollout's passes as the copies, in
-  time order. ``carry`` and ``resets`` are not read.
+  time order. ``carry`` and ``resets`` are not read. The mask is two
+  vectors (``trajectory_steps``: a row's pass, and whether that pass was
+  a commit). Where the program is lowered for a TPU and the heads are
+  whole lane tiles (the published 128), scores, mask, softmax and
+  weighted sum are ``ops/pallas_block_attention.py``'s kernel pair,
+  which rebuilds the mask a tile at a time and keeps the scores in VMEM,
+  forward and backward; on the CPU, and at ``ppo-sdar-tiny``'s heads
+  anywhere, they are the plain ``_attend`` over the dense ``[b, n, n]``
+  mask (``_attend_seq`` chooses, by the lowering platform and the
+  shapes alone; the log row's ``gqa_score_tiles_computed_share`` says
+  which ran).
 
 Rotary embedding: rotate-half over all ``head_dim`` dimensions
 (``x * cos + [-x2, x1] * sin`` with the halves ``x1, x2`` paired),
@@ -103,6 +113,9 @@ COMMITTED_TOKENS = "diffusion_committed_tokens"
 REVEALED_POSITIONS = "diffusion_revealed_positions"
 DENOISE_PASSES = "diffusion_denoise_passes"
 POSITIONS = "diffusion_positions"
+# And the share of the sequence form's (tile of queries, chunk of keys)
+# pairs whose scores were computed: 1 where the plain form ran.
+SCORE_TILES_COMPUTED = "gqa_score_tiles_computed_share"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -318,29 +331,99 @@ def gqa_block_step(p, x, caches, layer, pos, cfg, dtype):
     return _mm(out, p["o_proj"], dtype), caches
 
 
-def trajectory_mask(commit, block_length: int):
-    """``commit [T, b]`` (bool) -> the positions ``[b, T * block_length]``
-    and the mask ``[b, n, n]`` of the sequence form: a pass's positions
-    follow the tokens committed before it, and a query sees its own
-    pass's block and the blocks of earlier commit passes."""
+def trajectory_steps(commit, block_length: int):
+    """``commit [T, b]`` (bool) -> the positions ``[b, n]`` of the
+    sequence form's ``n = T * block_length`` rows (a pass's positions
+    follow the tokens committed before it) and the two vectors its mask
+    is made of: ``step [n]``, a row's pass, and ``key_commit [b, n]``,
+    whether that pass was a commit."""
     T, b = commit.shape
     committed = jnp.cumsum(commit.astype(jnp.int32), 0) - commit
     positions = (block_length * committed)[..., None] + jnp.arange(
         block_length
     )                                                   # [T, b, L]
     positions = jnp.swapaxes(positions, 0, 1).reshape(b, -1)
-    step = jnp.repeat(jnp.arange(T), block_length)      # a position's pass
+    step = jnp.repeat(jnp.arange(T), block_length)
+    key_commit = jnp.repeat(commit.T, block_length, axis=1)
+    return positions, step, key_commit
+
+
+def _visible(step, key_commit):
+    """The mask ``[b, n, n]``: a query sees its own pass's block and
+    the blocks of earlier commit passes."""
     own = step[:, None] == step[None, :]
     earlier = step[None, :] < step[:, None]             # key before query
-    key_commit = jnp.repeat(commit.T, block_length, axis=1)   # [b, n]
-    return positions, own | (earlier & key_commit[:, None, :])
+    return own | (earlier & key_commit[:, None, :])
 
 
-def gqa_seq(p, x, positions, visible, cfg, dtype):
+def trajectory_mask(commit, block_length: int):
+    """``commit [T, b]`` (bool) -> the positions ``[b, T *
+    block_length]`` and the dense mask ``[b, n, n]`` of the sequence
+    form."""
+    positions, step, key_commit = trajectory_steps(commit, block_length)
+    return positions, _visible(step, key_commit)
+
+
+def _kernel_or_plain(q, k, v, kernel, plain, *operands):
+    """``kernel(pallas_block_attention, *operands)`` where the program
+    is lowered for a TPU and the heads take the kernels (``head_dim``
+    whole lane tiles, ``n`` whole sublane tiles), ``plain(*operands)``
+    anywhere else: nothing but the lowering platform and the shapes
+    chooses."""
+    # Imported where it is used: Pallas is ~1.5 s of imports, and
+    # cli/train.py's PRESETS import this module for every preset.
+    from actor_critic_algs_on_tensorflow_tpu.ops import (
+        pallas_block_attention,
+    )
+
+    if not pallas_block_attention.fits(q, k, v):
+        return plain(*operands)
+    return jax.lax.platform_dependent(
+        *operands, tpu=functools.partial(kernel, pallas_block_attention),
+        default=plain,
+    )
+
+
+def _attend_seq(q, k, v, step, key_commit, dtype):
+    """``_attend`` under the trajectory's mask, rounded to ``dtype``
+    (the output projection's product rounds it there anyway): the
+    kernels that keep the scores in VMEM
+    (``ops/pallas_block_attention.py``), or the plain form over the
+    dense mask."""
+
+    def kernel(ops, q, k, v, key_commit):
+        return ops.block_attention(q, k, v, step, key_commit, dtype)
+
+    def plain(q, k, v, key_commit):
+        out = _attend(q, k, v, _visible(step, key_commit), dtype)
+        return out.astype(dtype)
+
+    with jax.named_scope(profiling.GQA_SEQ_ATTEND):
+        return _kernel_or_plain(q, k, v, kernel, plain, q, k, v, key_commit)
+
+
+def _score_tiles_computed_share(q, k, v, step):
+    """The share of the sequence form's (tile of queries, chunk of
+    keys) pairs whose scores are computed, the same in every layer:
+    what the kernels' skip rule visits, or every one."""
+
+    def kernel(ops, step):
+        return ops.score_tiles_computed_share(step)
+
+    def plain(step):
+        return jnp.ones((), _F32)
+
+    return _kernel_or_plain(q, k, v, kernel, plain, step)
+
+
+def gqa_seq(p, x, positions, step, key_commit, cfg, dtype):
     """The sequence form: ``x [b, n, H]`` over ``n = T * block_length``
-    positions at ``positions [b, n]`` under ``visible [b, n, n]``."""
+    positions at ``positions [b, n]`` under the mask that ``step [n]``
+    and ``key_commit [b, n]`` make."""
     q, k, v = _project(p, x, positions, cfg, dtype)
-    return _mm(_attend(q, k, v, visible, dtype), p["o_proj"], dtype)
+    return _mm(
+        _attend_seq(q, k, v, step, key_commit, dtype), p["o_proj"], dtype
+    )
 
 
 # ---- the expert block --------------------------------------------------
@@ -379,10 +462,10 @@ def _expert_layer(p, x, cfg, dtype, every_pair=False):
     return x + y.reshape(x.shape), stats
 
 
-def _decoder_layer_seq(p, x, positions, visible, cfg, dtype):
+def _decoder_layer_seq(p, x, positions, step, key_commit, cfg, dtype):
     with jax.named_scope(profiling.GQA):
         h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-        x = x + gqa_seq(p, h, positions, visible, cfg, dtype)
+        x = x + gqa_seq(p, h, positions, step, key_commit, cfg, dtype)
     return _expert_layer(p, x, cfg, dtype)
 
 
@@ -402,10 +485,11 @@ def _decoder_layer_step(p, x, caches, layer, pos, cfg, dtype):
 
 
 def iteration_stats(rollout_stats, update_stats, axis_name):
-    """The expert layer's counters of one training iteration and the
-    sampler's: the passes the rollout ran for each token it committed,
-    the positions a denoising pass revealed, and the share of the
-    positions the head computed whose logits the log-prob reads."""
+    """The expert layer's counters of one training iteration, the
+    sampler's (the passes the rollout ran for each token it committed,
+    the positions a denoising pass revealed, the share of the positions
+    the head computed whose logits the log-prob reads) and the share of
+    the update's attention scores, by tile, that were computed."""
     stats = moe.iteration_moe_stats(rollout_stats, update_stats, axis_name)
     committed = jax.lax.pmean(
         jnp.mean(rollout_stats[COMMITTED_TOKENS]), axis_name
@@ -422,6 +506,9 @@ def iteration_stats(rollout_stats, update_stats, axis_name):
             denoise, 1.0
         ),
         diffusion_scored_position_share=revealed / positions,
+    )
+    stats[SCORE_TILES_COMPUTED] = jax.lax.pmean(
+        jnp.mean(update_stats[SCORE_TILES_COMPUTED]), axis_name
     )
     return stats
 
@@ -477,19 +564,18 @@ class SDARActorCritic(nn.Module):
             committed = n * commit[0].astype(jnp.int32)
             carry = {"layers": caches, "pos": pos + committed}
         else:
-            positions, visible = trajectory_mask(commit, n)
+            positions, step, key_commit = trajectory_steps(commit, n)
             x = jnp.swapaxes(x, 0, 1).reshape(B, T * n, H)
+            # Each layer is recomputed in the backward pass, as in the
+            # other cores: kept, the activations of a minibatch's
+            # positions do not fit beside the weights and Adam's
+            # moments. One function for all layers, the mask's vectors
+            # among its arguments: jax.checkpoint then traces it once.
+            layer = jax.checkpoint(
+                functools.partial(_decoder_layer_seq, cfg=cfg, dtype=dtype)
+            )
             for p in layers:
-                # Each layer is recomputed in the backward pass, as in
-                # the other cores: kept, the activations of a
-                # minibatch's positions do not fit beside the weights
-                # and Adam's moments.
-                layer = jax.checkpoint(
-                    lambda p, x: _decoder_layer_seq(
-                        p, x, positions, visible, cfg, dtype
-                    )
-                )
-                x, stats = layer(p, x)
+                x, stats = layer(p, x, positions, step, key_commit)
                 all_stats.append(stats)
             x = jnp.swapaxes(x.reshape(B, T, n, H), 0, 1)
         with jax.named_scope(profiling.LM_HEAD):
@@ -514,6 +600,12 @@ class SDARActorCritic(nn.Module):
             ).astype(_F32)
             stats[DENOISE_PASSES] = jnp.sum(~commit).astype(_F32)
             stats[POSITIONS] = jnp.asarray(tokens.size, _F32)
+            heads = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                     cfg.num_key_value_heads)
+            stats[SCORE_TILES_COMPUTED] = _score_tiles_computed_share(
+                *(jax.ShapeDtypeStruct((B, T * n, h, cfg.head_dim), _F32)
+                  for h in heads), step,
+            )
         return logits, values, carry, stats
 
     def initialize_carry(self, batch: int) -> Dict[str, Any]:

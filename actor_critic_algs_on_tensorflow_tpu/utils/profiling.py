@@ -58,9 +58,10 @@ MLA_ABSORBED = "mla_absorbed"      # in mla: the step form over the cache
 DENSE_MLP = "dense_mlp"            # a leading dense layer's feed-forward
 GQA = "gqa"                        # a grouped-query mixer, both forms
 GQA_BLOCK_STEP = "gqa_block_step"  # in gqa: a block's pass over the cache
+GQA_SEQ_ATTEND = "gqa_seq_attend"  # in gqa: the sequence form less projections
 LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_SHARED, LM_HEAD, MLA, MLA_ABSORBED,
-                DENSE_MLP, GQA, GQA_BLOCK_STEP)
+                DENSE_MLP, GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)
 

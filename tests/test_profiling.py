@@ -255,17 +255,20 @@ def test_the_kimi_vl_iteration_carries_its_layer_scopes(
 
 
 def test_the_sdar_iteration_carries_its_layer_scopes(metadata_in_cache_key):
-    """The layers ``models/sdar.py`` names — the grouped-query mixer and
-    a block's pass over the cache inside it — and the shared expert
+    """The layers ``models/sdar.py`` names — the grouped-query mixer, a
+    block's pass over the cache and the sequence form's attention
+    inside it — and the shared expert
     layer's (no shared expert here), in the compiled text of the tiny
     preset's fused iteration, nested as declared."""
     from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
     from actor_critic_algs_on_tensorflow_tpu.utils.profiling import (
-        GQA, GQA_BLOCK_STEP, LM_HEAD, MOE, MOE_DISPATCH, MOE_EXPERTS,
-        MOE_ROUTER, MOE_SHARED,
+        GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND, LM_HEAD, MOE, MOE_DISPATCH,
+        MOE_EXPERTS, MOE_ROUTER, MOE_SHARED,
     )
 
-    assert {GQA, GQA_BLOCK_STEP} <= set(profiling.LAYER_SCOPES)
+    assert {GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND} <= set(
+        profiling.LAYER_SCOPES
+    )
     fns = make_ppo(PPOConfig(**PRESETS["ppo-sdar-tiny"][1]))
     state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
     table = profiling.scope_table(
@@ -282,9 +285,16 @@ def test_the_sdar_iteration_carries_its_layer_scopes(metadata_in_cache_key):
             # the update's differentiated pass
             assert GQA in phases[:phases.index(GQA_BLOCK_STEP)], phases
             assert LOSS_GRAD not in phases, phases
+        if GQA_SEQ_ATTEND in phases:
+            # the sequence form less its projections: in the mixer, in
+            # the update's differentiated pass (the bootstrap value's
+            # pass is one env step), never in an acting pass
+            assert GQA in phases[:phases.index(GQA_SEQ_ATTEND)], phases
+            assert POLICY_ACT not in phases, phases
         if GQA in phases:
             assert MOE not in phases, phases
     assert any(GQA in p and LOSS_GRAD in p for p in lists)
+    assert any(GQA_SEQ_ATTEND in p and LOSS_GRAD in p for p in lists)
     assert any(GQA_BLOCK_STEP in p and POLICY_ACT in p for p in lists)
     # BlockReveal's log-prob and entropy in the update are the head's
     assert any(LM_HEAD in p and LOSS_GRAD in p for p in lists)

@@ -425,6 +425,68 @@ def test_block_grads_equal_the_reference_loss_and_gradients(trainer):
     np.testing.assert_allclose(g, g_ref, atol=2e-5 * np.abs(g_ref).max())
 
 
+def test_the_sequence_form_through_the_kernels_is_the_plain_form(
+        trajectory, monkeypatch):
+    """``SDARActorCritic``'s sequence form at a head of 128 (what the
+    kernels take; the preset's 16 goes to the plain form) through
+    ``ops/pallas_block_attention.py`` in the Pallas interpreter, as
+    ``sdar._kernel_or_plain`` picks it where the program is lowered for
+    a TPU: the plain form's logits and values, its gradients under the
+    layers' ``jax.checkpoint``, and a counter that says what was
+    skipped."""
+    import functools
+    import types
+
+    from actor_critic_algs_on_tensorflow_tpu.ops import (
+        pallas_block_attention as pba,
+    )
+
+    model = _model(cfg=dataclasses.replace(CFG, head_dim=128))
+    params, tokens = _init(model), trajectory
+    weights = jax.random.normal(
+        jax.random.PRNGKey(5), (T, B, L, CFG.vocab_size - 1)
+    )
+
+    def run(params):
+        logits, values, _, stats = model.apply(
+            params, tokens, jnp.zeros((T, B)), None
+        )
+        loss = jnp.sum(logits[..., :MASK] * weights) + jnp.sum(values ** 2)
+        return loss, (logits, values, stats)
+
+    run = jax.value_and_grad(run, has_aux=True)
+    (_, (logits, values, stats)), grads = run(params)
+    assert float(stats[sdar.SCORE_TILES_COMPUTED]) == 1.0
+
+    sizes = dict(tile_q=32, chunk=32)  # 3 x 3 tiles over 96 positions
+    interpreted = types.SimpleNamespace(
+        block_attention=functools.partial(
+            pba.block_attention, interpret=True, **sizes
+        ),
+        score_tiles_computed_share=functools.partial(
+            pba.score_tiles_computed_share, **sizes
+        ),
+    )
+
+    def kernels(q, k, v, kernel, plain, *operands):
+        assert pba.fits(q, k, v)
+        return kernel(interpreted, *operands)
+
+    monkeypatch.setattr(sdar, "_kernel_or_plain", kernels)
+    (_, (k_logits, k_values, k_stats)), k_grads = run(params)
+    assert float(k_stats[sdar.SCORE_TILES_COMPUTED]) == pytest.approx(6 / 9)
+    np.testing.assert_allclose(
+        k_logits[..., :MASK], logits[..., :MASK], atol=2e-5
+    )
+    np.testing.assert_allclose(k_values, values, atol=2e-5)
+    flat = lambda t: np.concatenate(  # noqa: E731
+        [np.ravel(x) for x in jax.tree_util.tree_leaves(t)]
+    )
+    g, g_plain = flat(k_grads), flat(grads)
+    assert np.linalg.norm(g_plain) > 1e-3 and np.isfinite(g).all()
+    np.testing.assert_allclose(g, g_plain, atol=2e-5 * np.abs(g_plain).max())
+
+
 def test_a_commit_pass_has_no_policy_gradient(trajectory):
     """Every commit pass: log-probability 0 whatever the parameters
     (ratio 1, no policy gradient; the value is still trained), and the

@@ -10,6 +10,7 @@ section 2; ``perfbench/tests/test_families_tpu_hlo.py`` is the
 benchmark's own such file).
 """
 
+import math
 import os
 import re
 
@@ -501,3 +502,64 @@ def test_a_blocks_pass_writes_its_rows_in_place_and_copies_no_cache(one_chip):
                   if result.startswith("bf16[128,192,1024]")
                   and opcode not in FREE]
     assert len(layer_rows) == 1, layer_rows
+
+
+def test_the_update_attention_keeps_its_scores_off_the_chips_memory(one_chip):
+    """The sequence form of SDAR's mixer at the timed shape (a minibatch
+    of 16 sequences of 144 passes of 4 positions, published widths, one
+    layer) as the update runs it: recomputed under ``jax.checkpoint``
+    and differentiated, lowered for the described v5e. The attention
+    is the kernel pair and nothing else: the forward kernel twice (the
+    pass and its recomputation), the backward once, no conditional left
+    of the choice by platform, and no buffer of the scores' size, in
+    any arrangement of their ``16 x 32 x 576 x 576`` elements, forward
+    or backward; the plain form held several of 680 MB each."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import sdar
+
+    cfg = PRESETS["ppo-sdar-turns"][1]["seq_model"]
+    b, T, L, H = 16, 144, cfg.block_length, cfg.hidden_size
+    n = T * L
+    assert (n, cfg.num_attention_heads, cfg.head_dim) == (576, 32, 128)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    spec = sdar.layer_param_spec(cfg)
+    names = ("input_norm", "q_proj", "k_proj", "v_proj", "o_proj",
+             "q_norm", "k_norm")
+    p = {name: arr(spec[name][0]) for name in names}
+
+    def loss(p, x, commit):
+        positions, step, key_commit = sdar.trajectory_steps(commit, L)
+
+        def mixer(p, x):
+            h = sdar.rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+            return x + sdar.gqa_seq(
+                p, h, positions, step, key_commit, cfg, jnp.bfloat16
+            )
+
+        return jnp.sum(jax.checkpoint(mixer)(p, x) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        p, arr((b, n, H)), arr((T, b), jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%(block_attention[a-z_]*)[\w.]* = .*"
+        r"custom_call_target=\"tpu_custom_call\"", text,
+    )
+    assert sorted(kernels) == [
+        "block_attention", "block_attention", "block_attention_backward"
+    ], kernels
+    assert " conditional(" not in text
+    scores = b * cfg.num_attention_heads * n * n
+    large = {
+        dims for dims in re.findall(r"[a-z]\d*\[([\d,]+)\]", text)
+        if math.prod(map(int, dims.split(","))) >= scores
+    }
+    assert not large, large
+    # beside the arguments 0.94 GiB: the queries, the output, their
+    # cotangents (151 MB each in float32); the plain form's 1.53
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * 2 ** 30
