@@ -323,8 +323,9 @@ def collect_rollout_recurrent(
             dist, value, core, stats = seq_dist_value(
                 params, norm(obs)[None], prev_done[None], core
             )
-            action = dist.sample(k_act)[0]
-            log_prob = dist.log_prob(action[None])[0]
+            with jax.named_scope(profiling.SAMPLE):
+                action = dist.sample(k_act)[0]
+                log_prob = dist.log_prob(action[None])[0]
         with jax.named_scope(profiling.ENV_STEP):
             env_state, next_obs, reward, done, info = env.step(
                 k_env, env_state, action, env_params

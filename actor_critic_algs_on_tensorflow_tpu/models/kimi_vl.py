@@ -233,11 +233,14 @@ def _mla_project(p, x, angles, cfg, dtype):
     rope key ``k^r [..., d_rope]``, float32."""
     nh, rank, dn = (cfg.num_attention_heads, cfg.kv_lora_rank,
                     cfg.qk_nope_head_dim)
-    q = _mm(x, p["q_proj"], dtype).reshape(x.shape[:-1] + (nh, -1))
-    kva = _mm(x, p["kv_a_proj"], dtype)
-    c = rms_norm(kva[..., :rank], p["kv_a_norm"], cfg.rms_norm_eps)
-    return (q[..., :dn], _rope(q[..., dn:], angles[..., None, :]), c,
-            _rope(kva[..., rank:], angles))
+    with jax.named_scope(profiling.MIXER_PROJ):
+        q = _mm(x, p["q_proj"], dtype)
+        kva = _mm(x, p["kv_a_proj"], dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        q = q.reshape(x.shape[:-1] + (nh, -1))
+        c = rms_norm(kva[..., :rank], p["kv_a_norm"], cfg.rms_norm_eps)
+        return (q[..., :dn], _rope(q[..., dn:], angles[..., None, :]), c,
+                _rope(kva[..., rank:], angles))
 
 
 def _softmax_scale(cfg):
@@ -249,21 +252,34 @@ def mla_seq(p, x, cfg, dtype):
     position = step; per-head keys and values from the latents."""
     T, b, _ = x.shape
     nh, dn = cfg.num_attention_heads, cfg.qk_nope_head_dim
-    q_nope, q_rope, c, k_rope = _mla_project(
-        p, jnp.swapaxes(x, 0, 1), _angles(jnp.arange(T), cfg), cfg, dtype
-    )
-    kv = _mm(c, p["kv_b_proj"], dtype).reshape(b, T, nh, -1)
-    k_nope, v = kv[..., :dn], kv[..., dn:]
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        x, angles = jnp.swapaxes(x, 0, 1), _angles(jnp.arange(T), cfg)
+    q_nope, q_rope, c, k_rope = _mla_project(p, x, angles, cfg, dtype)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        kv = _mm(c, p["kv_b_proj"], dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        kv = kv.reshape(b, T, nh, -1)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
 
-    # q . [k^n; k^r] with the one rope key shared by the heads
-    scores = _softmax_scale(cfg) * (
-        _dot("bqhd,bshd->bhqs", q_nope, k_nope, dtype)
-        + _dot("bqhd,bsd->bhqs", q_rope, k_rope, dtype)
-    )
-    scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = _dot("bhqs,bshd->bqhd", probs, v, dtype)
-    return jnp.swapaxes(_mm(out.reshape(b, T, -1), p["o_proj"], dtype), 0, 1)
+    with jax.named_scope(profiling.MIXER_CORE), jax.named_scope(
+        profiling.MLA_SEQ_ATTEND
+    ):
+        # q . [k^n; k^r] with the one rope key shared by the heads
+        scores = _softmax_scale(cfg) * (
+            _dot("bqhd,bshd->bhqs", q_nope, k_nope, dtype)
+            + _dot("bqhd,bsd->bhqs", q_rope, k_rope, dtype)
+        )
+        scores = jnp.where(
+            jnp.tril(jnp.ones((T, T), bool)), scores, -jnp.inf
+        )
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = _dot("bhqs,bshd->bqhd", probs, v, dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        out = out.reshape(b, T, -1)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        out = _mm(out, p["o_proj"], dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        return jnp.swapaxes(out, 0, 1)
 
 
 def latent_attention(q, cache, pos, scale, rank, dtype):
@@ -341,24 +357,33 @@ def mla_step(p, x, caches, layer, pos, cfg, dtype):
     B = caches.shape[0]
     nh, rank, dn = (cfg.num_attention_heads, cfg.kv_lora_rank,
                     cfg.qk_nope_head_dim)
-    q_nope, q_rope, c, k_rope = _mla_project(
-        p, x, _angles(pos, cfg), cfg, dtype
-    )
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        angles = _angles(pos, cfg)
+    q_nope, q_rope, c, k_rope = _mla_project(p, x, angles, cfg, dtype)
 
+    # The absorbed form's two products with the halves of kv_b_proj are
+    # projections like the others; the rest of it is the core.
     with jax.named_scope(profiling.MLA_ABSORBED):
-        row = jnp.concatenate([c, k_rope], -1).astype(caches.dtype)
-        caches = caches.at[jnp.arange(B), layer, pos].set(
-            row, indices_are_sorted=True, unique_indices=True
-        )
-        w_kvb = p["kv_b_proj"].reshape(rank, nh, -1)
-        w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
-        q_latent = _dot("bhn,chn->bhc", q_nope, w_uk, dtype)
-        o_latent = _latent_attention(
-            jnp.concatenate([q_latent, q_rope], -1), caches, layer, pos,
-            _softmax_scale(cfg), rank, dtype,
-        )
-        out = _dot("bhc,chv->bhv", o_latent, w_uv, dtype)
-    return _mm(out.reshape(B, -1), p["o_proj"], dtype), caches
+        with jax.named_scope(profiling.MIXER_CORE):
+            row = jnp.concatenate([c, k_rope], -1).astype(caches.dtype)
+            caches = caches.at[jnp.arange(B), layer, pos].set(
+                row, indices_are_sorted=True, unique_indices=True
+            )
+        with jax.named_scope(profiling.MIXER_PROJ):
+            w_kvb = p["kv_b_proj"].reshape(rank, nh, -1)
+            w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
+            q_latent = _dot("bhn,chn->bhc", q_nope, w_uk, dtype)
+        with jax.named_scope(profiling.MIXER_CORE):
+            o_latent = _latent_attention(
+                jnp.concatenate([q_latent, q_rope], -1), caches, layer, pos,
+                _softmax_scale(cfg), rank, dtype,
+            )
+        with jax.named_scope(profiling.MIXER_PROJ):
+            out = _dot("bhc,chv->bhv", o_latent, w_uv, dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        out = out.reshape(B, -1)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        return _mm(out, p["o_proj"], dtype), caches
 
 
 # ---- the expert block --------------------------------------------------
@@ -420,14 +445,18 @@ def _feed_forward(p, x, cfg, dtype, expert: bool):
 
 def _decoder_layer_seq(p, x, cfg, dtype, expert: bool):
     with jax.named_scope(profiling.MLA):
-        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-        x = x + mla_seq(p, h, cfg, dtype)
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+        y = mla_seq(p, h, cfg, dtype)
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            x = x + y
     return _feed_forward(p, x, cfg, dtype, expert)
 
 
 def _decoder_layer_step(p, x, caches, layer, pos, cfg, dtype, expert: bool):
     with jax.named_scope(profiling.MLA):
-        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
         y, caches = mla_step(p, h, caches, layer, pos, cfg, dtype)
     x, stats = _feed_forward(p, x + y, cfg, dtype, expert)
     return x, caches, stats

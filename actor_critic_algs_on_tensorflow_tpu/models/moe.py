@@ -15,10 +15,13 @@ expert) pairs that land here are sorted by expert into one buffer of
 run grouped over it (``lax.ragged_dot``), and the pairs that did not fit
 are counted (``stats["moe_overflow_pairs"]``), as are the held experts a
 call gave no row at all (``stats["moe_experts_touched_share"]``: their
-weights are not read). Each model keeps its routing function and its
-shared branch (Qwen3-Next: softmax scores, a sigmoid-gated shared
-expert; Kimi-VL: sigmoid scores with a selection bias, an ungated one;
-SDAR: Qwen3-Next's routing, ``route_softmax_top_k``, no shared expert).
+weights are not read) and the share of the buffer's rows that hold a
+pair (``stats["moe_buffer_fill_share"]``: gather, grouped products and
+scatter-add run over every row). Each model keeps its routing function
+and its shared branch (Qwen3-Next: softmax scores, a sigmoid-gated
+shared expert; Kimi-VL: sigmoid scores with a selection bias, an
+ungated one; SDAR: Qwen3-Next's routing, ``route_softmax_top_k``, no
+shared expert).
 """
 
 from __future__ import annotations
@@ -130,7 +133,9 @@ def routed_experts(p, x, spec: ExpertSpec, dtype, route):
         h = jax.nn.silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
         ys = grouped(h, p["w_down"])
         ys = jnp.where(row_valid[:, None], ys, 0.0) * row_weight[:, None]
-    with jax.named_scope(profiling.MOE_DISPATCH):
+    with jax.named_scope(profiling.MOE_DISPATCH), jax.named_scope(
+        profiling.MOE_COMBINE
+    ):
         routed = jnp.zeros((N, x.shape[1]), _F32).at[row_token].add(ys)
     load = group_sizes.astype(_F32)
     stats = {
@@ -141,6 +146,10 @@ def routed_experts(p, x, spec: ExpertSpec, dtype, route):
         # the held experts this call gave a row: the ones whose weights
         # the grouped products read
         "moe_experts_touched_share": jnp.mean((group_sizes > 0).astype(_F32)),
+        # the dispatch buffer's rows that hold a pair: the grouped
+        # products and the gather and scatter around them run over all
+        # of its rows
+        "moe_buffer_fill_share": kept.astype(_F32) / rows,
     }
     return routed, stats
 
@@ -148,7 +157,8 @@ def routed_experts(p, x, spec: ExpertSpec, dtype, route):
 def reduce_moe_stats(stats):
     """One row of counters from many (layers, steps, minibatches, any
     leading axes): mean pairs a token, max imbalance, summed overflow,
-    mean share of the held experts a call touched."""
+    mean share of the held experts a call touched, mean share of the
+    dispatch buffer's rows that held a pair."""
     return {
         "moe_local_pairs_per_token":
             jnp.mean(stats["moe_local_pairs_per_token"]),
@@ -157,6 +167,7 @@ def reduce_moe_stats(stats):
         "moe_overflow_pairs": jnp.sum(stats["moe_overflow_pairs"]),
         "moe_experts_touched_share":
             jnp.mean(stats["moe_experts_touched_share"]),
+        "moe_buffer_fill_share": jnp.mean(stats["moe_buffer_fill_share"]),
     }
 
 
@@ -173,7 +184,8 @@ def iteration_moe_stats(rollout_stats, update_stats, axis_name):
     the update saw them, overflow summed over the rollout's steps and
     the update's blocks (it must be 0), the held experts touched as
     the rollout's steps saw it (nearly every call of the grouped
-    products is one of them)."""
+    products is one of them), the dispatch buffer's fill as each of the
+    two saw it (their buffers differ in rows by orders of magnitude)."""
     roll, upd = map(reduce_moe_stats, (rollout_stats, update_stats))
     return {
         "moe_local_pairs_per_token": jax.lax.pmean(
@@ -187,5 +199,11 @@ def iteration_moe_stats(rollout_stats, update_stats, axis_name):
         ),
         "moe_experts_touched_share": jax.lax.pmean(
             roll["moe_experts_touched_share"], axis_name
+        ),
+        "moe_buffer_fill_share_rollout": jax.lax.pmean(
+            roll["moe_buffer_fill_share"], axis_name
+        ),
+        "moe_buffer_fill_share_update": jax.lax.pmean(
+            upd["moe_buffer_fill_share"], axis_name
         ),
     }

@@ -209,12 +209,17 @@ def _attn_project(p, x, positions, cfg, dtype):
     ``[..., nkv, hd]``; query and key normed and rotated, float32."""
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    qg = _mm(x, p["q_proj"], dtype).reshape(x.shape[:-1] + (nh, 2 * hd))
-    q, gate = qg[..., :hd], qg[..., hd:]
-    k = _mm(x, p["k_proj"], dtype).reshape(x.shape[:-1] + (nkv, hd))
-    v = _mm(x, p["v_proj"], dtype).reshape(x.shape[:-1] + (nkv, hd))
-    q = _rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, cfg)
-    k = _rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, cfg)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        qg, k, v = (
+            _mm(x, p[w], dtype) for w in ("q_proj", "k_proj", "v_proj")
+        )
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        qg = qg.reshape(x.shape[:-1] + (nh, 2 * hd))
+        q, gate = qg[..., :hd], qg[..., hd:]
+        k = k.reshape(x.shape[:-1] + (nkv, hd))
+        v = v.reshape(x.shape[:-1] + (nkv, hd))
+        q = _rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, cfg)
+        k = _rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, cfg)
     return q, gate, k, v
 
 
@@ -240,29 +245,41 @@ def _attend(q, k, v, mask, cfg, dtype):
 def gated_attention_seq(p, x, cfg, dtype):
     """``x [T, b, H]``, causal over the sequence, position = step."""
     T, b, _ = x.shape
-    xb = jnp.swapaxes(x, 0, 1)
-    positions = jnp.broadcast_to(jnp.arange(T), (b, T))
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        xb = jnp.swapaxes(x, 0, 1)
+        positions = jnp.broadcast_to(jnp.arange(T), (b, T))
     q, gate, k, v = _attn_project(p, xb, positions, cfg, dtype)
-    mask = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (b, T, T))
-    out = _attend(q, k, v, mask, cfg, dtype) * jax.nn.sigmoid(gate)
-    return jnp.swapaxes(_mm(out.reshape(b, T, -1), p["o_proj"], dtype), 0, 1)
+    with jax.named_scope(profiling.MIXER_CORE):
+        mask = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (b, T, T))
+        out = _attend(q, k, v, mask, cfg, dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        out = (out * jax.nn.sigmoid(gate)).reshape(b, T, -1)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        out = _mm(out, p["o_proj"], dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        return jnp.swapaxes(out, 0, 1)
 
 
 def gated_attention_step(p, x, cache, pos, cfg, dtype):
     """One token an env against the cache: ``x [B, H]``, ``cache``
     ``{"k", "v"} [B, L, nkv, hd]``, ``pos [B]``. The new key and value
     are written at ``pos``; rows beyond it are masked."""
-    q, gate, k, v = _attn_project(p, x[:, None], pos[:, None], cfg, dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        x, positions = x[:, None], pos[:, None]
+    q, gate, k, v = _attn_project(p, x, positions, cfg, dtype)
     L = cache["k"].shape[1]
-    here = (jnp.arange(L)[None, :] == pos[:, None])[..., None, None]
-    cache = {
-        "k": jnp.where(here, k.astype(cache["k"].dtype), cache["k"]),
-        "v": jnp.where(here, v.astype(cache["v"].dtype), cache["v"]),
-    }
-    mask = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, :]
-    out = _attend(q, cache["k"], cache["v"], mask, cfg, dtype)
-    out = out * jax.nn.sigmoid(gate)
-    return _mm(out.reshape(x.shape[0], -1), p["o_proj"], dtype), cache
+    with jax.named_scope(profiling.MIXER_CORE):
+        here = (jnp.arange(L)[None, :] == pos[:, None])[..., None, None]
+        cache = {
+            "k": jnp.where(here, k.astype(cache["k"].dtype), cache["k"]),
+            "v": jnp.where(here, v.astype(cache["v"].dtype), cache["v"]),
+        }
+        mask = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, :]
+        out = _attend(q, cache["k"], cache["v"], mask, cfg, dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        out = (out * jax.nn.sigmoid(gate)).reshape(pos.shape[0], -1)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        return _mm(out, p["o_proj"], dtype), cache
 
 
 # ---- gated DeltaNet ----------------------------------------------------
@@ -294,23 +311,26 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int):
     gc = jnp.cumsum(g, -1)  # the decay from the chunk's start, in log
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    diff = gc[..., :, None] - gc[..., None, :]
-    decay = jnp.exp(jnp.where(lower, diff, 0.0)) * lower
+    with jax.named_scope(profiling.GDN_CHUNK_PRODUCTS):
+        diff = gc[..., :, None] - gc[..., None, :]
+        decay = jnp.exp(jnp.where(lower, diff, 0.0)) * lower
 
     def dot(spec, a, b):
         return jnp.einsum(spec, a, b, precision=_HIGHEST)
 
-    k_beta, v_beta = k * beta[..., None], v * beta[..., None]
-    # (I + A) [u | w] = [v beta | k beta exp(gc)], A strictly lower:
-    # inside a chunk every delta depends on the ones before it.
-    a = dot("nbhid,nbhjd->nbhij", k_beta, k) * decay * strict
-    rhs = jnp.concatenate([v_beta, k_beta * jnp.exp(gc)[..., None]], -1)
-    solved = jax.scipy.linalg.solve_triangular(
-        a + jnp.eye(chunk, dtype=a.dtype), rhs, lower=True,
-        unit_diagonal=True,
-    )
-    u, w = solved[..., :dv], solved[..., dv:]
-    qk = dot("nbhid,nbhjd->nbhij", q, k) * decay
+    with jax.named_scope(profiling.GDN_CHUNK_SOLVE):
+        k_beta, v_beta = k * beta[..., None], v * beta[..., None]
+        # (I + A) [u | w] = [v beta | k beta exp(gc)], A strictly lower:
+        # inside a chunk every delta depends on the ones before it.
+        a = dot("nbhid,nbhjd->nbhij", k_beta, k) * decay * strict
+        rhs = jnp.concatenate([v_beta, k_beta * jnp.exp(gc)[..., None]], -1)
+        solved = jax.scipy.linalg.solve_triangular(
+            a + jnp.eye(chunk, dtype=a.dtype), rhs, lower=True,
+            unit_diagonal=True,
+        )
+        u, w = solved[..., :dv], solved[..., dv:]
+    with jax.named_scope(profiling.GDN_CHUNK_PRODUCTS):
+        qk = dot("nbhid,nbhjd->nbhij", q, k) * decay
 
     def step(S, xs):
         q_i, k_i, u_i, w_i, qk_i, gc_i = xs
@@ -324,8 +344,9 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int):
         )
         return S, o
 
-    S0 = jnp.zeros(q.shape[1:3] + (q.shape[-1], dv), _F32)
-    S, o = jax.lax.scan(step, S0, (q, k, u, w, qk, gc))
+    with jax.named_scope(profiling.GDN_CHUNK_PRODUCTS):
+        S0 = jnp.zeros(q.shape[1:3] + (q.shape[-1], dv), _F32)
+        S, o = jax.lax.scan(step, S0, (q, k, u, w, qk, gc))
     o = jnp.moveaxis(o, 0, 2).reshape(o.shape[1:3] + (n * chunk, dv))
     return o[:, :, :T], S
 
@@ -354,19 +375,21 @@ def _gdn_inputs(p, x, cfg, dtype):
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv, r = cfg.linear_key_head_dim, cfg.linear_value_head_dim, nv // nk
     lead = x.shape[:-1]
-    qkvz = _mm(x, p["in_proj_qkvz"], dtype).reshape(
-        lead + (nk, 2 * dk + 2 * r * dv)
-    )
-    ba = _mm(x, p["in_proj_ba"], dtype).reshape(lead + (nk, 2 * r))
-    q = qkvz[..., :dk].reshape(lead + (nk * dk,))
-    k = qkvz[..., dk:2 * dk].reshape(lead + (nk * dk,))
-    v = qkvz[..., 2 * dk:2 * dk + r * dv].reshape(lead + (nv * dv,))
-    z = qkvz[..., 2 * dk + r * dv:].reshape(lead + (nv, dv))
-    b = ba[..., :r].reshape(lead + (nv,))
-    a = ba[..., r:].reshape(lead + (nv,))
-    beta = jax.nn.sigmoid(b)
-    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
-    return jnp.concatenate([q, k, v], -1), z, beta, g
+    with jax.named_scope(profiling.MIXER_PROJ):
+        qkvz = _mm(x, p["in_proj_qkvz"], dtype)
+        ba = _mm(x, p["in_proj_ba"], dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        qkvz = qkvz.reshape(lead + (nk, 2 * dk + 2 * r * dv))
+        ba = ba.reshape(lead + (nk, 2 * r))
+        q = qkvz[..., :dk].reshape(lead + (nk * dk,))
+        k = qkvz[..., dk:2 * dk].reshape(lead + (nk * dk,))
+        v = qkvz[..., 2 * dk:2 * dk + r * dv].reshape(lead + (nv * dv,))
+        z = qkvz[..., 2 * dk + r * dv:].reshape(lead + (nv, dv))
+        b = ba[..., :r].reshape(lead + (nv,))
+        a = ba[..., r:].reshape(lead + (nv,))
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+        return jnp.concatenate([q, k, v], -1), z, beta, g
 
 
 def _gdn_heads(qkv, cfg):
@@ -376,21 +399,25 @@ def _gdn_heads(qkv, cfg):
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     lead = qkv.shape[:-1]
-    q = qkv[..., : nk * dk].reshape(lead + (nk, dk))
-    k = qkv[..., nk * dk: 2 * nk * dk].reshape(lead + (nk, dk))
-    v = qkv[..., 2 * nk * dk:].reshape(lead + (nv, dv))
-    q = jnp.repeat(_l2norm(q) * dk ** -0.5, nv // nk, axis=-2)
-    k = jnp.repeat(_l2norm(k), nv // nk, axis=-2)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        q = qkv[..., : nk * dk].reshape(lead + (nk, dk))
+        k = qkv[..., nk * dk: 2 * nk * dk].reshape(lead + (nk, dk))
+        v = qkv[..., 2 * nk * dk:].reshape(lead + (nv, dv))
+        q = jnp.repeat(_l2norm(q) * dk ** -0.5, nv // nk, axis=-2)
+        k = jnp.repeat(_l2norm(k), nv // nk, axis=-2)
     return q, k, v
 
 
 def _gdn_output(p, o, z, cfg, dtype):
     """The gated norm over ``d_v`` (weight NOT zero-centred) and the
     output projection; ``o, z [..., nv, d_v]``."""
-    o = o * jax.lax.rsqrt(
-        jnp.mean(o * o, -1, keepdims=True) + cfg.rms_norm_eps
-    ) * p["gdn_norm"] * jax.nn.silu(z)
-    return _mm(o.reshape(o.shape[:-2] + (-1,)), p["out_proj"], dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        o = o * jax.lax.rsqrt(
+            jnp.mean(o * o, -1, keepdims=True) + cfg.rms_norm_eps
+        ) * p["gdn_norm"] * jax.nn.silu(z)
+        o = o.reshape(o.shape[:-2] + (-1,))
+    with jax.named_scope(profiling.MIXER_PROJ):
+        return _mm(o, p["out_proj"], dtype)
 
 
 def gated_deltanet_seq(p, x, cfg, dtype):
@@ -398,19 +425,23 @@ def gated_deltanet_seq(p, x, cfg, dtype):
     T = x.shape[0]
     K = cfg.linear_conv_kernel_dim
     qkv, z, beta, g = _gdn_inputs(p, x, cfg, dtype)
-    padded = jnp.pad(qkv, ((K - 1, 0), (0, 0), (0, 0)))
-    qkv = jax.nn.silu(sum(
-        padded[j: j + T] * p["conv"][j] for j in range(K)
-    ))
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        padded = jnp.pad(qkv, ((K - 1, 0), (0, 0), (0, 0)))
+        qkv = jax.nn.silu(sum(
+            padded[j: j + T] * p["conv"][j] for j in range(K)
+        ))
     q, k, v = _gdn_heads(qkv, cfg)
 
     def bh(x):  # [T, b, h, ...] -> [b, h, T, ...]
         return jnp.moveaxis(x, 0, 2)
 
-    o, _ = chunk_gated_delta_rule(
-        bh(q), bh(k), bh(v), bh(g), bh(beta), cfg.chunk_size
-    )
-    return _gdn_output(p, jnp.moveaxis(o, 2, 0), z, cfg, dtype)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        q, k, v, g, beta = map(bh, (q, k, v, g, beta))
+    with jax.named_scope(profiling.MIXER_CORE):
+        o, _ = chunk_gated_delta_rule(q, k, v, g, beta, cfg.chunk_size)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        o = jnp.moveaxis(o, 2, 0)
+    return _gdn_output(p, o, z, cfg, dtype)
 
 
 def _state_step(S, q, k, v, g, beta, keep):
@@ -440,15 +471,18 @@ def gated_deltanet_step(p, x, state, keep, cfg, dtype):
     1, C]}``; ``keep [B]``, 0 where the env starts over: its state and
     convolution history count as empty."""
     qkv, z, beta, g = _gdn_inputs(p, x, cfg, dtype)
-    window = jnp.concatenate(
-        [state["conv"] * keep[:, None, None], qkv[:, None]], 1
-    )
-    qkv = jax.nn.silu(jnp.sum(window * p["conv"], 1))
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        window = jnp.concatenate(
+            [state["conv"] * keep[:, None, None], qkv[:, None]], 1
+        )
+        qkv = jax.nn.silu(jnp.sum(window * p["conv"], 1))
+        conv = window[:, 1:]
     q, k, v = _gdn_heads(qkv, cfg)
-    with jax.named_scope(profiling.GDN_STATE):
+    with jax.named_scope(profiling.MIXER_CORE), jax.named_scope(
+        profiling.GDN_STATE
+    ):
         S, o = _state_step(state["S"], q, k, v, g, beta, keep)
-    return (_gdn_output(p, o, z, cfg, dtype),
-            {"S": S, "conv": window[:, 1:]})
+    return _gdn_output(p, o, z, cfg, dtype), {"S": S, "conv": conv}
 
 
 # ---- the expert block --------------------------------------------------
@@ -491,13 +525,18 @@ def moe_block(p, x, cfg: Qwen3NextConfig, dtype):
 
 def _decoder_layer_seq(p, x, cfg, dtype, attention: bool):
     T, b, H = x.shape
-    h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-    if attention:
-        with jax.named_scope(profiling.GATED_ATTN):
-            x = x + gated_attention_seq(p, h, cfg, dtype)
-    else:
-        with jax.named_scope(profiling.GDN):
-            x = x + gated_deltanet_seq(p, h, cfg, dtype)
+    # (the norm is outside the mixer's own scope, where PR 27 left it:
+    # gdn_time_share and gated_attn_time_share keep their meaning)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+    mixer, seq = (
+        (profiling.GATED_ATTN, gated_attention_seq) if attention
+        else (profiling.GDN, gated_deltanet_seq)
+    )
+    with jax.named_scope(mixer):
+        y = seq(p, h, cfg, dtype)
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            x = x + y
     with jax.named_scope(profiling.MOE):
         h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
         y, stats = moe_block(p, h.reshape(T * b, H), cfg, dtype)
@@ -505,7 +544,8 @@ def _decoder_layer_seq(p, x, cfg, dtype, attention: bool):
 
 
 def _decoder_layer_step(p, x, state, pos, keep, cfg, dtype, attention: bool):
-    h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
     if attention:
         with jax.named_scope(profiling.GATED_ATTN):
             y, state = gated_attention_step(p, h, state, pos, cfg, dtype)

@@ -248,11 +248,16 @@ def _project(p, x, positions, cfg, dtype):
     float32."""
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    q = _mm(x, p["q_proj"], dtype).reshape(x.shape[:-1] + (nh, hd))
-    k = _mm(x, p["k_proj"], dtype).reshape(x.shape[:-1] + (nkv, hd))
-    v = _mm(x, p["v_proj"], dtype).reshape(x.shape[:-1] + (nkv, hd))
-    q = _rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, cfg)
-    k = _rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, cfg)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        q, k, v = (
+            _mm(x, p[w], dtype) for w in ("q_proj", "k_proj", "v_proj")
+        )
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        q = q.reshape(x.shape[:-1] + (nh, hd))
+        k = k.reshape(x.shape[:-1] + (nkv, hd))
+        v = v.reshape(x.shape[:-1] + (nkv, hd))
+        q = _rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, cfg)
+        k = _rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, cfg)
     return q, k, v
 
 
@@ -316,10 +321,13 @@ def gqa_block_step(p, x, caches, layer, pos, cfg, dtype):
     rows below ``pos + block_length``: the finished blocks and the whole
     of their own."""
     B, n, _ = x.shape
-    positions = pos[:, None] + jnp.arange(n)
+    with jax.named_scope(profiling.MIXER_POINTWISE):
+        positions = pos[:, None] + jnp.arange(n)
     q, k, v = _project(p, x, positions, cfg, dtype)
 
-    with jax.named_scope(profiling.GQA_BLOCK_STEP):
+    with jax.named_scope(profiling.MIXER_CORE), jax.named_scope(
+        profiling.GQA_BLOCK_STEP
+    ):
         rows = jnp.concatenate(
             [k.reshape(B, n, -1), v.reshape(B, n, -1)], -1
         ).astype(caches.dtype)
@@ -328,7 +336,8 @@ def gqa_block_step(p, x, caches, layer, pos, cfg, dtype):
         )
         visible = jnp.arange(caches.shape[2])[None, :] < (pos + n)[:, None]
         out = _attend_cached(q, caches[:, layer], visible, cfg, dtype)
-    return _mm(out, p["o_proj"], dtype), caches
+    with jax.named_scope(profiling.MIXER_PROJ):
+        return _mm(out, p["o_proj"], dtype), caches
 
 
 def trajectory_steps(commit, block_length: int):
@@ -398,7 +407,9 @@ def _attend_seq(q, k, v, step, key_commit, dtype):
         out = _attend(q, k, v, _visible(step, key_commit), dtype)
         return out.astype(dtype)
 
-    with jax.named_scope(profiling.GQA_SEQ_ATTEND):
+    with jax.named_scope(profiling.MIXER_CORE), jax.named_scope(
+        profiling.GQA_SEQ_ATTEND
+    ):
         return _kernel_or_plain(q, k, v, kernel, plain, q, k, v, key_commit)
 
 
@@ -421,9 +432,9 @@ def gqa_seq(p, x, positions, step, key_commit, cfg, dtype):
     positions at ``positions [b, n]`` under the mask that ``step [n]``
     and ``key_commit [b, n]`` make."""
     q, k, v = _project(p, x, positions, cfg, dtype)
-    return _mm(
-        _attend_seq(q, k, v, step, key_commit, dtype), p["o_proj"], dtype
-    )
+    out = _attend_seq(q, k, v, step, key_commit, dtype)
+    with jax.named_scope(profiling.MIXER_PROJ):
+        return _mm(out, p["o_proj"], dtype)
 
 
 # ---- the expert block --------------------------------------------------
@@ -464,14 +475,18 @@ def _expert_layer(p, x, cfg, dtype, every_pair=False):
 
 def _decoder_layer_seq(p, x, positions, step, key_commit, cfg, dtype):
     with jax.named_scope(profiling.GQA):
-        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-        x = x + gqa_seq(p, h, positions, step, key_commit, cfg, dtype)
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+        y = gqa_seq(p, h, positions, step, key_commit, cfg, dtype)
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            x = x + y
     return _expert_layer(p, x, cfg, dtype)
 
 
 def _decoder_layer_step(p, x, caches, layer, pos, cfg, dtype):
     with jax.named_scope(profiling.GQA):
-        h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
         y, caches = gqa_block_step(p, h, caches, layer, pos, cfg, dtype)
     # A pass's mask tokens route alike (one embedding; attention
     # outputs that are averages of the same values): where the whole

@@ -9,6 +9,20 @@
   every instruction's ``op_name`` carries the scopes it was traced
   under — so the compiled program, its memory and its speed are those
   of the unscoped build.
+- ``LAYER_SCOPES``: the sequence cores' layers, named the same way
+  inside ``policy_act`` and ``loss_grad``: a mixer (``gdn``,
+  ``gated_attn``, ``mla``, ``gqa``), inside it exactly one of
+  ``mixer_proj`` / ``mixer_pointwise`` / ``mixer_core`` over every
+  instruction (projections; norms, rotary, gates, convolution,
+  reshapes; scores to weighted sum or the delta rule, with the cache or
+  state write), inside the core its forms (``gdn_state``,
+  ``gdn_chunk_solve``, ``gdn_chunk_products``, ``mla_seq_attend``,
+  ``gqa_block_step``, ``gqa_seq_attend``; ``mla_absorbed`` holds its two
+  products with ``kv_b_proj``'s halves as ``mixer_proj``); the expert
+  block (``moe`` with ``moe_router``, ``moe_dispatch`` and in it
+  ``moe_combine``, ``moe_experts``, ``moe_shared``), ``dense_mlp``,
+  ``lm_head`` and, in ``policy_act``, ``sample``. No layer index in a
+  name: a rollout's part of a layer is its operations under ``rollout``.
 - ``span(name)``: a host span in the profiler's trace
   (``jax.profiler.TraceAnnotation``) for a wait that has no counter.
   ``utils/metrics.py::TimeSplit.span`` is the same span under the
@@ -59,9 +73,24 @@ DENSE_MLP = "dense_mlp"            # a leading dense layer's feed-forward
 GQA = "gqa"                        # a grouped-query mixer, both forms
 GQA_BLOCK_STEP = "gqa_block_step"  # in gqa: a block's pass over the cache
 GQA_SEQ_ATTEND = "gqa_seq_attend"  # in gqa: the sequence form less projections
+# One level down, the same three names in every mixer (gdn, gated_attn,
+# mla, gqa), both forms, no layer index: every instruction traced under
+# a mixer's scope is under exactly one of them.
+MIXER_PROJ = "mixer_proj"          # the linear projections in and out
+MIXER_POINTWISE = "mixer_pointwise"  # norms, rotary, gates, conv, reshapes
+MIXER_CORE = "mixer_core"          # scores to weighted sum, or the delta rule
+MLA_SEQ_ATTEND = "mla_seq_attend"  # in mla's core: the expanded attention
+GDN_CHUNK_SOLVE = "gdn_chunk_solve"  # in gdn's core: a, rhs, the solve
+GDN_CHUNK_PRODUCTS = "gdn_chunk_products"  # in gdn's core: decay, qk, scan
+MOE_COMBINE = "moe_combine"        # in moe_dispatch: the scatter-add back
+SAMPLE = "sample"                  # in policy_act: sample and its log-prob
 LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_SHARED, LM_HEAD, MLA, MLA_ABSORBED,
-                DENSE_MLP, GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND)
+                DENSE_MLP, GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND,
+                MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE, MLA_SEQ_ATTEND,
+                GDN_CHUNK_SOLVE, GDN_CHUNK_PRODUCTS, MOE_COMBINE, SAMPLE)
+MIXER_SCOPES = (GDN, GATED_ATTN, MLA, GQA)
+MIXER_PARTS = (MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)
 
@@ -138,7 +167,13 @@ def scope_table(hlo_text: str) -> Dict[str, Tuple[str, ...] | None]:
     9-15 % of the device's time. Such a fusion
     takes the deepest phase list among the instructions it fused; any
     other takes the phases of the instruction it feeds (a copy exists
-    for its consumer), failing that of the one that feeds it. What is
+    for its consumer), failing that of the one that feeds it. A kernel
+    of the compiler's own name is work, not a copy: where it feeds a
+    fusion, the fused instruction that reads it speaks and not the
+    fusion's root, and it takes a consumer's phases only where
+    something that feeds it is in the same phase (``PHASES``), its
+    operands' otherwise — a weight gradient's grouped product feeds
+    Adam and is no part of ``optimizer`` (``_kernel_phases``). What is
     still unnamed has no phase: ``()``. One text under two different
     phase lists maps to ``None``: a reader must take that as not
     found, never pick one.
@@ -160,6 +195,7 @@ class _Instruction:
     phases: Tuple[str, ...] | None      # None: no op_name of its own
     operands: Tuple[str, ...]
     calls: Tuple[str, ...]
+    kernel: bool = False                # a kernel under the compiler's name
 
 
 def _parse(hlo_text: str) -> Dict[str, Dict[str, _Instruction]]:
@@ -181,10 +217,11 @@ def _parse(hlo_text: str) -> Dict[str, Dict[str, _Instruction]]:
         # A kernel the compiler put in under a name of its own coining
         # (the TPU's grouped products are all `ragged-dot-none`, 9.7 %
         # of the Qwen3-Next iteration: PERF.md section 6, PR 27) is as
-        # good as unnamed, and inherits like the rest.
-        traced = op_name is not None and not (
+        # good as unnamed, and inherits as `_kernel_phases` says.
+        kernel = op_name is not None and (
             " custom-call(" in rest and "jit(" not in op_name.group(1)
         )
+        traced = op_name is not None and not kernel
         current[name] = _Instruction(
             key=key,
             phases=phases_of(op_name.group(1)) if traced else None,
@@ -192,6 +229,7 @@ def _parse(hlo_text: str) -> Dict[str, Dict[str, _Instruction]]:
                 n for n in _NAME.findall(rest) if n[1:] not in calls
             ),
             calls=calls,
+            kernel=kernel,
         )
     return computations
 
@@ -210,15 +248,67 @@ def _inherit(instructions, computations) -> None:
     for name, inst in instructions.items():
         for operand in inst.operands:
             users.setdefault(operand, []).append(name)
-    for neighbours in (users.get, lambda n: instructions[n].operands):
+
+    def spread(neighbours, kernels: bool) -> None:
         changed = True
         while changed:
             changed = False
             for name, inst in instructions.items():
-                if inst.phases is not None:
+                if inst.phases is not None or (inst.kernel and not kernels):
                     continue
                 for other in neighbours(name) or ():
                     near = instructions.get(other)
                     if near is not None and near.phases is not None:
                         inst.phases, changed = near.phases, True
                         break
+
+    # Consumers first, the kernels held back until what they feed is
+    # known; then each kernel; then whatever is left, either way.
+    spread(users.get, kernels=False)
+    for name, inst in instructions.items():
+        if inst.kernel and inst.phases is None:
+            inst.phases = _kernel_phases(
+                name, instructions, users, computations
+            )
+    spread(users.get, kernels=True)
+    spread(lambda n: instructions[n].operands, kernels=True)
+
+
+def _kernel_phases(name, instructions, users, computations):
+    """A kernel's phases: those of an instruction it feeds, if one of
+    its operands is in the same program phase (or none has any); else
+    the deepest list among its operands, the later operand on a tie (a
+    product's right-hand side). ``None`` while nothing it feeds is
+    known (a kernel that feeds a kernel: it waits for that one). Where
+    it feeds a fusion, the fused instruction that reads it speaks, not
+    the fusion's root (the expert layer's last product is read by a
+    mask traced under ``moe_experts``, fused into the scatter-add of
+    ``moe_combine``). An operand the compiler made is read through to
+    what feeds it."""
+
+    def read_by(user):
+        inst = instructions[user]
+        if " fusion(" in inst.key:
+            fused = computations.get(inst.calls[0], {})
+            parameter = f" parameter({inst.operands.index(name)})"
+            inside = {n for n, i in fused.items() if parameter in i.key}
+            for reader in fused.values():
+                if reader.phases and inside.intersection(reader.operands):
+                    return reader.phases
+        return inst.phases
+
+    def fed_by(operand):
+        near = instructions.get(operand)
+        while near is not None and near.phases is None and not near.kernel:
+            near = instructions.get(next(iter(near.operands), None))
+        return near.phases if near is not None else None
+
+    def program(phases):
+        return tuple(p for p in phases if p in PHASES)
+
+    feeds = [p for p in map(read_by, users.get(name, ())) if p is not None]
+    fed = [p for p in map(fed_by, instructions[name].operands) if p]
+    for phases in feeds:
+        if not fed or program(phases) in map(program, fed):
+            return phases
+    return max(reversed(fed), key=len) if feeds else None

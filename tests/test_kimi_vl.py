@@ -503,6 +503,8 @@ def test_a_short_run_trains_and_counts():
     assert float(metrics["moe_overflow_pairs"]) == 0.0
     assert 0.0 < float(metrics["moe_local_pairs_per_token"]) < 2.0
     assert 0.0 < float(metrics["moe_experts_touched_share"]) <= 1.0
+    for phase in ("rollout", "update"):
+        assert 0.0 < float(metrics[f"moe_buffer_fill_share_{phase}"]) <= 1.0
     # the plain form ran (the CPU, a narrow cache): every row, every step
     assert float(metrics[kv.CACHE_ROWS_READ]) == 1.0
     assert float(metrics["episodes"]) == cfg.num_envs

@@ -219,26 +219,72 @@ def test_compiled_program_carries_its_phases(
     assert not any("NatureCNN" in n for n in bare)
 
 
-def test_the_kimi_vl_iteration_carries_its_layer_scopes(
-    metadata_in_cache_key
-):
+SEQUENCE_PRESETS = {
+    "qwen3_next": "ppo-qwen3next-tiny",
+    "kimi_vl": "ppo-kimivl-tiny",
+    "sdar": "ppo-sdar-tiny",
+}
+_SEQUENCE_TEXTS = {}
+
+
+def _sequence_text(core):
+    """The compiled text of ``core``'s tiny preset's fused iteration,
+    compiled once a module (under ``metadata_in_key``: see the fixture
+    of that name)."""
+    from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
+
+    if core not in _SEQUENCE_TEXTS:
+        with compile_cache.metadata_in_key():
+            fns = make_ppo(PPOConfig(**PRESETS[SEQUENCE_PRESETS[core]][1]))
+            state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
+            _SEQUENCE_TEXTS[core] = (
+                fns.iteration.lower(state).compile().as_text()
+            )
+    return _SEQUENCE_TEXTS[core]
+
+
+def _phase_lists(core):
+    table = profiling.scope_table(_sequence_text(core))
+    return {p for p in table.values() if p}
+
+
+def test_the_qwen3_next_iteration_carries_its_layer_scopes():
+    """The layers ``models/qwen3_next.py`` names — the Gated DeltaNet
+    mixer with its step form's state update, the gated-attention mixer
+    — and the shared expert layer's four, in the compiled text of the
+    tiny preset's fused iteration, nested as declared."""
+    from actor_critic_algs_on_tensorflow_tpu.utils.profiling import (
+        GATED_ATTN, GDN, GDN_STATE, LM_HEAD, MOE, MOE_DISPATCH,
+        MOE_EXPERTS, MOE_ROUTER, MOE_SHARED,
+    )
+
+    lists = _phase_lists("qwen3_next")
+    found = {phase for p in lists for phase in p}
+    assert {GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
+            MOE_EXPERTS, MOE_SHARED, LM_HEAD} <= found
+    for phases in lists:
+        if GDN_STATE in phases:
+            assert GDN in phases[:phases.index(GDN_STATE)], phases
+            assert LOSS_GRAD not in phases, phases
+        if GDN in phases:
+            assert GATED_ATTN not in phases and MOE not in phases, phases
+    for mixer in (GDN, GATED_ATTN):
+        assert any(mixer in p and LOSS_GRAD in p for p in lists)
+        assert any(mixer in p and POLICY_ACT in p for p in lists)
+
+
+def test_the_kimi_vl_iteration_carries_its_layer_scopes():
     """The layers ``models/kimi_vl.py`` names — the latent-attention
     mixer, its absorbed step inside it, the leading dense layer's
     feed-forward — and the shared expert layer's four, in the compiled
     text of the tiny preset's fused iteration, nested as declared."""
-    from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
     from actor_critic_algs_on_tensorflow_tpu.utils.profiling import (
         DENSE_MLP, LM_HEAD, MLA, MLA_ABSORBED, MOE, MOE_DISPATCH,
         MOE_EXPERTS, MOE_ROUTER, MOE_SHARED,
     )
 
     assert {MLA, MLA_ABSORBED, DENSE_MLP} <= set(profiling.LAYER_SCOPES)
-    fns = make_ppo(PPOConfig(**PRESETS["ppo-kimivl-tiny"][1]))
-    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
-    table = profiling.scope_table(
-        fns.iteration.lower(state).compile().as_text()
-    )
-    lists = {p for p in table.values() if p}
+    lists = _phase_lists("kimi_vl")
     found = {phase for p in lists for phase in p}
     assert {MLA, MLA_ABSORBED, DENSE_MLP, MOE, MOE_ROUTER, MOE_DISPATCH,
             MOE_EXPERTS, MOE_SHARED, LM_HEAD} <= found
@@ -254,13 +300,12 @@ def test_the_kimi_vl_iteration_carries_its_layer_scopes(
     assert any(MLA_ABSORBED in p and POLICY_ACT in p for p in lists)
 
 
-def test_the_sdar_iteration_carries_its_layer_scopes(metadata_in_cache_key):
+def test_the_sdar_iteration_carries_its_layer_scopes():
     """The layers ``models/sdar.py`` names — the grouped-query mixer, a
     block's pass over the cache and the sequence form's attention
     inside it — and the shared expert
     layer's (no shared expert here), in the compiled text of the tiny
     preset's fused iteration, nested as declared."""
-    from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
     from actor_critic_algs_on_tensorflow_tpu.utils.profiling import (
         GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND, LM_HEAD, MOE, MOE_DISPATCH,
         MOE_EXPERTS, MOE_ROUTER, MOE_SHARED,
@@ -269,12 +314,7 @@ def test_the_sdar_iteration_carries_its_layer_scopes(metadata_in_cache_key):
     assert {GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND} <= set(
         profiling.LAYER_SCOPES
     )
-    fns = make_ppo(PPOConfig(**PRESETS["ppo-sdar-tiny"][1]))
-    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
-    table = profiling.scope_table(
-        fns.iteration.lower(state).compile().as_text()
-    )
-    lists = {p for p in table.values() if p}
+    lists = _phase_lists("sdar")
     found = {phase for p in lists for phase in p}
     assert {GQA, GQA_BLOCK_STEP, MOE, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS,
             LM_HEAD} <= found
@@ -300,6 +340,106 @@ def test_the_sdar_iteration_carries_its_layer_scopes(metadata_in_cache_key):
     assert any(LM_HEAD in p and LOSS_GRAD in p for p in lists)
 
 
+# One level down (PR 35): where each sub-scope must be found in a
+# core's compiled iteration. (core, scope) -> (the scopes it is nested
+# in, a phase it must be found under, the phases it must never be
+# under).
+_P = profiling
+SUB_SCOPES = {
+    ("qwen3_next", _P.MIXER_PROJ): ((), LOSS_GRAD, ()),
+    ("qwen3_next", _P.MIXER_POINTWISE): ((), LOSS_GRAD, ()),
+    ("qwen3_next", _P.MIXER_CORE): ((), POLICY_ACT, ()),
+    ("qwen3_next", _P.GDN_CHUNK_SOLVE):
+        ((_P.GDN, _P.MIXER_CORE), LOSS_GRAD, (POLICY_ACT, ADVANTAGE)),
+    ("qwen3_next", _P.GDN_CHUNK_PRODUCTS):
+        ((_P.GDN, _P.MIXER_CORE), LOSS_GRAD, (POLICY_ACT, ADVANTAGE)),
+    ("qwen3_next", _P.GDN_STATE):
+        ((_P.GDN, _P.MIXER_CORE), POLICY_ACT, (LOSS_GRAD,)),
+    ("qwen3_next", _P.MOE_COMBINE):
+        ((_P.MOE, _P.MOE_DISPATCH), LOSS_GRAD, ()),
+    ("qwen3_next", _P.SAMPLE): ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
+    ("kimi_vl", _P.MIXER_PROJ): ((_P.MLA,), LOSS_GRAD, ()),
+    ("kimi_vl", _P.MIXER_POINTWISE): ((_P.MLA,), LOSS_GRAD, ()),
+    ("kimi_vl", _P.MIXER_CORE): ((_P.MLA,), POLICY_ACT, ()),
+    ("kimi_vl", _P.MLA_SEQ_ATTEND):
+        ((_P.MLA, _P.MIXER_CORE), LOSS_GRAD, (POLICY_ACT, ADVANTAGE)),
+    ("kimi_vl", _P.MOE_COMBINE): ((_P.MOE, _P.MOE_DISPATCH), LOSS_GRAD, ()),
+    ("kimi_vl", _P.SAMPLE): ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
+    ("sdar", _P.MIXER_PROJ): ((_P.GQA,), LOSS_GRAD, ()),
+    ("sdar", _P.MIXER_POINTWISE): ((_P.GQA,), LOSS_GRAD, ()),
+    ("sdar", _P.MIXER_CORE): ((_P.GQA,), POLICY_ACT, ()),
+    ("sdar", _P.GQA_SEQ_ATTEND):
+        ((_P.GQA, _P.MIXER_CORE), LOSS_GRAD, (POLICY_ACT, ADVANTAGE)),
+    ("sdar", _P.GQA_BLOCK_STEP):
+        ((_P.GQA, _P.MIXER_CORE), POLICY_ACT, (LOSS_GRAD,)),
+    ("sdar", _P.MOE_COMBINE): ((_P.MOE, _P.MOE_DISPATCH), LOSS_GRAD, ()),
+    ("sdar", _P.SAMPLE): ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
+}
+
+
+@pytest.mark.parametrize(
+    "core,scope", sorted(SUB_SCOPES), ids=lambda x: str(x)
+)
+def test_a_sequence_core_iteration_carries_its_sub_scopes(core, scope):
+    """Every sub-scope of ``utils/profiling.py`` is in the core's
+    compiled tiny iteration, inside the scopes it is declared in (in
+    that order), under the phase its work runs in and under no phase
+    it must not run in."""
+    outer, under, never = SUB_SCOPES[core, scope]
+    assert scope in profiling.LAYER_SCOPES
+    holding = [p for p in _phase_lists(core) if scope in p]
+    assert holding, (core, scope)
+    assert any(under in p for p in holding), (under, holding)
+    assert any(set(outer) <= set(p[:p.index(scope)]) for p in holding)
+    for phases in holding:
+        assert not set(never) & set(phases), phases
+        # (a reduction's sub-computation keeps only the tail of its
+        # name: what is there of the declared nesting is in order)
+        before = phases[:phases.index(scope)]
+        kept = [s for s in outer if s in before]
+        assert kept == [s for s in before if s in outer], phases
+        if scope in profiling.MIXER_PARTS and outer:
+            assert set(before) & set(profiling.MIXER_SCOPES), phases
+    if scope == _P.MLA_SEQ_ATTEND:
+        # the absorbed form's two products with kv_b_proj's halves are
+        # projections, the rest of mla_absorbed is the core
+        lists = _phase_lists(core)
+        assert any(_P.MLA_ABSORBED in p and _P.MIXER_PROJ in p for p in lists)
+        assert any(_P.MLA_ABSORBED in p and _P.MIXER_CORE in p for p in lists)
+
+
+@pytest.mark.parametrize("core", sorted(SEQUENCE_PRESETS))
+def test_a_mixer_is_partitioned_into_its_three_parts(core):
+    """Every instruction traced under a mixer's scope (gdn, gated_attn,
+    mla, gqa) is under exactly one of mixer_proj, mixer_pointwise and
+    mixer_core, in the forward pass, the recomputed one and the
+    backward pass: the three time shares add up to the mixers'."""
+    names = [n for n in re.findall(r'op_name="([^"]*)"',
+                                   _sequence_text(core))
+             if n.startswith("jit(")]
+    under = 0
+    for name in names:
+        # every name a merged instruction carries, not only the first
+        for one in name.split(";"):
+            phases = profiling.phases_of(one)
+            if set(phases) & set(profiling.MIXER_SCOPES):
+                under += 1
+                parts = [p for p in phases if p in profiling.MIXER_PARTS]
+                assert len(parts) == 1, one
+    assert under > 100, (core, under)
+    # and no part's name is found outside a mixer, but for the input
+    # norm that Qwen3-Next traces before its mixer's scope opens
+    loose = {
+        one for name in names for one in name.split(";")
+        if set(profiling.phases_of(one)) & set(profiling.MIXER_PARTS)
+        and not set(profiling.phases_of(one)) & set(profiling.MIXER_SCOPES)
+    }
+    if core == "qwen3_next":
+        assert loose and all(_P.MIXER_POINTWISE in n for n in loose), loose
+    else:
+        assert not loose, loose
+
+
 @pytest.mark.parametrize("op_name,phases", [
     ("jit(local_iteration)/rollout/while/body/closed_call/env_step/add",
      (ROLLOUT, ENV_STEP)),
@@ -315,6 +455,37 @@ def test_the_sdar_iteration_carries_its_layer_scopes(metadata_in_cache_key):
     ("jit(f)/update/loss_grad/jvp()/broadcast_in_dim;jit(f)/update/"
      "minibatch_prep/reshape", (UPDATE, LOSS_GRAD)),
     ("state.params['params']['Dense_0']['bias']", ()),
+    # the sequence cores' layers one level down, as the compiled text
+    # of the tiny presets names them: forward, ...
+    ("jit(local_iteration_recurrent)/rollout/while/body/closed_call/"
+     "policy_act/SDARActorCritic/gqa/mixer_core/gqa_block_step/bms,bsd->bmd/"
+     "dot_general",
+     (ROLLOUT, POLICY_ACT, _P.GQA, _P.MIXER_CORE, _P.GQA_BLOCK_STEP)),
+    ("jit(local_iteration_recurrent)/rollout/while/body/closed_call/"
+     "policy_act/sample/argmax", (ROLLOUT, POLICY_ACT, _P.SAMPLE)),
+    ("jit(local_iteration_recurrent)/advantage/KimiVLActorCritic/mla/"
+     "mla_absorbed/mixer_proj/bhn,chn->bhc/dot_general",
+     (ADVANTAGE, _P.MLA, _P.MLA_ABSORBED, _P.MIXER_PROJ)),
+    # ... differentiated, ...
+    ("jit(f)/update/while/body/closed_call/loss_grad/"
+     "jvp(Qwen3NextActorCritic)/gdn/mixer_core/gdn_chunk_solve/"
+     "triangular_solve",
+     (UPDATE, LOSS_GRAD, _P.GDN, _P.MIXER_CORE, _P.GDN_CHUNK_SOLVE)),
+    # ... and recomputed and transposed: the outer transform's own
+    # copy of loss_grad is the same phase, named once
+    ("jit(f)/update/while/body/closed_call/loss_grad/"
+     "transpose(jvp(SDARActorCritic))/loss_grad/jvp(SDARActorCritic)/"
+     "checkpoint/gqa/mixer_proj/dot_general",
+     (UPDATE, LOSS_GRAD, _P.GQA, _P.MIXER_PROJ)),
+    ("jit(f)/update/loss_grad/transpose(jvp(KimiVLActorCritic))/mla/"
+     "transpose(jvp(mixer_core))/transpose(jvp(mla_seq_attend))/mul",
+     (UPDATE, LOSS_GRAD, _P.MLA, _P.MIXER_CORE, _P.MLA_SEQ_ATTEND)),
+    ("jit(f)/update/loss_grad/transpose(jvp(M))/moe/moe_dispatch/"
+     "transpose(jvp(moe_combine))/gather",
+     (UPDATE, LOSS_GRAD, _P.MOE, _P.MOE_DISPATCH, _P.MOE_COMBINE)),
+    ("jit(f)/update/loss_grad/jvp(M)/gdn/mixer_core/gdn_chunk_products/"
+     "while/body/closed_call/bhck,bhkv->bhcv/dot_general",
+     (UPDATE, LOSS_GRAD, _P.GDN, _P.MIXER_CORE, _P.GDN_CHUNK_PRODUCTS)),
 ])
 def test_phases_of_an_op_name(op_name, phases):
     assert profiling.phases_of(op_name) == phases
@@ -388,10 +559,19 @@ def test_scope_table_names_what_the_compiler_made():
 # ---- a scope is metadata only -------------------------------------------
 
 
-def _ppo_iteration(seed):
-    fns = make_ppo(PPOConfig(
-        num_envs=4, num_epochs=2, num_minibatches=2, seed=seed, **TINY_PONG,
-    ))
+def _ppo_iteration(seed, preset=None):
+    """The metrics of one iteration and its compiled text: the tiny
+    feed-forward PPO, or a preset of ``cli/train.py``."""
+    if preset is None:
+        cfg = PPOConfig(
+            num_envs=4, num_epochs=2, num_minibatches=2, seed=seed,
+            **TINY_PONG,
+        )
+    else:
+        from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
+
+        cfg = PPOConfig(**dict(PRESETS[preset][1], seed=seed))
+    fns = make_ppo(cfg)
     state = fns.init(jax.random.PRNGKey(seed))
     text = fns.iteration.lower(state).compile().as_text()
     _, metrics = fns.iteration(state)
@@ -431,6 +611,30 @@ def test_scoped_and_unscoped_builds_are_one_program(
     assert _instructions(scoped_text) == _instructions(plain_text)
 
 
+@pytest.mark.parametrize("core", sorted(SEQUENCE_PRESETS))
+def test_scoped_and_unscoped_sequence_core_builds_are_one_program(
+    monkeypatch, metadata_in_cache_key, core
+):
+    """The same for a sequence core's fused iteration, whose mixers and
+    expert block open a scope around every few lines: the layers'
+    names, and no instruction, counter or number, are what a scope
+    adds."""
+    preset = SEQUENCE_PRESETS[core]
+    scoped_metrics, scoped_text = _ppo_iteration(seed=7, preset=preset)
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+    )
+    plain_metrics, plain_text = _ppo_iteration(seed=7, preset=preset)
+    for scope in profiling.MIXER_PARTS + (profiling.MOE_COMBINE,
+                                          profiling.SAMPLE):
+        assert f"/{scope}/" in scoped_text, scope
+        assert f"/{scope}/" not in plain_text, scope
+    assert scoped_metrics.keys() == plain_metrics.keys()
+    for k in scoped_metrics:
+        np.testing.assert_array_equal(scoped_metrics[k], plain_metrics[k])
+    assert _instructions(scoped_text) == _instructions(plain_text)
+
+
 def test_a_kernel_under_the_compilers_own_name_inherits_its_consumers_phases():
     """The TPU's grouped-product kernels reach the compiled text as
     ``op_name="ragged-dot-none"``: no traced function, so no scope of
@@ -450,3 +654,110 @@ def test_a_kernel_under_the_compilers_own_name_inherits_its_consumers_phases():
     kernel = next(k for k in table if k.startswith("%ragged-dot-none.1"))
     assert table[kernel] == (UPDATE, LOSS_GRAD, profiling.MOE,
                              profiling.MOE_EXPERTS)
+
+
+def test_a_kernel_that_feeds_another_phase_stays_with_its_operands():
+    """A weight gradient's grouped product feeds Adam (or the norm that
+    clips it), and nothing that feeds it is in ``optimizer``: it is
+    work of the differentiated function, and takes its operands'
+    phases, the deepest, the later operand on a tie. The kernel that
+    lays out its groups, the copy the compiler put before it and the
+    handle on its result follow it. (On the chip the three
+    language-model cells read 18, 15 and 12 such products an update
+    block as ``optimizer`` until PR 35.)"""
+    md = lambda name: ', metadata={op_name="%s"}' % name
+    grad = "jit(f)/update/loss_grad/transpose(jvp(M))/moe/%s"
+    text = "\n".join([
+        "HloModule jit_f, is_scheduled=true",
+        "ENTRY %main (x: f32[8,4], g: f32[8,4], n: s32[2]) -> f32[2,4,4] {",
+        "  %x = f32[8,4]{1,0} parameter(0)",
+        "  %g = f32[8,4]{1,0} parameter(1)",
+        "  %n = s32[2]{0} parameter(2)",
+        "  %xs = bf16[8,4]{1,0} convert(%x)"
+        + md(grad % "moe_dispatch/convert"),
+        "  %pair = (f32[8,4]{1,0}, f32[8,4]{1,0}) fusion(%g), kind=kLoop, "
+        "calls=%none" + md(grad % "moe_experts/mul"),
+        "  %cotangent = f32[8,4]{1,0} get-tuple-element(%pair), index=0",
+        "  %copy.1 = bf16[8,4]{0,1} copy(%xs)",
+        "  %ragged-dot-metadata.1 = (s32[3]{0}, s32[1]{0}) custom-call(%n), "
+        'custom_call_target="tpu_custom_call"' + md("ragged-dot-metadata"),
+        "  %groups = s32[3]{0} get-tuple-element(%ragged-dot-metadata.1), "
+        "index=0",
+        "  %ragged-dot-none.1 = f32[2,4,4]{2,1,0} custom-call(%groups, "
+        '%copy.1, %cotangent), custom_call_target="tpu_custom_call"'
+        + md("ragged-dot-none"),
+        "  %handle = f32[2,4,4]{2,1,0} bitcast(%ragged-dot-none.1)",
+        "  ROOT %adam = f32[2,4,4]{2,1,0} multiply(%handle, %handle)"
+        + md("jit(f)/update/optimizer/mul"),
+        "}",
+    ])
+    short = {k.split(" = ")[0]: v
+             for k, v in profiling.scope_table(text).items()}
+    experts = (UPDATE, LOSS_GRAD, profiling.MOE, profiling.MOE_EXPERTS)
+    assert short["%ragged-dot-none.1"] == experts
+    assert short["%ragged-dot-metadata.1"] == short["%groups"] == experts
+    assert short["%copy.1"] == experts
+    # what it feeds keeps its own phases; a handle on the result is
+    # for its consumer, as any copy
+    assert short["%adam"] == short["%handle"] == (UPDATE, OPTIMIZER)
+    assert short["%xs"] == (UPDATE, LOSS_GRAD, profiling.MOE,
+                            profiling.MOE_DISPATCH)
+    # a consumer in the operands' phase speaks, as before ...
+    same = text.replace(
+        "jit(f)/update/optimizer/mul", grad % "moe_combine/add"
+    )
+    short = {k.split(" = ")[0]: v
+             for k, v in profiling.scope_table(same).items()}
+    assert short["%ragged-dot-none.1"] == (
+        UPDATE, LOSS_GRAD, profiling.MOE, profiling.MOE_COMBINE
+    )
+
+
+def test_a_kernel_that_feeds_a_fusion_takes_the_phases_of_what_reads_it():
+    """The expert layer's last grouped product is read by a mask traced
+    under ``moe_experts`` that the compiler fuses into the scatter-add
+    of ``moe_combine``; the fusion carries its root's name. The fused
+    instruction that reads the kernel speaks for it, not the root. (On
+    the chip 0.69 ms of every 3.8 ms decode step of
+    ``ppo-kimivl-recall`` read as ``moe_dispatch`` until PR 35: the
+    product, not the dispatch.)"""
+    md = lambda name: (
+        ', metadata={op_name="jit(f)/rollout/policy_act/%s"}' % name
+    )
+    text = "\n".join([
+        "HloModule jit_f, is_scheduled=true",
+        "%fused_scatter (p0: f32[4,4], p1: f32[8,4], p2: f32[8]) -> "
+        "f32[4,4] {",
+        "  %p0 = f32[4,4]{1,0} parameter(0)",
+        "  %p1 = f32[8,4]{1,0} parameter(1)",
+        "  %p2 = f32[8]{0} parameter(2)",
+        "  %w = f32[8,4]{1,0} broadcast(%p2), dimensions={0}"
+        + md("moe/moe_experts/broadcast_in_dim"),
+        "  %masked = f32[8,4]{1,0} multiply(%p1, %w)"
+        + md("moe/moe_experts/mul"),
+        "  ROOT %out = f32[4,4]{1,0} scatter(%p0, %masked)"
+        + md("moe/moe_dispatch/moe_combine/scatter-add"),
+        "}",
+        "ENTRY %main (h: f32[8,4], w: f32[2,4,4], r: f32[8]) -> f32[4,4] {",
+        "  %h = f32[8,4]{1,0} parameter(0)",
+        "  %w = f32[2,4,4]{2,1,0} parameter(1)",
+        "  %r = f32[8]{0} parameter(2)",
+        "  %zeros = f32[4,4]{1,0} broadcast(%r)"
+        + md("moe/moe_dispatch/moe_combine/broadcast_in_dim"),
+        "  %ragged-dot-none.7 = f32[8,4]{1,0} custom-call(%h, %w), "
+        'custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}',
+        "  ROOT %scatter_fusion = f32[4,4]{1,0} fusion(%zeros, "
+        "%ragged-dot-none.7, %r), kind=kInput, calls=%fused_scatter"
+        + md("moe/moe_dispatch/moe_combine/scatter-add"),
+        "}",
+    ])
+    short = {k.split(" = ")[0]: v
+             for k, v in profiling.scope_table(text).items()}
+    assert short["%scatter_fusion"] == (
+        ROLLOUT, POLICY_ACT, profiling.MOE, profiling.MOE_DISPATCH,
+        profiling.MOE_COMBINE,
+    )
+    assert short["%ragged-dot-none.7"] == (
+        ROLLOUT, POLICY_ACT, profiling.MOE, profiling.MOE_EXPERTS,
+    )

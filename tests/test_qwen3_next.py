@@ -319,6 +319,65 @@ def test_the_overflow_counter_counts():
     assert float(stats["moe_overflow_pairs"]) == pairs - rows
 
 
+@pytest.mark.parametrize("capacity_factor,full", [
+    (4.0, False), (2.5, False), (2.0, True), (0.5, True),
+])
+def test_the_buffer_fill_is_the_kept_pairs_over_the_rows(
+    capacity_factor, full
+):
+    """``moe_buffer_fill_share``: the dispatch buffer's rows that hold
+    a pair, over its rows. Below 1 while the buffer holds every local
+    pair, exactly 1 once pairs overflow."""
+    cfg = dataclasses.replace(CFG, capacity_factor=capacity_factor)
+    p, x = _biased(cfg)
+    _, stats = qn.moe_block(p, x, cfg, jnp.float32)
+    rows = cfg.moe_capacity(x.shape[0])
+    pairs = float(stats["moe_local_pairs_per_token"]) * x.shape[0]
+    kept = pairs - float(stats["moe_overflow_pairs"])
+    fill = float(stats["moe_buffer_fill_share"])
+    assert fill == pytest.approx(kept / rows, rel=1e-6)
+    assert 0.0 < fill <= 1.0
+    assert (fill == 1.0) == full == (pairs > rows)
+
+
+@pytest.mark.parametrize("core", ["qwen3_next", "kimi_vl", "sdar"])
+def test_an_iteration_reports_the_buffer_fill_of_both_phases(core):
+    """Every core's ``iteration_stats`` carries the fill twice: the
+    mean over the rollout's steps and the mean over the update's
+    blocks, whose buffers differ in rows by orders of magnitude."""
+    from actor_critic_algs_on_tensorflow_tpu import models
+
+    model, _ = models.sequence_core(core)
+    module = sys.modules[model.__module__]
+    steps, blocks = 6, 2
+    counters = ("moe_local_pairs_per_token", "moe_expert_load_max_over_mean",
+                "moe_overflow_pairs", "moe_experts_touched_share")
+    rollout = {k: jnp.zeros((steps,)) for k in counters}
+    update = {k: jnp.zeros((1, blocks)) for k in counters}
+    rollout["moe_buffer_fill_share"] = jnp.linspace(0.1, 0.2, steps)
+    update["moe_buffer_fill_share"] = jnp.asarray([[0.25, 0.75]])
+    # what else a core's reduction reads: its own counters, one row a
+    # step or a block
+    for name in ("CACHE_ROWS_READ", "COMMITTED_TOKENS"):
+        if hasattr(module, name):
+            rollout[getattr(module, name)] = jnp.ones((steps,))
+    for name in ("REVEALED_POSITIONS", "DENOISE_PASSES", "POSITIONS",
+                 "SCORE_TILES_COMPUTED"):
+        if hasattr(module, name):
+            update[getattr(module, name)] = jnp.ones((1, blocks))
+    stats = jax.vmap(
+        lambda r, u: model.iteration_stats(r, u, "data"),
+        axis_name="data",
+    )(*jax.tree_util.tree_map(lambda x: x[None], (rollout, update)))
+    np.testing.assert_allclose(
+        stats["moe_buffer_fill_share_rollout"], [0.15], rtol=1e-6
+    )
+    np.testing.assert_allclose(
+        stats["moe_buffer_fill_share_update"], [0.5], rtol=1e-6
+    )
+    assert "moe_buffer_fill_share" not in stats
+
+
 @pytest.mark.parametrize("tokens,touched", [(64, 1.0), (1, 0.5), (0, 0.0)])
 def test_the_touched_experts_are_counted(tokens, touched):
     """The held experts a call gave a row, as a share of the two held:
@@ -461,6 +520,8 @@ def test_a_short_run_trains_and_counts():
     assert float(metrics["moe_overflow_pairs"]) == 0.0
     assert 0.0 < float(metrics["moe_local_pairs_per_token"]) < 2.0
     assert 0.0 < float(metrics["moe_experts_touched_share"]) <= 1.0
+    for phase in ("rollout", "update"):
+        assert 0.0 < float(metrics[f"moe_buffer_fill_share_{phase}"]) <= 1.0
     assert float(metrics["episodes"]) == cfg.num_envs
     assert int(state.step) == 2
     assert fns.steps_per_iteration == cfg.num_envs * cfg.rollout_length
