@@ -443,9 +443,9 @@ def gqa_seq(p, x, positions, step, key_commit, cfg, dtype):
 def routed_experts(p, x, cfg: SDARConfig, dtype, every_pair=False):
     """``x [N, H]`` -> ``(y [N, H], stats)``: the held experts' terms
     of the routed sum (there is no shared expert). ``every_pair``: the
-    dispatch buffer holds every pair the ``N`` positions could send
-    here (``N * top_k`` rows) instead of ``capacity_factor`` times the
-    expected ones."""
+    dispatch buffer may hold every pair the ``N`` positions could send
+    here (at most ``N * top_k`` rows) instead of ``capacity_factor``
+    times the expected ones."""
     spec = cfg.expert_spec
     if every_pair:
         spec = dataclasses.replace(
@@ -492,9 +492,14 @@ def _decoder_layer_step(p, x, caches, layer, pos, cfg, dtype):
     # outputs that are averages of the same values): where the whole
     # batch's blocks are masks, the held experts get none of the pairs
     # or several times the expected ones, by the seed (more than twice
-    # in 1 seed of 10). At a pass's few hundred positions a buffer for
-    # every pair costs little; the update's buffer, over a trajectory's
-    # mix of positions, is cfg.capacity_factor's.
+    # in 1 seed of 10). So the most the buffer may hold is every pair;
+    # the update's, over a trajectory's mix of positions, is
+    # cfg.capacity_factor's. Run at all those rows whatever landed, the
+    # buffer cost the chip 5.6 ms of every 9.05 ms pass for rows an
+    # eighth full (PERF.md section 6, PR 36): moe.routed_experts runs
+    # each call at the lowest rung of a short ladder of row counts that
+    # holds the pairs it counted, and the top rung only where they need
+    # it.
     x, stats = _expert_layer(p, x + y, cfg, dtype, every_pair=True)
     return x, caches, stats
 

@@ -505,6 +505,8 @@ def test_a_short_run_trains_and_counts():
     assert 0.0 < float(metrics["moe_experts_touched_share"]) <= 1.0
     for phase in ("rollout", "update"):
         assert 0.0 < float(metrics[f"moe_buffer_fill_share_{phase}"]) <= 1.0
+        used = float(metrics[f"moe_buffer_rows_used_share_{phase}"])
+        assert 0.0 < used <= 1.0
     # the plain form ran (the CPU, a narrow cache): every row, every step
     assert float(metrics[kv.CACHE_ROWS_READ]) == 1.0
     assert float(metrics["episodes"]) == cfg.num_envs

@@ -620,10 +620,15 @@ def test_scoped_and_unscoped_sequence_core_builds_are_one_program(
     names, and no instruction, counter or number, are what a scope
     adds."""
     preset = SEQUENCE_PRESETS[core]
+    # the expert layer's two switches are traced once a process and
+    # inlined where called (PR 36): without this a build would be
+    # handed the trace of the one before it, its names and all
+    jax.clear_caches()
     scoped_metrics, scoped_text = _ppo_iteration(seed=7, preset=preset)
     monkeypatch.setattr(
         jax, "named_scope", lambda name: contextlib.nullcontext()
     )
+    jax.clear_caches()
     plain_metrics, plain_text = _ppo_iteration(seed=7, preset=preset)
     for scope in profiling.MIXER_PARTS + (profiling.MOE_COMBINE,
                                           profiling.SAMPLE):
@@ -761,3 +766,65 @@ def test_a_kernel_that_feeds_a_fusion_takes_the_phases_of_what_reads_it():
     assert short["%ragged-dot-none.7"] == (
         ROLLOUT, POLICY_ACT, profiling.MOE, profiling.MOE_EXPERTS,
     )
+
+
+def test_a_kernel_in_a_branch_computation_follows_what_it_feeds():
+    """The expert layer picks its buffer's rows under ``lax.switch``
+    (PR 36): the grouped products sit in the ``conditional``'s branch
+    computations, fed by handles on the branch's parameter, which
+    carry no scope of the program's. A kernel there takes the phases of
+    what it feeds, branch by branch, as in any computation, and the
+    branch's scatter-add keeps ``moe_combine``
+    (``tests/test_tpu_hlo.py`` holds the same on the compiled SDAR
+    step)."""
+    md = lambda name: (
+        ', metadata={op_name="jit(f)/rollout/policy_act/moe/%s"}' % name
+    )
+    in_branch = "cond/branch_%d_fun/%s"
+    branch = lambda i, rows: [
+        "%%region_%d (arg: (bf16[%d,4], bf16[2,4,4], s32[%d])) -> "
+        "f32[4,4] {" % (i, rows, rows),
+        "  %%arg.%d = (bf16[%d,4]{1,0}, bf16[2,4,4]{2,1,0}, s32[%d]{0}) "
+        "parameter(0)" % (i, rows, rows),
+        "  %%xs.%d = bf16[%d,4]{1,0} get-tuple-element(%%arg.%d), index=0"
+        % (i, rows, i),
+        "  %%w.%d = bf16[2,4,4]{2,1,0} get-tuple-element(%%arg.%d), index=1"
+        % (i, i),
+        "  %%token.%d = s32[%d]{0} get-tuple-element(%%arg.%d), index=2"
+        % (i, rows, i),
+        "  %%ragged-dot-none.%d = f32[%d,4]{1,0} custom-call(%%xs.%d, "
+        '%%w.%d), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}' % (i, rows, i, i),
+        "  %%masked.%d = f32[%d,4]{1,0} multiply(%%ragged-dot-none.%d, "
+        "%%ragged-dot-none.%d)" % (i, rows, i, i)
+        + md(in_branch % (i, "moe_experts/mul")),
+        "  ROOT %%add.%d = f32[4,4]{1,0} scatter(%%masked.%d, %%token.%d)"
+        % (i, i, i)
+        + md(in_branch % (i, "moe_dispatch/moe_combine/scatter-add")),
+        "}",
+    ]
+    text = "\n".join([
+        "HloModule jit_f, is_scheduled=true",
+        *branch(0, 8), *branch(1, 16),
+        "ENTRY %main (r: s32[], a: (bf16[8,4], bf16[2,4,4], s32[8]), "
+        "b: (bf16[16,4], bf16[2,4,4], s32[16])) -> f32[4,4] {",
+        "  %r = s32[] parameter(0)",
+        "  %a = (bf16[8,4]{1,0}, bf16[2,4,4]{2,1,0}, s32[8]{0}) "
+        "parameter(1)",
+        "  %b = (bf16[16,4]{1,0}, bf16[2,4,4]{2,1,0}, s32[16]{0}) "
+        "parameter(2)",
+        "  ROOT %cond.1 = f32[4,4]{1,0} conditional(%r, %a, %b), "
+        "branch_computations={%region_0, %region_1}" + md("cond"),
+        "}",
+    ])
+    short = {k.split(" = ")[0]: v
+             for k, v in profiling.scope_table(text).items()}
+    step = (ROLLOUT, POLICY_ACT, profiling.MOE)
+    for i in (0, 1):
+        assert short[f"%ragged-dot-none.{i}"] == step + (
+            profiling.MOE_EXPERTS,
+        )
+        assert short[f"%add.{i}"] == step + (
+            profiling.MOE_DISPATCH, profiling.MOE_COMBINE,
+        )
+    assert short["%cond.1"] == step

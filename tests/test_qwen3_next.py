@@ -356,6 +356,8 @@ def test_an_iteration_reports_the_buffer_fill_of_both_phases(core):
     update = {k: jnp.zeros((1, blocks)) for k in counters}
     rollout["moe_buffer_fill_share"] = jnp.linspace(0.1, 0.2, steps)
     update["moe_buffer_fill_share"] = jnp.asarray([[0.25, 0.75]])
+    rollout["moe_buffer_rows_used_share"] = jnp.linspace(0.2, 0.4, steps)
+    update["moe_buffer_rows_used_share"] = jnp.asarray([[0.375, 1.0]])
     # what else a core's reduction reads: its own counters, one row a
     # step or a block
     for name in ("CACHE_ROWS_READ", "COMMITTED_TOKENS"):
@@ -376,6 +378,12 @@ def test_an_iteration_reports_the_buffer_fill_of_both_phases(core):
         stats["moe_buffer_fill_share_update"], [0.5], rtol=1e-6
     )
     assert "moe_buffer_fill_share" not in stats
+    np.testing.assert_allclose(
+        stats["moe_buffer_rows_used_share_rollout"], [0.3], rtol=1e-6
+    )
+    np.testing.assert_allclose(
+        stats["moe_buffer_rows_used_share_update"], [0.6875], rtol=1e-6
+    )
 
 
 @pytest.mark.parametrize("tokens,touched", [(64, 1.0), (1, 0.5), (0, 0.0)])
@@ -522,6 +530,8 @@ def test_a_short_run_trains_and_counts():
     assert 0.0 < float(metrics["moe_experts_touched_share"]) <= 1.0
     for phase in ("rollout", "update"):
         assert 0.0 < float(metrics[f"moe_buffer_fill_share_{phase}"]) <= 1.0
+        used = float(metrics[f"moe_buffer_rows_used_share_{phase}"])
+        assert 0.0 < used <= 1.0
     assert float(metrics["episodes"]) == cfg.num_envs
     assert int(state.step) == 2
     assert fns.steps_per_iteration == cfg.num_envs * cfg.rollout_length

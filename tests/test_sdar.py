@@ -530,6 +530,8 @@ def test_a_short_run_trains_and_counts():
     assert 0.0 < float(metrics["moe_experts_touched_share"]) <= 1.0
     for phase in ("rollout", "update"):
         assert 0.0 < float(metrics[f"moe_buffer_fill_share_{phase}"]) <= 1.0
+        used = float(metrics[f"moe_buffer_rows_used_share_{phase}"])
+        assert 0.0 < used <= 1.0
     # 6 passes a turn commit 8 tokens; a denoising pass reveals 1 of 4
     # positions; of a turn's 24 positions the log-prob reads 4
     np.testing.assert_allclose(

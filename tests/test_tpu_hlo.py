@@ -265,6 +265,99 @@ def test_expert_layer_compiles_to_grouped_product_kernels(one_chip):
     assert len(products) == 9, kernels
     assert sum(s.startswith("f32[32,") for s in products) == 3  # dW
     assert sum(s.startswith("f32[10240,") for s in products) == 6
+    # one rung at 2.0 x: the buffer's rows are not chosen a call
+    assert cfg.expert_spec.buffer_ladder(tokens) == (10240,)
+    assert " conditional(" not in text
+
+
+def test_a_blocks_pass_picks_its_buffers_rows_by_the_pairs_it_counted(
+    one_chip
+):
+    """One pass of the ``ppo-sdar-turns`` preset (128 blocks of 4
+    positions) through the expert layer for the described v5e: ONE
+    ``conditional`` over the ladder's three row counts, each branch the
+    three grouped products at its own rows and the scatter-add back;
+    the experts' weights reach the branches as they are (cast once,
+    outside, where a rollout's loop can hoist it; no copy of them); and
+    the trace reader's join (``profiling.scope_table``) finds every
+    kernel inside a branch under ``moe_experts`` and every branch's
+    scatter-add under ``moe_combine``, though a branch's parameter
+    carries no scope."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import sdar
+    from actor_critic_algs_on_tensorflow_tpu.utils import profiling
+
+    cfg = PRESETS["ppo-sdar-turns"][1]["seq_model"]
+    ladder = (768, 1536, 4096)
+    spec = sdar.layer_param_spec(cfg)
+    names = ("post_norm", "router", "w_gate", "w_up", "w_down")
+    p = {n: jax.ShapeDtypeStruct(spec[n][0], jnp.float32, sharding=one_chip)
+         for n in names}
+    x = jax.ShapeDtypeStruct((128, cfg.block_length, cfg.hidden_size),
+                             jnp.float32, sharding=one_chip)
+
+    def step(p, x):
+        with jax.named_scope(profiling.ROLLOUT), jax.named_scope(
+            profiling.POLICY_ACT
+        ):
+            return sdar._expert_layer(
+                p, x, cfg, jnp.bfloat16, every_pair=True
+            )
+
+    text = jax.jit(step).lower(p, x).compile().as_text()
+    (branches,) = re.findall(
+        r" conditional\(.*branch_computations=\{([^}]*)\}", text
+    )
+    branches = [b.strip().lstrip("%") for b in branches.split(",")]
+    assert len(branches) == len(ladder)
+    computation, inside = None, {}
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            computation = head.group(1)
+        elif " = " in line:
+            inside.setdefault(computation, []).append(line.strip())
+    table = profiling.scope_table(text)
+    step_phases = (profiling.ROLLOUT, profiling.POLICY_ACT, profiling.MOE)
+
+    def phases(line):
+        return table[line.removeprefix("ROOT ").split(", metadata=")[0]]
+
+    for rows, branch in zip(ladder, branches):
+        kernels = [line for line in inside[branch]
+                   if line.startswith("%ragged-dot-none")]
+        assert sorted(
+            line.split(" = ")[1].split("{")[0] for line in kernels
+        ) == [f"f32[{rows},2048]", f"f32[{rows},768]", f"f32[{rows},768]"]
+        for line in kernels:
+            assert phases(line) == step_phases + (profiling.MOE_EXPERTS,)
+        adds = [line for line in inside[branch]
+                if re.match(r"%\S+ = f32\[512,2048\]\S* fusion\(", line)
+                and "/scatter-add" in line]
+        assert adds, branch
+        for line in adds:
+            assert phases(line) == step_phases + (
+                profiling.MOE_DISPATCH, profiling.MOE_COMBINE
+            )
+    # no kernel outside the branches, and the weights as they came: in
+    # float32 the parameters alone, in bfloat16 one cast each, made in
+    # the entry computation, and no copy of either (what the compiler's
+    # memory-space assignment prefetches into fast memory, `S(1)`, for
+    # a kernel is its own business)
+    assert sum(line.startswith("%ragged-dot-none")
+               for lines in inside.values() for line in lines) == 9
+    weights = re.compile(
+        r"^%?\S+ = (f32|bf16)\[16,(?:2048,768|768,2048)\](\S*) ([\w\-]+)\("
+    )
+    made = [(c, *m.groups()) for c, lines in inside.items()
+            for m in map(weights.match, lines)
+            if m and m.group(3) not in FREE]
+    assert not [m for m in made if m[1] == "f32"], made
+    casts = [m for m in made if m[3] in ("convert", "fusion")]
+    assert len(casts) == 3 and not any(m[0] in branches for m in casts), made
+    assert all("S(1)" in m[2] and m[3] != "copy"
+               for m in made if m not in casts), made
 
 
 def test_chunked_deltanet_compiles_at_the_timed_shape(one_chip):
