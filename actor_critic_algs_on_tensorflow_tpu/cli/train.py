@@ -95,6 +95,14 @@ def _sdar_config(**kw):
     return SDARConfig(**kw)
 
 
+def _granite_hybrid_config(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.models.granite_hybrid import (
+        GraniteHybridConfig,
+    )
+
+    return GraniteHybridConfig(**kw)
+
+
 def _snapshot_fits(fns, cfg) -> bool:
     """Whether a device has room for the sentinel's rollback target, a
     second copy of the train state: three times the state within the
@@ -576,6 +584,64 @@ PRESETS = {
                 moe_intermediate_size=32, vocab_size=64, mask_token_id=63,
                 first_expert=0, experts_held=4, capacity_factor=4.0,
                 block_length=4, denoising_steps=4,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 8,
+            "rollout_length": 24,
+            "total_env_steps": 4_096,
+            "num_devices": 1,
+        },
+    ),
+    # 16. Token-level PPO with Granite-4.0-H-Micro as the policy, a
+    # dense hybrid of Mamba-2 state-space layers and attention without
+    # positions, at the published widths, cut to one chip's share of a
+    # stated deployment: the 40 layers as four pipeline stages of one
+    # period each (layers 0-9 here: five Mamba-2, the attention layer,
+    # four Mamba-2, every head and the whole SwiGLU of each) and 1/8 of
+    # the tied vocabulary: 772.2 M parameters, 12.35 GB with gradients
+    # and Adam's moments. One episode is one sequence of 512 tokens
+    # (two chunks of the scan) on the token-recall env; the schedule is
+    # perfbench/traffic/recall-32x512-e1mb4.json's.
+    "ppo-granite-recall": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=12_544, delay=64, episode_length=512
+            ),
+            "torso": "granite_hybrid",
+            "seq_model": _granite_hybrid_config(
+                num_hidden_layers=10,
+                layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+                vocab_size=12_544,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 32,
+            "rollout_length": 512,
+            "num_minibatches": 4,
+            "compute_dtype": "bfloat16",
+            "total_env_steps": 10_000_000,
+        },
+    ),
+    # The same model and schedule at widths for the CPU tests: hidden
+    # 64, 8 Mamba-2 heads of 16 over a state of 16 in chunks of 8,
+    # 4 query / 2 key-value heads of 16, a SwiGLU of 128, vocabulary
+    # 64, a rollout of three chunks.
+    "ppo-granite-tiny": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=64, delay=4, episode_length=24
+            ),
+            "torso": "granite_hybrid",
+            "seq_model": _granite_hybrid_config(
+                hidden_size=64, num_hidden_layers=3,
+                layer_types=("mamba", "attention", "mamba"),
+                mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+                mamba_chunk_size=8, num_attention_heads=4,
+                num_key_value_heads=2, attention_multiplier=0.0625,
+                shared_intermediate_size=128, vocab_size=64,
             ),
             **_PPO_TOKEN_SCHEDULE,
             "num_envs": 8,

@@ -25,6 +25,8 @@ SEQUENCE_CORES = {
     "qwen3_next": ("qwen3_next", "Qwen3NextActorCritic", "Qwen3NextConfig"),
     "kimi_vl": ("kimi_vl", "KimiVLActorCritic", "KimiVLConfig"),
     "sdar": ("sdar", "SDARActorCritic", "SDARConfig"),
+    "granite_hybrid": ("granite_hybrid", "GraniteHybridActorCritic",
+                       "GraniteHybridConfig"),
 }
 
 

@@ -11,13 +11,14 @@
   of the unscoped build.
 - ``LAYER_SCOPES``: the sequence cores' layers, named the same way
   inside ``policy_act`` and ``loss_grad``: a mixer (``gdn``,
-  ``gated_attn``, ``mla``, ``gqa``), inside it exactly one of
+  ``gated_attn``, ``mla``, ``gqa``, ``mamba``), inside it exactly one of
   ``mixer_proj`` / ``mixer_pointwise`` / ``mixer_core`` over every
   instruction (projections; norms, rotary, gates, convolution,
   reshapes; scores to weighted sum or the delta rule, with the cache or
   state write), inside the core its forms (``gdn_state``,
   ``gdn_chunk_solve``, ``gdn_chunk_products``, ``mla_seq_attend``,
-  ``gqa_block_step``, ``gqa_seq_attend``; ``mla_absorbed`` holds its two
+  ``gqa_block_step``, ``gqa_seq_attend``, ``mamba_state``,
+  ``mamba_chunk_scan``; ``mla_absorbed`` holds its two
   products with ``kv_b_proj``'s halves as ``mixer_proj``); the expert
   block (``moe`` with ``moe_router``, ``moe_dispatch`` and in it
   ``moe_combine``, ``moe_experts``, ``moe_shared``), ``dense_mlp``,
@@ -55,8 +56,8 @@ MINIBATCH_PREP = "minibatch_prep"  # in update: slice, convert, relayout
 LOSS_GRAD = "loss_grad"            # in update: forward + backward
 OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
 # Layers of the sequence-policy cores (models/qwen3_next.py,
-# models/kimi_vl.py, models/sdar.py; the expert block is
-# models/moe.py's), inside policy_act and loss_grad; read like the
+# models/kimi_vl.py, models/sdar.py, models/granite_hybrid.py; the
+# expert block is models/moe.py's), inside policy_act and loss_grad; read like the
 # phases, listed apart.
 GDN = "gdn"                        # a Gated DeltaNet mixer
 GDN_STATE = "gdn_state"            # in gdn: the step form's state update
@@ -74,7 +75,7 @@ GQA = "gqa"                        # a grouped-query mixer, both forms
 GQA_BLOCK_STEP = "gqa_block_step"  # in gqa: a block's pass over the cache
 GQA_SEQ_ATTEND = "gqa_seq_attend"  # in gqa: the sequence form less projections
 # One level down, the same three names in every mixer (gdn, gated_attn,
-# mla, gqa), both forms, no layer index: every instruction traced under
+# mla, gqa, mamba), both forms, no layer index: every instruction traced under
 # a mixer's scope is under exactly one of them.
 MIXER_PROJ = "mixer_proj"          # the linear projections in and out
 MIXER_POINTWISE = "mixer_pointwise"  # norms, rotary, gates, conv, reshapes
@@ -84,12 +85,16 @@ GDN_CHUNK_SOLVE = "gdn_chunk_solve"  # in gdn's core: a, rhs, the solve
 GDN_CHUNK_PRODUCTS = "gdn_chunk_products"  # in gdn's core: decay, qk, scan
 MOE_COMBINE = "moe_combine"        # in moe_dispatch: the scatter-add back
 SAMPLE = "sample"                  # in policy_act: sample and its log-prob
+MAMBA = "mamba"                    # a Mamba-2 mixer, both forms
+MAMBA_STATE = "mamba_state"        # in mamba's core: the step form's update
+MAMBA_CHUNK_SCAN = "mamba_chunk_scan"  # in mamba's core: the chunked scan
 LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_SHARED, LM_HEAD, MLA, MLA_ABSORBED,
                 DENSE_MLP, GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND,
                 MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE, MLA_SEQ_ATTEND,
-                GDN_CHUNK_SOLVE, GDN_CHUNK_PRODUCTS, MOE_COMBINE, SAMPLE)
-MIXER_SCOPES = (GDN, GATED_ATTN, MLA, GQA)
+                GDN_CHUNK_SOLVE, GDN_CHUNK_PRODUCTS, MOE_COMBINE, SAMPLE,
+                MAMBA, MAMBA_STATE, MAMBA_CHUNK_SCAN)
+MIXER_SCOPES = (GDN, GATED_ATTN, MLA, GQA, MAMBA)
 MIXER_PARTS = (MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)

@@ -608,3 +608,52 @@ def test_the_sdar_presets_resolve_and_need_no_rollback_copy(monkeypatch):
     )
     monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [chip])
     assert not cli._snapshot_fits(fns, cfg)
+
+
+def test_the_granite_presets_resolve_and_need_no_rollback_copy(monkeypatch):
+    """Both Granite hybrid presets are PPO configs whose env and model
+    agree on the vocabulary; one episode is one rollout, a whole number
+    of the scan's chunks and more than one; the cell's carry is four
+    arrays with the env axis first; and its train state (772 M
+    parameters with Adam's moments, 8.6 GiB) does not fit a 16 GB chip
+    beside a rollback copy of itself, so the sentinel's snapshot is
+    left out as for the other sequence-core presets."""
+    import types
+
+    import jax
+
+    from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (
+        PPOConfig,
+        make_ppo,
+    )
+
+    for name in ("ppo-granite-recall", "ppo-granite-tiny"):
+        algo, base = cli.PRESETS[name]
+        cfg = PPOConfig(**dict(base, num_devices=1))
+        env, model = cfg.env_params, cfg.seq_model
+        assert algo == "ppo" and cfg.torso == "granite_hybrid"
+        assert cfg.recurrent and cfg.num_minibatches == 4
+        assert env.episode_length == cfg.rollout_length
+        assert env.vocab_size == model.vocab_size
+        chunks, rest = divmod(cfg.rollout_length, model.mamba_chunk_size)
+        assert chunks >= 2 and rest == 0
+        assert env.delay > model.mamba_d_conv - 1  # beyond its reach
+        assert model.layer_types.count("attention") == 1
+    fns = make_ppo(cfg)  # the tiny one
+    assert cli._snapshot_fits(fns, cfg)  # the CPU reports no capacity
+    algo, base = cli.PRESETS["ppo-granite-recall"]
+    cfg = PPOConfig(**dict(base, num_devices=1))
+    fns = make_ppo(cfg)
+    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
+    assert n_params == 772_162_497
+    core = state.carry["core"]
+    assert core["state"].shape == (32, 9, 64, 64, 128)
+    assert core["conv"].shape == (32, 9, 3, 4352)
+    assert core["k"].shape == core["v"].shape == (32, 1, 512, 8, 64)
+    assert state.obs.shape == (32,)
+    chip = types.SimpleNamespace(
+        memory_stats=lambda: {"bytes_limit": int(15.75 * 2**30)}
+    )
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [chip])
+    assert not cli._snapshot_fits(fns, cfg)

@@ -22,7 +22,7 @@ import sys
 # the presets build every sequence core's config: their modules, the
 # block-diffusion core and its env among them, are imported by now
 for module in ("models.qwen3_next", "models.kimi_vl", "models.sdar",
-               "envs.block_turns"):
+               "models.granite_hybrid", "envs.block_turns"):
     assert "actor_critic_algs_on_tensorflow_tpu." + module in sys.modules
 # Behavioral probe (public API only): selecting a platform after the
 # package import only takes effect while the backend is still

@@ -223,7 +223,10 @@ SEQUENCE_PRESETS = {
     "qwen3_next": "ppo-qwen3next-tiny",
     "kimi_vl": "ppo-kimivl-tiny",
     "sdar": "ppo-sdar-tiny",
+    "granite_hybrid": "ppo-granite-tiny",
 }
+# The cores with no expert layer: no moe_* scope is looked for there.
+DENSE_CORES = {"granite_hybrid"}
 _SEQUENCE_TEXTS = {}
 
 
@@ -374,6 +377,17 @@ SUB_SCOPES = {
         ((_P.GQA, _P.MIXER_CORE), POLICY_ACT, (LOSS_GRAD,)),
     ("sdar", _P.MOE_COMBINE): ((_P.MOE, _P.MOE_DISPATCH), LOSS_GRAD, ()),
     ("sdar", _P.SAMPLE): ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
+    ("granite_hybrid", _P.MIXER_PROJ): ((_P.MAMBA,), LOSS_GRAD, ()),
+    ("granite_hybrid", _P.MIXER_POINTWISE): ((_P.MAMBA,), LOSS_GRAD, ()),
+    ("granite_hybrid", _P.MIXER_CORE): ((_P.GQA,), POLICY_ACT, ()),
+    ("granite_hybrid", _P.MAMBA_CHUNK_SCAN):
+        ((_P.MAMBA, _P.MIXER_CORE), LOSS_GRAD, (POLICY_ACT, ADVANTAGE)),
+    ("granite_hybrid", _P.MAMBA_STATE):
+        ((_P.MAMBA, _P.MIXER_CORE), POLICY_ACT, (LOSS_GRAD,)),
+    ("granite_hybrid", _P.DENSE_MLP): ((), LOSS_GRAD, ()),
+    ("granite_hybrid", _P.LM_HEAD): ((), LOSS_GRAD, ()),
+    ("granite_hybrid", _P.SAMPLE):
+        ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
 }
 
 
@@ -411,7 +425,7 @@ def test_a_sequence_core_iteration_carries_its_sub_scopes(core, scope):
 @pytest.mark.parametrize("core", sorted(SEQUENCE_PRESETS))
 def test_a_mixer_is_partitioned_into_its_three_parts(core):
     """Every instruction traced under a mixer's scope (gdn, gated_attn,
-    mla, gqa) is under exactly one of mixer_proj, mixer_pointwise and
+    mla, gqa, mamba) is under exactly one of mixer_proj, mixer_pointwise and
     mixer_core, in the forward pass, the recomputed one and the
     backward pass: the three time shares add up to the mixers'."""
     names = [n for n in re.findall(r'op_name="([^"]*)"',
@@ -630,8 +644,10 @@ def test_scoped_and_unscoped_sequence_core_builds_are_one_program(
     )
     jax.clear_caches()
     plain_metrics, plain_text = _ppo_iteration(seed=7, preset=preset)
-    for scope in profiling.MIXER_PARTS + (profiling.MOE_COMBINE,
-                                          profiling.SAMPLE):
+    scopes = profiling.MIXER_PARTS + (profiling.SAMPLE,) + (
+        () if core in DENSE_CORES else (profiling.MOE_COMBINE,)
+    )
+    for scope in scopes:
         assert f"/{scope}/" in scoped_text, scope
         assert f"/{scope}/" not in plain_text, scope
     assert scoped_metrics.keys() == plain_metrics.keys()

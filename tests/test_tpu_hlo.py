@@ -656,3 +656,98 @@ def test_the_update_attention_keeps_its_scores_off_the_chips_memory(one_chip):
     # beside the arguments 0.94 GiB: the queries, the output, their
     # cotangents (151 MB each in float32); the plain form's 1.53
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * 2 ** 30
+
+
+def test_the_state_space_step_updates_its_carry_in_place(one_chip):
+    """The Granite hybrid core's step form at the timed shape (32 envs;
+    nine Mamba-2 layers' states ``[64, 64, 128]`` and convolution tails
+    in one array each, one attention layer's key and value caches of
+    512 rows) as the rollout runs it: two Mamba-2 mixers and the
+    attention in a loop that carries the donated arrays, lowered for
+    the described v5e. All four arrays are aliased to the program's
+    arguments; the states, 18 MiB an env, are written by the
+    multiply-add's own fusion through a dynamic-update-slice (one a
+    layer, each reading and writing that layer's 2 MiB an env and no
+    other) and nothing else produces, copies or stages an array of
+    their shape; the tails and the caches, 15 and 2 x 16 MiB in all,
+    are, in the loop's body, either written in place or staged whole
+    through VMEM around the step (``copy-start`` / ``copy-done``) and
+    never copied in HBM (the program may change a cache's layout once,
+    outside the loop); and beside the carry the program holds less than
+    one layer's states."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import granite_hybrid as gh
+
+    cfg = PRESETS["ppo-granite-recall"][1]["seq_model"]
+    B, L, H, steps = 32, 512, cfg.hidden_size, 3
+    n_mamba, n_attn = cfg.layers_of("mamba"), cfg.layers_of("attention")
+    assert (n_mamba, n_attn) == (9, 1)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def params(layer, names):
+        spec = gh.layer_param_spec(cfg, layer)
+        return {name: arr(spec[name][0]) for name in names}
+
+    mamba = params(0, ("in_proj", "conv", "conv_bias", "dt_bias", "A_log",
+                       "D", "mamba_norm", "out_proj"))
+    attn = params(5, ("q_proj", "k_proj", "v_proj", "o_proj"))
+    state = (B, n_mamba, cfg.mamba_n_heads, cfg.mamba_d_head,
+             cfg.mamba_d_state)
+    tails = (B, n_mamba, cfg.mamba_d_conv - 1, cfg.conv_channels)
+    cache = (B, n_attn, L, cfg.num_key_value_heads, cfg.head_dim)
+
+    def rollout(mamba, attn, xs, carry, pos):
+        def step(carried, x):
+            (S, tails, k, v), pos = carried
+            keep = jnp.ones((B,), jnp.float32)
+            for layer in (3, 4):
+                y, S, tails, _ = gh.mamba_mixer_step(
+                    mamba, x, S, tails, layer, keep, cfg, jnp.bfloat16
+                )
+                x = x + y
+            y, k, v = gh.gqa_step(attn, x, k, v, 0, pos, cfg, jnp.bfloat16)
+            return ((S, tails, k, v), pos + 1), x + y
+
+        (carry, pos), ys = jax.lax.scan(step, (carry, pos), xs)
+        return ys, carry, pos
+
+    compiled = jax.jit(rollout, donate_argnums=3).lower(
+        mamba, attn, arr((steps, B, H)),
+        (arr(state), arr(tails), arr(cache, jnp.bfloat16),
+         arr(cache, jnp.bfloat16)),
+        arr((B,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    memory = compiled.memory_analysis()
+    carried = 4 * (math.prod(state) + math.prod(tails)) + 2 * 2 * (
+        math.prod(cache)
+    )
+    # (the chip may pad a carried array's tiles: at least the carry)
+    assert memory.alias_size_in_bytes >= carried
+    assert memory.temp_size_in_bytes < 4 * math.prod(state) // n_mamba
+    comps = unfused(text)
+
+    def made(shape, in_loop=False):
+        return [(name, opcode) for comp, rows in comps.items()
+                if not (in_loop and comp.startswith("main"))
+                for name, result, opcode, *_ in rows
+                if result.startswith(shape) and opcode not in FREE | {"while"}]
+
+    dims = lambda shape: ",".join(map(str, shape))
+    states = made(f"f32[{dims(state)}]")
+    assert [opcode for _, opcode in states] == ["fusion"] * 2, states
+    assert all("dynamic-update-slice" in name for name, _ in states), states
+    assert not [line for line in text.splitlines()
+                if "-start(" in line and f"[{dims(state)}]" in line]
+    # (one attention layer: the loop may hold its cache without the
+    # layer axis, a view)
+    for shapes in ((dims(tails),), (dims(cache), dims(cache[:1] + cache[2:]))):
+        moved = {opcode for shape in shapes for kind in ("f32", "bf16")
+                 for _, opcode in made(f"{kind}[{shape}]", in_loop=True)}
+        assert moved and moved <= {
+            "fusion", "copy-start", "copy-done", "custom-call"
+        }, (shapes, moved)
