@@ -51,8 +51,13 @@ kept for the cache's sake only. Two forms share the parameters:
 * ``T == 1`` — the step form: one token an env through state, tail and
   cache. Where ``resets`` is set, state, tail and position count as
   empty before the step (a cache row beyond the position is never
-  read). The state's update is ``mamba_step``, plain ``jax.numpy``: one
-  multiply-add pass over the state and one reduction against ``C``.
+  read). The state's update (``_state_step``) is one Pallas kernel
+  where the program is lowered for a TPU at widths that tile its vector
+  unit (``ops/pallas_mamba_step.py``: the named layer's state read once
+  and written once, in place in the carry's array, the update and the
+  read-out against ``C`` while it is in VMEM), and the plain
+  ``mamba_step`` through a dynamic-update-slice everywhere else;
+  nothing but the lowering platform and the state's shape chooses.
 * ``T > 1`` — the sequence form, the teacher-forced pass from the EMPTY
   carry (``replays_from_empty_carry``): ``chunk_state_space_scan`` in
   chunks of ``mamba_chunk_size`` and causal attention over the whole
@@ -248,8 +253,10 @@ def mamba_step(S, x, dt, a, B, C):
     """One step of the recurrence on ``S [b, h, p, n]``: ``S <- a S +
     dt x (x) B; y = S C``, with ``x [b, h, p]``, ``dt`` and the decay
     ``a = exp(dt A) [b, h]`` (the caller folds a reset into it), ``B, C
-    [b, n]``. Float32 on the vector unit, no matrix product: one
-    multiply-add pass over the state and one reduction. Returns ``(S, y
+    [b, n]``. Float32 on the vector unit, no matrix product. The plain
+    form: XLA makes it one multiply-add pass over the state and one
+    reduction over what that wrote (``ops/pallas_mamba_step.py`` is the
+    same step with the state held in VMEM for both). Returns ``(S, y
     [b, h, p])``."""
     S = S * a[..., None, None] + (
         (dt[..., None] * x)[..., None] * B[:, None, None, :]
@@ -375,11 +382,40 @@ def mamba_seq(p, x, cfg, dtype):
     return _mamba_output(p, y, xs, z, cfg, dtype), retention
 
 
+def _state_step(state, layer, x, dt, a, B, C):
+    """``mamba_step`` on Mamba layer ``layer`` (a Python int) of ``state
+    [B, layers, h, p, n]``, written back where it stood -> ``(state, y
+    [B, h, p])``. Lowered for a TPU, at widths that tile its vector
+    unit, the one-pass kernel that holds the layer's state in VMEM for
+    the update and the read-out and writes it to the buffer it came
+    from; anywhere else the plain form through a dynamic-update-slice."""
+    # Imported where it is used: Pallas is ~1.5 s of imports, and
+    # cli/train.py's PRESETS import this module for every preset.
+    from actor_critic_algs_on_tensorflow_tpu.ops import pallas_mamba_step
+
+    def plain(state, x, dt, a, B, C):
+        S, y = mamba_step(state[:, layer], x, dt, a, B, C)
+        return state.at[:, layer].set(S), y
+
+    if not pallas_mamba_step.fits(state):
+        return plain(state, x, dt, a, B, C)
+
+    def kernel(state, x, dt, a, B, C):
+        return pallas_mamba_step.mamba_step(state, layer, x, dt, a, B, C)
+
+    return jax.lax.platform_dependent(
+        state, x, dt, a, B, C, tpu=kernel, default=plain
+    )
+
+
 def mamba_mixer_step(p, x, state, tails, layer, keep, cfg, dtype):
     """``x [B, H]``; ``state [B, layers, h, p, n]`` and ``tails [B,
     layers, K - 1, d + 2 n]`` of which this is Mamba layer ``layer`` (a
     Python int), both updated in place; ``keep [B]``, 0 where the env
-    starts over: its state and convolution history count as empty."""
+    starts over: its state and convolution history count as empty (the
+    state's reset is folded into the decay, so it costs no pass: what
+    ``_state_step`` runs, the kernel or the plain form, sees ``a *
+    keep``)."""
     z, xBC, dt = _mamba_inputs(p, x, cfg, dtype)
     with jax.named_scope(profiling.MIXER_POINTWISE):
         window = jnp.concatenate(
@@ -393,10 +429,9 @@ def mamba_mixer_step(p, x, state, tails, layer, keep, cfg, dtype):
     with jax.named_scope(profiling.MIXER_CORE), jax.named_scope(
         profiling.MAMBA_STATE
     ):
-        S, y = mamba_step(
-            state[:, layer], xs, dt, a * keep[:, None], B, C
+        state, y = _state_step(
+            state, layer, xs, dt, a * keep[:, None], B, C
         )
-        state = state.at[:, layer].set(S)
     return _mamba_output(p, y, xs, z, cfg, dtype), state, tails, retention
 
 

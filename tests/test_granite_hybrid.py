@@ -109,15 +109,57 @@ def _stepwise(model, params, tokens, resets):
     return jnp.stack(logits), jnp.stack(values), carry
 
 
-def test_stepping_through_the_carry_equals_the_sequence_pass():
+@pytest.fixture
+def interpreted_kernel(monkeypatch):
+    """The model's choice (``gh._state_step``) pointed at the TPU's
+    branch, and that at the Pallas interpreter: the CPU then runs the
+    kernel's body where a chip would run the kernel. Yields the list of
+    the Mamba layers it was traced for."""
+    from actor_critic_algs_on_tensorflow_tpu.ops import pallas_mamba_step
+
+    kernel, traced = pallas_mamba_step.mamba_step, []
+
+    def interpreted(state, layer, *args):
+        traced.append(layer)
+        return kernel(state, layer, *args, interpret=True)
+
+    monkeypatch.setattr(pallas_mamba_step, "mamba_step", interpreted)
+    monkeypatch.setattr(
+        jax.lax, "platform_dependent",
+        lambda *args, tpu, default: tpu(*args),
+    )
+    return traced
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_stepping_through_the_carry_equals_the_sequence_pass(
+    kernel, request
+):
     """State, convolution tail and key/value cache, a token at a time,
-    against the chunked scan and causal attention over the sequence;
-    and a reset in the middle is a fresh start for that env alone."""
-    model = _model()
+    against the chunked scan and causal attention over the sequence
+    (and, the sequence form being held to it above, the reference's
+    unrolled sum); and a reset in the middle is a fresh start for that
+    env alone. Once at the tiny preset's widths, where the plain step
+    runs; once at a state that tiles the vector unit (``n`` 128, heads
+    of 16 rows), with the one-pass kernel as the mixer's update: tails,
+    the reset folded into the decay and the layer index with it."""
+    cfg = CFG
+    if kernel:
+        cfg = dataclasses.replace(CFG, mamba_d_state=128)
+        traced = request.getfixturevalue("interpreted_kernel")
+    model = _model(cfg=cfg)
     params, tokens = _init(model), _tokens(T_SEQ, B_SEQ)
     cut = CHUNK + 2
     resets = jnp.zeros((T_SEQ, B_SEQ)).at[cut, 1].set(1.0)
     logits, values, carry = _stepwise(model, params, tokens, resets)
+    if kernel:
+        # (the parameters' initialisation traces the step form too)
+        assert traced[-2:] == [0, 1], traced
+        ref_logits, ref_values = _reference(
+            params, tokens, model=dict(MODEL, mamba_d_state=128)
+        )
+        np.testing.assert_allclose(logits[:, 0], ref_logits[:, 0], atol=2e-5)
+        np.testing.assert_allclose(values[:, 0], ref_values[:, 0], atol=2e-5)
     seq = lambda tok: model.apply(
         params, tok, jnp.zeros(tok.shape), None
     )[:2]
@@ -140,9 +182,9 @@ def test_stepping_through_the_carry_equals_the_sequence_pass():
     )
     assert carry["pos"].tolist() == [T_SEQ, T_SEQ - cut, T_SEQ]
     assert carry["state"].shape == (
-        B_SEQ, 2, CFG.mamba_n_heads, CFG.mamba_d_head, CFG.mamba_d_state
+        B_SEQ, 2, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
     )
-    assert carry["conv"].shape == (B_SEQ, 2, 3, CFG.conv_channels)
+    assert carry["conv"].shape == (B_SEQ, 2, 3, cfg.conv_channels)
     assert carry["k"].shape == carry["v"].shape == (B_SEQ, 1, T_SEQ, 2, 16)
 
 

@@ -664,17 +664,19 @@ def test_the_state_space_step_updates_its_carry_in_place(one_chip):
     in one array each, one attention layer's key and value caches of
     512 rows) as the rollout runs it: two Mamba-2 mixers and the
     attention in a loop that carries the donated arrays, lowered for
-    the described v5e. All four arrays are aliased to the program's
-    arguments; the states, 18 MiB an env, are written by the
-    multiply-add's own fusion through a dynamic-update-slice (one a
-    layer, each reading and writing that layer's 2 MiB an env and no
-    other) and nothing else produces, copies or stages an array of
-    their shape; the tails and the caches, 15 and 2 x 16 MiB in all,
-    are, in the loop's body, either written in place or staged whole
-    through VMEM around the step (``copy-start`` / ``copy-done``) and
-    never copied in HBM (the program may change a cache's layout once,
-    outside the loop); and beside the carry the program holds less than
-    one layer's states."""
+    the described v5e through the model's own choice
+    (``gh._state_step``). All four arrays are aliased to the program's
+    arguments; the states, 18 MiB an env, are written by the one-pass
+    kernel and by nothing else (one ``mamba_state_step`` custom call a
+    layer in the loop's body, its output the state operand's buffer, no
+    conditional left of the choice by platform, and no fusion, copy or
+    ``copy-start`` that produces or stages an array of the states'
+    shape); the tails and the caches, 15 and 2 x 16 MiB in all, are, in
+    the loop's body, either written in place or staged whole through
+    VMEM around the step (``copy-start`` / ``copy-done``) and never
+    copied in HBM (the program may change a cache's layout once, outside
+    the loop); and beside the carry the program holds less than one
+    layer's states."""
     import jax.numpy as jnp
 
     from actor_critic_algs_on_tensorflow_tpu.models import granite_hybrid as gh
@@ -738,11 +740,25 @@ def test_the_state_space_step_updates_its_carry_in_place(one_chip):
                 if result.startswith(shape) and opcode not in FREE | {"while"}]
 
     dims = lambda shape: ",".join(map(str, shape))
-    states = made(f"f32[{dims(state)}]")
-    assert [opcode for _, opcode in states] == ["fusion"] * 2, states
-    assert all("dynamic-update-slice" in name for name, _ in states), states
-    assert not [line for line in text.splitlines()
-                if "-start(" in line and f"[{dims(state)}]" in line]
+    kernels = [
+        (comp, line) for comp, rows in comps.items()
+        for name, _, opcode, _, line in rows
+        if opcode == "custom-call" and name.startswith("mamba_state_step")
+    ]
+    assert len(kernels) == 2, kernels
+    # the kernel sees the state as tiles of 128 rows, a view
+    tiled = state[:2] + (state[2] * state[3] // 128, 128, state[4])
+    for comp, line in kernels:
+        assert not comp.startswith("main"), comp
+        assert 'custom_call_target="tpu_custom_call"' in line
+        assert f" = (f32[{dims(tiled)}]" in line
+        # operands 0 and 1 are the layer and the decays; the state is 2
+        assert "output_to_operand_aliasing={{0}: (2, {})}" in line
+    assert " conditional(" not in text
+    for shape in (state, tiled):
+        assert not made(f"f32[{dims(shape)}]"), made(f"f32[{dims(shape)}]")
+        assert not [line for line in text.splitlines()
+                    if "-start(" in line and f"[{dims(shape)}]" in line]
     # (one attention layer: the loop may hold its cache without the
     # layer axis, a view)
     for shapes in ((dims(tails),), (dims(cache), dims(cache[:1] + cache[2:]))):
