@@ -103,6 +103,12 @@ def _granite_hybrid_config(**kw):
     return GraniteHybridConfig(**kw)
 
 
+def _xing_config(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.models.xing import XingConfig
+
+    return XingConfig(**kw)
+
+
 def _snapshot_fits(fns, cfg) -> bool:
     """Whether a device has room for the sentinel's rollback target, a
     second copy of the train state: three times the state within the
@@ -646,6 +652,71 @@ PRESETS = {
             **_PPO_TOKEN_SCHEDULE,
             "num_envs": 8,
             "rollout_length": 24,
+            "total_env_steps": 4_096,
+            "num_devices": 1,
+        },
+    ),
+    # 17. Token-level PPO with Xing4.0-29B-A4B as the policy: Kimi-VL's
+    # latent attention (here with a low-rank query and YaRN positions)
+    # and sigmoid-routed experts, every sublayer wrapped in
+    # manifold-constrained hyper-connections (four float32 residual
+    # streams mixed by a Sinkhorn-projected matrix), at the published
+    # widths, cut to one chip's share of a stated deployment: each
+    # expert layer shared by 8 chips, expert-parallel (8 of the 64
+    # routed experts here, the whole router, the shared expert, MLA and
+    # mHC), the leading dense layer and 4 of the 38 expert layers (the
+    # rest lie on further chips as pipeline stages) and 1/8 of the
+    # vocabulary: 759.4 M parameters, 12.15 GB with gradients and
+    # Adam's moments. One episode is one sequence of 256 tokens on the
+    # token-recall env; the schedule is
+    # perfbench/traffic/recall-128x256-e1mb8.json's.
+    "ppo-xing-recall": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=16_384, delay=64, episode_length=256
+            ),
+            "torso": "xing",
+            "seq_model": _xing_config(
+                num_hidden_layers=5, first_k_dense_replace=1,
+                vocab_size=16_384, first_expert=0, experts_held=8,
+                capacity_factor=2.0,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 128,
+            "rollout_length": 256,
+            "num_minibatches": 8,
+            "compute_dtype": "bfloat16",
+            "total_env_steps": 10_000_000,
+        },
+    ),
+    # The same model and schedule at widths for the CPU tests: hidden
+    # 64, 4 streams and 3 Sinkhorn iterations, 4 heads of 16 + 8 (rope)
+    # and 16 (value) over a latent of 32 and a query of rank 16, a
+    # dense layer of width 128, then 2 expert layers of 8 experts top-2
+    # of width 32 (4 held) with a shared one, vocabulary 64.
+    "ppo-xing-tiny": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=64, delay=4, episode_length=16
+            ),
+            "torso": "xing",
+            "seq_model": _xing_config(
+                hidden_size=64, intermediate_size=128,
+                moe_intermediate_size=32, num_hidden_layers=3,
+                first_k_dense_replace=1, num_attention_heads=4,
+                num_key_value_heads=4, n_routed_experts=8,
+                num_experts_per_tok=2, kv_lora_rank=32, q_lora_rank=16,
+                qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16,
+                hc_sinkhorn_iters=3, vocab_size=64, first_expert=0,
+                experts_held=4, capacity_factor=4.0,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 8,
+            "rollout_length": 16,
             "total_env_steps": 4_096,
             "num_devices": 1,
         },

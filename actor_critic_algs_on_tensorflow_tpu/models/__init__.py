@@ -27,6 +27,7 @@ SEQUENCE_CORES = {
     "sdar": ("sdar", "SDARActorCritic", "SDARConfig"),
     "granite_hybrid": ("granite_hybrid", "GraniteHybridActorCritic",
                        "GraniteHybridConfig"),
+    "xing": ("xing", "XingActorCritic", "XingConfig"),
 }
 
 

@@ -56,6 +56,12 @@ rope_theta^(-2i / d)``, as the published loader pairs them; the result
 is laid out as halves (all first elements, then all second ones) for
 queries and keys alike, which no dot product sees.
 
+Two pieces of the same layer that this config refuses and the Xing core
+(``models/xing.py``) passes, both read off the config: a low-rank query
+(``q_lora_rank``: ``q = N(x W_qa) W_qb``) and YaRN
+positions (``rope_scaling``: ``yarn_inv_freq`` for the angles, the
+softmax scale times ``yarn_mscale(factor, mscale_all_dim)^2``).
+
 Parameters are float32. Matrix products run in ``dtype`` with float32
 accumulation, and the cache, an input of such products, is held in
 ``dtype``; the norms, the router (product, sigmoid, top-k, weights),
@@ -77,10 +83,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from actor_critic_algs_on_tensorflow_tpu.models import moe
@@ -125,6 +133,7 @@ class KimiVLConfig:
     scoring_func: str = "sigmoid"
     rms_norm_eps: float = 1e-5
     rope_theta: float = 800000.0
+    rope_scaling: Optional[tuple] = None
     vocab_size: int = 163840
     # The share of the expert layer held here.
     first_expert: int = 0
@@ -132,7 +141,8 @@ class KimiVLConfig:
     capacity_factor: float = 2.0
 
     def __post_init__(self):
-        built = {"q_lora_rank": None, "topk_method": "noaux_tc",
+        built = {"q_lora_rank": None, "rope_scaling": None,
+                 "topk_method": "noaux_tc",
                  "n_group": 1, "topk_group": 1, "scoring_func": "sigmoid"}
         for key, value in built.items():
             if getattr(self, key) != value:
@@ -162,14 +172,23 @@ class KimiVLConfig:
 
 
 def layer_param_spec(cfg: KimiVLConfig, layer: int):
-    """``{name: (shape, init)}`` of one decoder layer."""
+    """``{name: (shape, init)}`` of one decoder layer. The query runs
+    through one ``q_proj``, or where ``cfg.q_lora_rank`` is set through
+    that rank with its own norm (``q_a_proj``, ``q_a_norm``,
+    ``q_b_proj``)."""
     H, w = cfg.hidden_size, nn.initializers.normal(_INIT_STD)
     ones = nn.initializers.ones_init()
     nh, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if cfg.q_lora_rank is None:
+        query = {"q_proj": ((H, nh * (dn + dr)), w)}
+    else:
+        query = {"q_a_proj": ((H, cfg.q_lora_rank), w),
+                 "q_a_norm": ((cfg.q_lora_rank,), ones),
+                 "q_b_proj": ((cfg.q_lora_rank, nh * (dn + dr)), w)}
     spec = {
         "input_norm": ((H,), ones), "post_norm": ((H,), ones),
-        "q_proj": ((H, nh * (dn + dr)), w),
+        **query,
         "kv_a_proj": ((H, rank + dr), w), "kv_a_norm": ((rank,), ones),
         "kv_b_proj": ((rank, nh * (dn + dv)), w),
         "o_proj": ((nh * dv, H), w),
@@ -203,8 +222,39 @@ def rms_norm(x, w, eps):
 def _angles(positions, cfg: KimiVLConfig):
     """``positions [...]`` -> the rotation angles ``[..., d_rope / 2]``."""
     d = cfg.qk_rope_head_dim
-    inv_freq = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    if cfg.rope_scaling is None:
+        inv_freq = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(cfg), _F32)
     return positions.astype(_F32)[..., None] * inv_freq
+
+
+def yarn_inv_freq(cfg):
+    """YaRN's inverse frequencies ``[d_rope / 2]`` (float64, numpy), as
+    DeepSeek-V3's loader computes them (``yarn_find_correction_range``,
+    ``yarn_linear_ramp_mask``): the plain ``theta^(-2i / d)`` below the
+    correction range, those over ``factor`` above it, a linear ramp
+    between."""
+    d, theta = cfg.qk_rope_head_dim, cfg.rope_theta
+    yarn = dict(cfg.rope_scaling)
+    original = yarn["original_max_position_embeddings"]
+
+    def correction_dim(rotations):
+        return d * math.log(original / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(correction_dim(yarn["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(yarn["beta_slow"])), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    extrapolated = theta ** (-np.arange(0, d, 2) / d)
+    return (extrapolated / yarn["factor"]) * ramp + extrapolated * (1 - ramp)
+
+
+def yarn_mscale(factor, mscale):
+    """DeepSeek-V3's ``yarn_get_mscale``: ``0.1 mscale ln(factor) + 1``
+    where the positions are scaled (``factor > 1``), else 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 def _rope(x, angles):
@@ -233,9 +283,16 @@ def _mla_project(p, x, angles, cfg, dtype):
     rope key ``k^r [..., d_rope]``, float32."""
     nh, rank, dn = (cfg.num_attention_heads, cfg.kv_lora_rank,
                     cfg.qk_nope_head_dim)
+    low_rank = cfg.q_lora_rank is not None
     with jax.named_scope(profiling.MIXER_PROJ):
-        q = _mm(x, p["q_proj"], dtype)
+        q = _mm(x, p["q_a_proj" if low_rank else "q_proj"], dtype)
         kva = _mm(x, p["kv_a_proj"], dtype)
+    if low_rank:
+        # c_q = N(x W_qa), q = c_q W_qb
+        with jax.named_scope(profiling.MIXER_POINTWISE):
+            q = rms_norm(q, p["q_a_norm"], cfg.rms_norm_eps)
+        with jax.named_scope(profiling.MIXER_PROJ):
+            q = _mm(q, p["q_b_proj"], dtype)
     with jax.named_scope(profiling.MIXER_POINTWISE):
         q = q.reshape(x.shape[:-1] + (nh, -1))
         c = rms_norm(kva[..., :rank], p["kv_a_norm"], cfg.rms_norm_eps)
@@ -244,7 +301,14 @@ def _mla_project(p, x, angles, cfg, dtype):
 
 
 def _softmax_scale(cfg):
-    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    """``(d_nope + d_rope)^(-1/2)``, and under YaRN times the square of
+    ``yarn_mscale(factor, mscale_all_dim)``, as the published loader
+    scales it."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_scaling is not None:
+        yarn = dict(cfg.rope_scaling)
+        scale *= yarn_mscale(yarn["factor"], yarn["mscale_all_dim"]) ** 2
+    return scale
 
 
 def mla_seq(p, x, cfg, dtype):
@@ -429,18 +493,24 @@ def moe_block(p, x, cfg: KimiVLConfig, dtype):
 # ---- the model ---------------------------------------------------------
 
 
-def _feed_forward(p, x, cfg, dtype, expert: bool):
-    """``x + F(N(x))`` on ``x [..., H]``: the expert block (with its
+def feed_forward_branch(p, x, cfg, dtype, expert: bool):
+    """``(F(N(x)), stats)`` on ``x [..., H]``: the expert block (with its
     counters) or the leading dense SwiGLU (``None``)."""
     if expert:
         with jax.named_scope(profiling.MOE):
             h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
             y, stats = moe_block(p, h.reshape(-1, x.shape[-1]), cfg, dtype)
-        return x + y.reshape(x.shape), stats
+        return y.reshape(x.shape), stats
     with jax.named_scope(profiling.DENSE_MLP):
         h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
         y = moe.swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"], dtype)
-    return x + y, None
+    return y, None
+
+
+def _feed_forward(p, x, cfg, dtype, expert: bool):
+    """``x + F(N(x))`` on ``x [..., H]``, and ``F``'s counters."""
+    y, stats = feed_forward_branch(p, x, cfg, dtype, expert)
+    return x + y, stats
 
 
 def _decoder_layer_seq(p, x, cfg, dtype, expert: bool):

@@ -22,7 +22,11 @@
   products with ``kv_b_proj``'s halves as ``mixer_proj``); the expert
   block (``moe`` with ``moe_router``, ``moe_dispatch`` and in it
   ``moe_combine``, ``moe_experts``, ``moe_shared``), ``dense_mlp``,
-  ``lm_head`` and, in ``policy_act``, ``sample``. No layer index in a
+  ``lm_head`` and, in ``policy_act``, ``sample``; around each
+  sublayer of a core with hyper-connections (``models/xing.py``) ``mhc``
+  over all but the sublayer itself, with ``mhc_sinkhorn`` inside it
+  (neither nests in a mixer or the expert block, nor they in it). No
+  layer index in a
   name: a rollout's part of a layer is its operations under ``rollout``.
 - ``span(name)``: a host span in the profiler's trace
   (``jax.profiler.TraceAnnotation``) for a wait that has no counter.
@@ -56,7 +60,8 @@ MINIBATCH_PREP = "minibatch_prep"  # in update: slice, convert, relayout
 LOSS_GRAD = "loss_grad"            # in update: forward + backward
 OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
 # Layers of the sequence-policy cores (models/qwen3_next.py,
-# models/kimi_vl.py, models/sdar.py, models/granite_hybrid.py; the
+# models/kimi_vl.py, models/sdar.py, models/granite_hybrid.py,
+# models/xing.py; the
 # expert block is models/moe.py's), inside policy_act and loss_grad; read like the
 # phases, listed apart.
 GDN = "gdn"                        # a Gated DeltaNet mixer
@@ -88,12 +93,14 @@ SAMPLE = "sample"                  # in policy_act: sample and its log-prob
 MAMBA = "mamba"                    # a Mamba-2 mixer, both forms
 MAMBA_STATE = "mamba_state"        # in mamba's core: the step form's update
 MAMBA_CHUNK_SCAN = "mamba_chunk_scan"  # in mamba's core: the chunked scan
+MHC = "mhc"                        # a sublayer's hyper-connections, less F
+MHC_SINKHORN = "mhc_sinkhorn"      # in mhc: the Sinkhorn iterations
 LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_SHARED, LM_HEAD, MLA, MLA_ABSORBED,
                 DENSE_MLP, GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND,
                 MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE, MLA_SEQ_ATTEND,
                 GDN_CHUNK_SOLVE, GDN_CHUNK_PRODUCTS, MOE_COMBINE, SAMPLE,
-                MAMBA, MAMBA_STATE, MAMBA_CHUNK_SCAN)
+                MAMBA, MAMBA_STATE, MAMBA_CHUNK_SCAN, MHC, MHC_SINKHORN)
 MIXER_SCOPES = (GDN, GATED_ATTN, MLA, GQA, MAMBA)
 MIXER_PARTS = (MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,

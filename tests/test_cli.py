@@ -657,3 +657,52 @@ def test_the_granite_presets_resolve_and_need_no_rollback_copy(monkeypatch):
     )
     monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [chip])
     assert not cli._snapshot_fits(fns, cfg)
+
+
+def test_the_xing_presets_resolve_and_need_no_rollback_copy(monkeypatch):
+    """Both Xing presets are PPO configs whose env and model agree on
+    the vocabulary; one episode is one rollout; the cell holds one dense
+    and four expert layers of 8 experts each with the published widths,
+    four residual streams and 20 Sinkhorn iterations; its carry is the
+    one cache of latents with the env axis first; and its train state
+    (759 M parameters with Adam's moments, 8.5 GiB) does not fit a 16 GB
+    chip beside a rollback copy of itself."""
+    import types
+
+    import jax
+
+    from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (
+        PPOConfig,
+        make_ppo,
+    )
+
+    for name in ("ppo-xing-recall", "ppo-xing-tiny"):
+        algo, base = cli.PRESETS[name]
+        cfg = PPOConfig(**dict(base, num_devices=1))
+        env, model = cfg.env_params, cfg.seq_model
+        assert algo == "ppo" and cfg.torso == "xing"
+        assert cfg.recurrent and env.episode_length == cfg.rollout_length
+        assert env.vocab_size == model.vocab_size
+        assert model.hc_mult == 4 and model.q_lora_rank is not None
+        assert [model.is_expert_layer(i)
+                for i in range(model.num_hidden_layers)] == (
+            [False] + [True] * (model.num_hidden_layers - 1))
+    fns = make_ppo(cfg)  # the tiny one
+    assert cli._snapshot_fits(fns, cfg)  # the CPU reports no capacity
+    algo, base = cli.PRESETS["ppo-xing-recall"]
+    cfg = PPOConfig(**dict(base, num_devices=1))
+    assert (cfg.num_envs, cfg.rollout_length, cfg.num_minibatches) == (
+        128, 256, 8)
+    assert cfg.seq_model.hc_sinkhorn_iters == 20
+    assert cfg.seq_model.hidden_size == 3584
+    fns = make_ppo(cfg)
+    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
+    assert n_params == 759_350_031
+    assert state.carry["core"]["layers"].shape == (128, 5, 256, 576)
+    assert state.obs.shape == (128,)
+    chip = types.SimpleNamespace(
+        memory_stats=lambda: {"bytes_limit": int(15.75 * 2**30)}
+    )
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [chip])
+    assert not cli._snapshot_fits(fns, cfg)

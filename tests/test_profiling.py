@@ -224,6 +224,7 @@ SEQUENCE_PRESETS = {
     "kimi_vl": "ppo-kimivl-tiny",
     "sdar": "ppo-sdar-tiny",
     "granite_hybrid": "ppo-granite-tiny",
+    "xing": "ppo-xing-tiny",
 }
 # The cores with no expert layer: no moe_* scope is looked for there.
 DENSE_CORES = {"granite_hybrid"}
@@ -343,6 +344,34 @@ def test_the_sdar_iteration_carries_its_layer_scopes():
     assert any(LM_HEAD in p and LOSS_GRAD in p for p in lists)
 
 
+def test_the_xing_iteration_carries_its_layer_scopes():
+    """The layers ``models/xing.py`` names — Kimi-VL's mixer with its
+    absorbed step, the dense layer, the expert block's four — and the
+    hyper-connections around every sublayer, ``mhc`` with
+    ``mhc_sinkhorn`` inside it, in the compiled text of the tiny preset's
+    fused iteration: ``mhc`` holds neither a mixer nor the expert block
+    nor the dense layer, and none of them holds ``mhc``."""
+    from actor_critic_algs_on_tensorflow_tpu.utils.profiling import (
+        DENSE_MLP, LM_HEAD, MHC, MHC_SINKHORN, MLA, MLA_ABSORBED, MOE,
+        MOE_DISPATCH, MOE_EXPERTS, MOE_ROUTER, MOE_SHARED,
+    )
+
+    assert {MHC, MHC_SINKHORN} <= set(profiling.LAYER_SCOPES)
+    lists = _phase_lists("xing")
+    found = {phase for p in lists for phase in p}
+    assert {MHC, MHC_SINKHORN, MLA, MLA_ABSORBED, DENSE_MLP, MOE,
+            MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
+            LM_HEAD} <= found
+    for phases in lists:
+        if MHC_SINKHORN in phases:
+            assert MHC in phases[:phases.index(MHC_SINKHORN)], phases
+        if MHC in phases:
+            assert not {MLA, MOE, DENSE_MLP, LM_HEAD} & set(phases), phases
+    for scope in (MHC, MHC_SINKHORN):
+        assert any(scope in p and LOSS_GRAD in p for p in lists)
+        assert any(scope in p and POLICY_ACT in p for p in lists)
+
+
 # One level down (PR 35): where each sub-scope must be found in a
 # core's compiled iteration. (core, scope) -> (the scopes it is nested
 # in, a phase it must be found under, the phases it must never be
@@ -388,6 +417,15 @@ SUB_SCOPES = {
     ("granite_hybrid", _P.LM_HEAD): ((), LOSS_GRAD, ()),
     ("granite_hybrid", _P.SAMPLE):
         ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
+    ("xing", _P.MIXER_PROJ): ((_P.MLA,), LOSS_GRAD, ()),
+    ("xing", _P.MIXER_POINTWISE): ((_P.MLA,), LOSS_GRAD, ()),
+    ("xing", _P.MIXER_CORE): ((_P.MLA,), POLICY_ACT, ()),
+    ("xing", _P.MLA_SEQ_ATTEND):
+        ((_P.MLA, _P.MIXER_CORE), LOSS_GRAD, (POLICY_ACT, ADVANTAGE)),
+    ("xing", _P.MOE_COMBINE): ((_P.MOE, _P.MOE_DISPATCH), LOSS_GRAD, ()),
+    ("xing", _P.MHC): ((), LOSS_GRAD, ()),
+    ("xing", _P.MHC_SINKHORN): ((_P.MHC,), POLICY_ACT, ()),
+    ("xing", _P.SAMPLE): ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
 }
 
 

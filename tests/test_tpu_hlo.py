@@ -767,3 +767,94 @@ def test_the_state_space_step_updates_its_carry_in_place(one_chip):
         assert moved and moved <= {
             "fusion", "copy-start", "copy-done", "custom-call"
         }, (shapes, moved)
+
+
+def test_the_xing_step_writes_its_cache_in_place_beside_the_streams(one_chip):
+    """Xing's step form at the timed shape (128 envs, five layers'
+    caches of 256 rows of 512 + 64 in one array, 32 heads, a query of
+    rank 768, four float32 streams of 3,584 around the mixer and the
+    expert block) as the rollout runs an expert layer: in a loop that
+    carries the donated caches, lowered for the described v5e. The
+    hyper-connections change nothing of what Kimi-VL's step does to its
+    cache: the caches are written in place (a scatter of 128 rows),
+    scores, softmax and weighted sum are the one-pass kernel (one custom
+    call, no conditional left), beside them no instruction takes the
+    caches and none is staged; and the Sinkhorn runs on matrices with
+    the envs minor (no ``[128, 4, 4]`` float32 array is made in the
+    loop: on the chip its two axes of 4 would be padded to a tile)."""
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+
+    from actor_critic_algs_on_tensorflow_tpu.models import (
+        hyper_connections as hc,
+    )
+    from actor_critic_algs_on_tensorflow_tpu.models import xing
+
+    cfg = PRESETS["ppo-xing-recall"][1]["seq_model"]
+    B, L, H, layers = 128, 256, cfg.hidden_size, cfg.num_hidden_layers
+    assert cfg.cache_width == 576 and layers == 5 and cfg.hc_mult == 4
+
+    def arr(shape, dtype=jnp.float32, sharding=one_chip):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    p = {n: arr(s) for n, (s, _) in xing.layer_param_spec(cfg, 1).items()}
+    rows_minor = Format(
+        Layout(major_to_minor=(0, 1, 2, 3), tiling=((8, 128), (2, 1))),
+        one_chip,
+    )
+
+    def rollout(p, xs, caches, pos):
+        def step(carry, x):
+            caches, pos = carry
+            X, caches, _, _ = xing._decoder_layer_step(
+                p, hc.expand(x, cfg.hc_mult), caches, 1, pos, cfg,
+                jnp.bfloat16, True,
+            )
+            return (caches, pos + 1), hc.reduce(X)
+
+        (caches, pos), ys = jax.lax.scan(step, (caches, pos), xs)
+        return ys, caches, pos
+
+    compiled = jax.jit(
+        rollout, donate_argnums=2,
+        out_shardings=(one_chip, rows_minor, one_chip),
+    ).lower(
+        p, arr((3, B, H)),
+        arr((B, layers, L, 576), jnp.bfloat16, rows_minor),
+        arr((B,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    assert re.search(r"bf16\[128,5,256,576\]\S* scatter\(", text)
+    cache = B * L * 576 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == layers * cache * 640 // 576
+    kernels = re.findall(
+        r"%(mla_absorbed_step[\w.]*) = .*custom_call_target=\"tpu_custom_call\"",
+        text,
+    )
+    assert len(kernels) == 1, kernels
+    assert " conditional(" not in text
+    comps = unfused(text)
+    takers = sorted(
+        (opcode, comp.startswith("main"))
+        for comp, rows in comps.items()
+        for _, _, opcode, _, line in rows
+        if opcode not in FREE | {"while"} and any(
+            result.startswith("bf16[128,5,256,576]")
+            for name, result, *_ in rows
+            if re.search(rf"%{re.escape(name)}\b",
+                         line[line.index(opcode + "("):])
+        )
+    )
+    assert takers == [("custom-call", False), ("fusion", False)], takers
+    staged = [line for line in text.splitlines()
+              if "-start(" in line and "[128,5,256,576]" in line]
+    assert not staged, staged
+    # (an array of that shape whose minor axis is the envs' is no pad)
+    padded = [(name, result) for comp, rows in comps.items()
+              if not comp.startswith("main")
+              for name, result, opcode, *_ in rows
+              if result.startswith("f32[128,4,4]") and opcode not in FREE
+              and not result.startswith("f32[128,4,4]{0,")]
+    assert not padded, padded
