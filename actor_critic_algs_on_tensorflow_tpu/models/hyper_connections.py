@@ -32,9 +32,18 @@ iterations and both mixes. Scope ``mhc`` is around all of it but ``F``
 ``mhc_sinkhorn`` inside it around the iterations. The iterations run on
 ``[n, n, tokens]``, the tokens the minor axis: on ``[tokens, n, n]`` a
 TPU would pad the two axes of 4 to a tile of 8 x 128.
+
+A core carries the streams as ``[..., n C]``, stream ``i`` in ``[i C,
+(i + 1) C)``: a TPU tiles the last two axes of an array, and an axis of
+``n`` = 4 among them would be padded to 8 and relaid between every
+sublayer's passes (PERF.md section 5, PR 40). On a TPU at widths that
+tile (``ops/pallas_hyper_connections.py``), the two passes over the
+streams are that module's kernels, forward and backward.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +57,9 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # and the largest |row or column sum - 1| of H_res.
 OFFDIAG_MASS = "mhc_res_offdiag_mass"
 SINKHORN_RESIDUAL = "mhc_sinkhorn_residual"
+# The share of a call's sublayers whose two passes ran as the kernels of
+# ops/pallas_hyper_connections.py: 1 or 0.
+FUSED_SHARE = "mhc_fused_share"
 # The gains of the coefficients' dynamic part start small, so that at
 # the start a sublayer's coefficients are its biases' give or take a
 # little: at 1, ``u Phi`` (unit ``u`` over ``n C`` = 14,336 numbers,
@@ -76,18 +88,18 @@ def param_spec(cfg, prefix: str):
 
 
 def expand(x, n: int):
-    """``x [..., C]`` -> the streams ``[..., n, C]``, float32: ``x`` in
+    """``x [..., C]`` -> the streams ``[..., n C]``, float32: ``x`` in
     each."""
     with jax.named_scope(profiling.MHC):
-        x = x.astype(_F32)
-        return jnp.broadcast_to(x[..., None, :],
-                                x.shape[:-1] + (n, x.shape[-1]))
+        return jnp.concatenate([x.astype(_F32)] * n, -1)
 
 
-def reduce(X):
-    """The streams ``[..., n, C]`` -> their sum ``[..., C]``."""
+def reduce(X, n: int):
+    """The streams ``[..., n C]`` -> their sum ``[..., C]``: the slices
+    added, so that no ``[..., n, C]`` array is laid out for it."""
+    C = X.shape[-1] // n
     with jax.named_scope(profiling.MHC):
-        return jnp.sum(X, -2)
+        return sum(X[..., i * C:(i + 1) * C] for i in range(n))
 
 
 def sinkhorn(M, iters: int, eps: float):
@@ -99,16 +111,25 @@ def sinkhorn(M, iters: int, eps: float):
     return M
 
 
-def coefficients(p, prefix, X, cfg):
-    """``X [N, n, C]`` -> ``h_pre [N, n]``, ``h_post [N, n]`` and
-    ``H_res [n, n, N]`` (rows, columns, tokens), float32."""
-    n = cfg.hc_mult
+def _project(p, prefix, X, cfg):
+    """``X [N, n, C]`` -> ``[p | q | r] = u Phi [N, 2n + n^2]``, ``u``
+    the ungained norm of ``vec(X)``."""
     u = X.reshape(X.shape[0], -1)
     u = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True)
                           + cfg.rms_norm_eps)
-    pqr = jnp.dot(u, p[f"{prefix}phi"], precision=_HIGHEST)
+    return jnp.dot(u, p[f"{prefix}phi"], precision=_HIGHEST)
+
+
+def _read_weights(p, prefix, pqr, n):
+    """``h_pre [N, n]`` from ``pqr``."""
+    return jax.nn.sigmoid(p[f"{prefix}alpha"][0] * pqr[:, :n]
+                          + p[f"{prefix}b_pre"])
+
+
+def _write_coefficients(p, prefix, pqr, cfg):
+    """``h_post [N, n]`` and ``H_res [n, n, N]`` from ``pqr``."""
+    n = cfg.hc_mult
     a = p[f"{prefix}alpha"]
-    h_pre = jax.nn.sigmoid(a[0] * pqr[:, :n] + p[f"{prefix}b_pre"])
     h_post = 2.0 * jax.nn.sigmoid(a[1] * pqr[:, n:2 * n]
                                   + p[f"{prefix}b_post"])
     logits = a[2] * pqr[:, 2 * n:].reshape(-1, n, n) + p[f"{prefix}b_res"]
@@ -117,7 +138,7 @@ def coefficients(p, prefix, X, cfg):
     with jax.named_scope(profiling.MHC_SINKHORN):
         H = sinkhorn(jnp.exp(jnp.moveaxis(logits, 0, -1)),
                      cfg.hc_sinkhorn_iters, cfg.hc_eps)
-    return h_pre, h_post, H
+    return h_post, H
 
 
 def counters(H):
@@ -146,17 +167,58 @@ def post_mix(X, y, h_post, H):
     return mixed + h_post[..., None] * y[:, None, :].astype(_F32)
 
 
+def _kernel_or_plain(kernel, plain, *operands):
+    """``kernel(*operands)`` where the program is lowered for a TPU,
+    ``plain(*operands)`` anywhere else."""
+    return jax.lax.platform_dependent(*operands, tpu=kernel, default=plain)
+
+
 def sublayer(p, prefix, X, F, cfg):
-    """One sublayer with hyper-connections: ``X [..., n, C]`` float32
-    and ``F(h [..., C]) -> (y [..., C], aux)`` -> ``(X', aux,
-    counters)``."""
-    lead = X.shape[:-2]
+    """One sublayer with hyper-connections: the streams ``X [..., n C]``
+    float32 and ``F(h [..., C]) -> (y [..., C], aux)`` -> ``(X', aux,
+    counters)``. Lowered for a TPU, at widths that tile its vector unit
+    (``ops/pallas_hyper_connections.py``'s ``fits``), the two passes
+    over the streams are that module's kernels, forward and backward;
+    anywhere else the plain array operations above. The counter
+    ``mhc_fused_share`` says which ran: 1 or 0."""
+    # Imported where it is used: Pallas is ~1.5 s of imports, and
+    # cli/train.py's PRESETS import this module for every preset.
+    from actor_critic_algs_on_tensorflow_tpu.ops import (
+        pallas_hyper_connections as kernels,
+    )
+
+    n = cfg.hc_mult
+    lead, C = X.shape[:-1], X.shape[-1] // n
+    flat = X.reshape(-1, n * C)                  # tokens-major, no copy
+    read = {prefix + k: p[prefix + k] for k in ("phi", "alpha", "b_pre")}
+
+    def pre_plain(x, read):
+        streams = x.reshape(-1, n, C)
+        pqr = _project(read, prefix, streams, cfg)
+        return pre_mix(streams, _read_weights(read, prefix, pqr, n)), pqr, x
+
+    def pre_kernel(x, read):
+        return kernels.pre(x, read[prefix + "phi"], read[prefix + "alpha"][0],
+                           read[prefix + "b_pre"], eps=cfg.rms_norm_eps)
+
+    def post_plain(x, y, h_post, H):
+        return post_mix(x.reshape(-1, n, C), y, h_post, H).reshape(x.shape)
+
+    def zero():
+        return jnp.zeros((), _F32)
+
     with jax.named_scope(profiling.MHC):
-        flat = X.reshape((-1,) + X.shape[-2:])
-        h_pre, h_post, H = coefficients(p, prefix, flat, cfg)
-        h = pre_mix(flat, h_pre).reshape(lead + X.shape[-1:])
-        stats = counters(H)
-    y, aux = F(h)
+        if kernels.fits(flat, n):
+            pre = functools.partial(_kernel_or_plain, pre_kernel, pre_plain)
+            post = functools.partial(_kernel_or_plain, kernels.post,
+                                     post_plain)
+            share = _kernel_or_plain(lambda: jnp.ones((), _F32), zero)
+        else:
+            pre, post, share = pre_plain, post_plain, zero()
+        h, pqr, flat = pre(flat, read)
+        h_post, H = _write_coefficients(p, prefix, pqr, cfg)
+        stats = {**counters(H), FUSED_SHARE: share}
+    y, aux = F(h.reshape(lead + (C,)))
     with jax.named_scope(profiling.MHC):
-        out = post_mix(flat, y.reshape(flat.shape[0], -1), h_post, H)
+        out = post(flat, y.reshape(-1, C), h_post, H)
         return out.reshape(X.shape), aux, stats

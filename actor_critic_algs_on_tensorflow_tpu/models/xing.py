@@ -18,7 +18,8 @@ objective has no next-but-one token to train it on, and it would take
 2.5 GB of the chip), and the update of the routing bias (as in
 Kimi-VL).
 
-Layer ``l`` on the streams ``X [..., n, C]`` (``n = hc_mult``):
+Layer ``l`` on the streams ``X [..., n C]`` (``n = hc_mult``, stream
+``i`` in ``[i C, (i + 1) C)``):
 ``X = mHC(X, MLA . N_in)``, then ``X = mHC(X, F_l . N_post)``, ``F_l``
 the dense SwiGLU for ``l < first_k_dense_replace`` and the expert block
 after it. The embedding ``e`` is copied into every stream at the bottom
@@ -48,6 +49,10 @@ of ``1 - tr(H_res) / n`` (0 at the identity), and
 any ``H_res``. ``B_res`` starts at normal(0, 1) (assumed), so the first
 is far from 0 and the comparison with the reference tests the
 projection; a loader's identity-like start would test nothing of it.
+Beside them ``mhc_fused_share``: the share of the iteration's sublayer
+calls, rollout and update, whose two passes over the streams ran as
+``ops/pallas_hyper_connections.py``'s kernels (1 on a TPU at the
+published widths, 0 where the plain form ran).
 """
 
 from __future__ import annotations
@@ -166,7 +171,8 @@ def layer_param_spec(cfg: XingConfig, layer: int):
 
 
 def _decoder_layer_seq(p, X, cfg, dtype, expert: bool):
-    """``X [T, b, n, H]`` -> ``(X', expert counters, mHC counters)``."""
+    """The streams ``X [T, b, n H]`` -> ``(X', expert counters, mHC
+    counters)``."""
     def attention(h):
         with jax.named_scope(profiling.MLA):
             with jax.named_scope(profiling.MIXER_POINTWISE):
@@ -182,7 +188,7 @@ def _decoder_layer_seq(p, X, cfg, dtype, expert: bool):
 
 
 def _decoder_layer_step(p, X, caches, layer, pos, cfg, dtype, expert: bool):
-    """``X [B, n, H]`` -> ``(X', caches, expert counters, mHC
+    """The streams ``X [B, n H]`` -> ``(X', caches, expert counters, mHC
     counters)``: the step form through layer ``layer``'s cache."""
     def attention(h):
         with jax.named_scope(profiling.MLA):
@@ -207,13 +213,18 @@ def _mhc_stats(counters):
         hc.SINKHORN_RESIDUAL: jnp.max(jnp.stack(
             [c[hc.SINKHORN_RESIDUAL] for c in counters]
         )),
+        hc.FUSED_SHARE: jnp.mean(jnp.stack(
+            [c[hc.FUSED_SHARE] for c in counters]
+        )),
     }
 
 
 def iteration_stats(rollout_stats, update_stats, axis_name):
     """Kimi-VL's counters of one training iteration and, of the
     rollout's steps, the mean off-diagonal mass of ``H_res`` and the
-    largest distance of its row and column sums from 1."""
+    largest distance of its row and column sums from 1; of the rollout's
+    and the update's forward calls together, the share of sublayers
+    whose passes over the streams ran as the kernels."""
     stats = kv.iteration_stats(rollout_stats, update_stats, axis_name)
     stats[hc.OFFDIAG_MASS] = jax.lax.pmean(
         jnp.mean(rollout_stats[hc.OFFDIAG_MASS]), axis_name
@@ -221,6 +232,9 @@ def iteration_stats(rollout_stats, update_stats, axis_name):
     stats[hc.SINKHORN_RESIDUAL] = jax.lax.pmax(
         jnp.max(rollout_stats[hc.SINKHORN_RESIDUAL]), axis_name
     )
+    calls = jnp.concatenate([rollout_stats[hc.FUSED_SHARE].ravel(),
+                             update_stats[hc.FUSED_SHARE].ravel()])
+    stats[hc.FUSED_SHARE] = jax.lax.pmean(jnp.mean(calls), axis_name)
     return stats
 
 
@@ -284,7 +298,7 @@ class XingActorCritic(nn.Module):
                 X, stats, counters = layer(p, X)
                 expert_stats.append(stats)
                 mhc_stats.extend(counters)
-        x = hc.reduce(X)
+        x = hc.reduce(X, cfg.hc_mult)
         with jax.named_scope(profiling.LM_HEAD):
             h = kv.rms_norm(x, final_norm, cfg.rms_norm_eps)
             logits = _mm(h, lm_head, dtype)

@@ -810,7 +810,7 @@ def test_the_xing_step_writes_its_cache_in_place_beside_the_streams(one_chip):
                 p, hc.expand(x, cfg.hc_mult), caches, 1, pos, cfg,
                 jnp.bfloat16, True,
             )
-            return (caches, pos + 1), hc.reduce(X)
+            return (caches, pos + 1), hc.reduce(X, cfg.hc_mult)
 
         (caches, pos), ys = jax.lax.scan(step, (caches, pos), xs)
         return ys, caches, pos
@@ -858,3 +858,67 @@ def test_the_xing_step_writes_its_cache_in_place_beside_the_streams(one_chip):
               if result.startswith("f32[128,4,4]") and opcode not in FREE
               and not result.startswith("f32[128,4,4]{0,")]
     assert not padded, padded
+
+
+def test_the_xing_update_mixes_its_streams_in_the_mhc_kernels(one_chip):
+    """Xing's leading decoder layer as the update runs it (a minibatch of
+    16 sequences of 256 tokens, four float32 streams of 3,584, published
+    widths): recomputed under ``jax.checkpoint`` and differentiated,
+    lowered for the described v5e. Each sublayer's two passes over the
+    streams are the kernels of ``ops/pallas_hyper_connections.py``
+    (``models/hyper_connections.sublayer`` chooses by the lowering
+    platform and ``fits``): the pass before the sublayer four times (two
+    sublayers, each in the pass and its recomputation), the pass after
+    it three times (the layer's output is not recomputed), each backward
+    twice; no conditional is left of the choice; no float32 array
+    ``[..., 4, 4, 3584]`` is made anywhere in the program, fused or not
+    (the plain residual mix's broadcast product and its transpose, 940
+    MB each at these tokens); and nothing copies or relays the streams
+    out of the ``[T, b, 4 x 3584]`` the layer carries them in."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import xing
+
+    cfg = PRESETS["ppo-xing-recall"][1]["seq_model"]
+    T, b, n, C = 256, 16, cfg.hc_mult, cfg.hidden_size
+    assert (n, C) == (4, 3584) and not cfg.is_expert_layer(0)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    spec = xing.layer_param_spec(cfg, 0)
+    p = {name: arr(shape) for name, (shape, _) in spec.items()}
+
+    def loss(p, X):
+        layer = jax.checkpoint(
+            lambda p, X: xing._decoder_layer_seq(p, X, cfg, jnp.bfloat16,
+                                                 False)[0]
+        )
+        return jnp.sum(layer(p, X))
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        p, arr((T, b, n * C))
+    ).compile().as_text()
+    kernels = re.findall(
+        r"%(mhc_[a-z_]+)[\w.]* = .*custom_call_target=\"tpu_custom_call\"",
+        text,
+    )
+    assert sorted(kernels) == sorted(
+        ["mhc_pre"] * 4 + ["mhc_post"] * 3 + ["mhc_pre_backward"] * 2
+        + ["mhc_post_backward"] * 2
+    ), kernels
+    assert " conditional(" not in text
+    mixed = re.findall(rf"f32\[[\d,]*{n},{n},{C}\]", text)
+    assert not mixed, mixed[:5]
+    # the streams stay [tokens, n C] from layer to kernel and back: no
+    # relayout of them (the plain path's copies to and from [T, b, n, C]
+    # were ~9 of its passes, PERF.md section 5)
+    streams = T * b * n * C
+    moved = [
+        (name, result) for rows in unfused(text).values()
+        for name, result, opcode, *_ in rows
+        if opcode in ("copy", "copy-start", "reshape", "transpose")
+        and any(math.prod(map(int, dims.split(","))) >= streams
+                for dims in re.findall(r"f32\[([\d,]+)\]", result))
+    ]
+    assert not moved, moved

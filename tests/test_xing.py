@@ -259,10 +259,12 @@ def test_the_shares_add_up_through_the_residual_mix():
     params = _init(model)["params"]["layer_1"]
     X = jax.random.normal(jax.random.PRNGKey(3), (5, 2, CFG.hc_mult,
                                                   CFG.hidden_size))
+    streams = X.reshape(5, 2, -1)
     held = CFG.experts_held
 
     def sublayer(F):
-        return hc.sublayer(params, "ffn_hc_", X, F, full)[0]
+        return hc.sublayer(params, "ffn_hc_", streams, F, full)[0].reshape(
+            X.shape)
 
     def normed(h):
         return kv.rms_norm(h, params["post_norm"], CFG.rms_norm_eps).reshape(
