@@ -36,7 +36,12 @@ parameters:
   Gated DeltaNet and causal attention over the whole sequence from an
   EMPTY carry, position 0 at step 0. ``carry`` and ``resets`` are not
   read and the carry is handed back as it came; the trainer holds the
-  sequences to whole episodes (``make_ppo``'s refusal).
+  sequences to whole episodes (``make_ppo``'s refusal). A chunk's
+  unit-lower-triangular system is solved through its inverse
+  (``solve_unit_lower``), taken where the program is lowered for a TPU
+  at widths that tile by ``ops/pallas_unit_lower_inverse.py`` and
+  anywhere else by XLA's triangular solve; the counter
+  ``gdn_solve_kernel_share`` says which ran.
 
 Parameters are float32. Matrix products run in ``dtype`` with float32
 accumulation; the norms, the router (product, softmax, top-k), the
@@ -72,6 +77,9 @@ from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 _INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
+# The share of a sequence pass's chunked-rule solves whose inverse ran as
+# the kernel of ops/pallas_unit_lower_inverse.py: 1 or 0.
+SOLVE_KERNEL_SHARE = "gdn_solve_kernel_share"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -285,16 +293,8 @@ def gated_attention_step(p, x, cache, pos, cfg, dtype):
 # ---- gated DeltaNet ----------------------------------------------------
 
 
-def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int):
-    """The chunked form of the gated delta rule, float32.
-
-    ``q, k [b, h, T, d_k]`` (normed, ``q`` scaled), ``v [b, h, T, d_v]``,
-    ``g`` (log decay, <= 0) and ``beta [b, h, T]``. Equals, from an
-    empty state, the recurrence ``S <- S exp(g_t); delta = (v_t - S^T
-    k_t) beta_t; S <- S + k_t delta^T; o_t = S^T q_t``
-    (``gated_delta_step``). Returns ``(o [b, h, T, d_v], S [b, h, d_k,
-    d_v])``. A length that is no multiple of the chunk is padded with
-    steps that change nothing (``k = v = beta = g = 0``)."""
+def _chunk_rule(q, k, v, g, beta, chunk: int):
+    """``chunk_gated_delta_rule`` and its solve's ``solve_kernel_share``."""
     T, dv = q.shape[2], v.shape[-1]
     pad = -T % chunk
     if pad:
@@ -324,10 +324,8 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int):
         # inside a chunk every delta depends on the ones before it.
         a = dot("nbhid,nbhjd->nbhij", k_beta, k) * decay * strict
         rhs = jnp.concatenate([v_beta, k_beta * jnp.exp(gc)[..., None]], -1)
-        solved = jax.scipy.linalg.solve_triangular(
-            a + jnp.eye(chunk, dtype=a.dtype), rhs, lower=True,
-            unit_diagonal=True,
-        )
+        solved = solve_unit_lower(a, rhs)
+        share = solve_kernel_share(a)
         u, w = solved[..., :dv], solved[..., dv:]
     with jax.named_scope(profiling.GDN_CHUNK_PRODUCTS):
         qk = dot("nbhid,nbhjd->nbhij", q, k) * decay
@@ -348,7 +346,94 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int):
         S0 = jnp.zeros(q.shape[1:3] + (q.shape[-1], dv), _F32)
         S, o = jax.lax.scan(step, S0, (q, k, u, w, qk, gc))
     o = jnp.moveaxis(o, 0, 2).reshape(o.shape[1:3] + (n * chunk, dv))
-    return o[:, :, :T], S
+    return o[:, :, :T], S, share
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int):
+    """The chunked form of the gated delta rule, float32.
+
+    ``q, k [b, h, T, d_k]`` (normed, ``q`` scaled), ``v [b, h, T, d_v]``,
+    ``g`` (log decay, <= 0) and ``beta [b, h, T]``. Equals, from an
+    empty state, the recurrence ``S <- S exp(g_t); delta = (v_t - S^T
+    k_t) beta_t; S <- S + k_t delta^T; o_t = S^T q_t``
+    (``gated_delta_step``). Returns ``(o [b, h, T, d_v], S [b, h, d_k,
+    d_v])``. A length that is no multiple of the chunk is padded with
+    steps that change nothing (``k = v = beta = g = 0``)."""
+    o, S, _ = _chunk_rule(q, k, v, g, beta, chunk)
+    return o, S
+
+
+# ---- the chunks' solve: (I + a)^{-1} once, products both ways -------------
+
+
+def _plain_inverse(a):
+    eye = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=a.dtype), a.shape)
+    return jax.scipy.linalg.solve_triangular(
+        eye + a, eye, lower=True, unit_diagonal=True
+    )
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^{-1}`` of ``a [..., c, c]`` strictly lower, float32.
+    Lowered for a TPU, at widths that tile its vector unit, the kernel
+    that substitutes 128 matrices at once in VMEM
+    (``ops/pallas_unit_lower_inverse.py``); anywhere else XLA's
+    triangular solve, which a TPU runs one matrix after another."""
+    # Imported where it is used: Pallas is ~1.5 s of imports, and
+    # cli/train.py's PRESETS import this module for every preset.
+    from actor_critic_algs_on_tensorflow_tpu.ops import (
+        pallas_unit_lower_inverse as kernel,
+    )
+
+    if not kernel.fits(a):
+        return _plain_inverse(a)
+    return jax.lax.platform_dependent(
+        a, tpu=kernel.inverse, default=_plain_inverse
+    )
+
+
+def solve_kernel_share(a):
+    """1 where ``_unit_lower_inverse(a)`` runs the kernel, else 0."""
+    from actor_critic_algs_on_tensorflow_tpu.ops import (
+        pallas_unit_lower_inverse as kernel,
+    )
+
+    def zero():
+        return jnp.zeros((), _F32)
+
+    if not kernel.fits(a):
+        return zero()
+    return jax.lax.platform_dependent(
+        tpu=lambda: jnp.ones((), _F32), default=zero
+    )
+
+
+@jax.custom_vjp
+def solve_unit_lower(a, rhs):
+    """``X = (I + a)^{-1} rhs``: ``a [..., c, c]`` strictly lower (what
+    lies on and above its diagonal is not read), ``rhs [..., c, d]``,
+    float32. The inverse ``T`` is taken once and kept, so that the
+    derivative is two products with it and needs no solve of its own:
+    ``d rhs = T^T dX``, ``d a = -(d rhs X^T)`` on the strictly lower
+    entries."""
+    return _solve_forward(a, rhs)[0]
+
+
+def _solve_forward(a, rhs):
+    T = _unit_lower_inverse(a)
+    X = jnp.einsum("...ij,...jd->...id", T, rhs, precision=_HIGHEST)
+    return X, (T, X)
+
+
+def _solve_backward(residuals, dX):
+    T, X = residuals
+    d_rhs = jnp.einsum("...ji,...jd->...id", T, dX, precision=_HIGHEST)
+    strict = jnp.tril(jnp.ones(T.shape[-2:], bool), -1)
+    d_a = -jnp.einsum("...id,...jd->...ij", d_rhs, X, precision=_HIGHEST)
+    return d_a * strict, d_rhs
+
+
+solve_unit_lower.defvjp(_solve_forward, _solve_backward)
 
 
 def gated_delta_step(S, q, k, v, g, beta):
@@ -421,7 +506,8 @@ def _gdn_output(p, o, z, cfg, dtype):
 
 
 def gated_deltanet_seq(p, x, cfg, dtype):
-    """``x [T, b, H]`` from an empty state and convolution history."""
+    """``x [T, b, H]`` from an empty state and convolution history ->
+    ``(y, solve_kernel_share)``."""
     T = x.shape[0]
     K = cfg.linear_conv_kernel_dim
     qkv, z, beta, g = _gdn_inputs(p, x, cfg, dtype)
@@ -438,10 +524,10 @@ def gated_deltanet_seq(p, x, cfg, dtype):
     with jax.named_scope(profiling.MIXER_POINTWISE):
         q, k, v, g, beta = map(bh, (q, k, v, g, beta))
     with jax.named_scope(profiling.MIXER_CORE):
-        o, _ = chunk_gated_delta_rule(q, k, v, g, beta, cfg.chunk_size)
+        o, _, share = _chunk_rule(q, k, v, g, beta, cfg.chunk_size)
     with jax.named_scope(profiling.MIXER_POINTWISE):
         o = jnp.moveaxis(o, 2, 0)
-    return _gdn_output(p, o, z, cfg, dtype)
+    return _gdn_output(p, o, z, cfg, dtype), share
 
 
 def _state_step(S, q, k, v, g, beta, keep):
@@ -535,12 +621,14 @@ def _decoder_layer_seq(p, x, cfg, dtype, attention: bool):
     )
     with jax.named_scope(mixer):
         y = seq(p, h, cfg, dtype)
+        # the layer's chunked-rule solves, solve_kernel_share each
+        y, shares = (y, ()) if attention else (y[0], y[1:])
         with jax.named_scope(profiling.MIXER_POINTWISE):
             x = x + y
     with jax.named_scope(profiling.MOE):
         h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
         y, stats = moe_block(p, h.reshape(T * b, H), cfg, dtype)
-    return x + y.reshape(T, b, H), stats
+    return x + y.reshape(T, b, H), stats, shares
 
 
 def _decoder_layer_step(p, x, state, pos, keep, cfg, dtype, attention: bool):
@@ -559,6 +647,17 @@ def _decoder_layer_step(p, x, state, pos, keep, cfg, dtype, attention: bool):
     return x + y, state, stats
 
 
+def iteration_stats(rollout_stats, update_stats, axis_name):
+    """The expert layer's counters of one training iteration and the
+    share of the update's chunked-rule solves whose inverse ran as the
+    kernel (the rollout's step form solves nothing)."""
+    stats = moe.iteration_moe_stats(rollout_stats, update_stats, axis_name)
+    stats[SOLVE_KERNEL_SHARE] = jax.lax.pmean(
+        jnp.mean(update_stats[SOLVE_KERNEL_SHARE]), axis_name
+    )
+    return stats
+
+
 class Qwen3NextActorCritic(nn.Module):
     """The policy over ``cfg.vocab_size`` tokens and the value."""
 
@@ -568,7 +667,7 @@ class Qwen3NextActorCritic(nn.Module):
     # The sequence form reads neither carry nor resets (see above).
     replays_from_empty_carry = True
     # (rollout rows, update rows, axis) -> an iteration's counters
-    iteration_stats = staticmethod(moe.iteration_moe_stats)
+    iteration_stats = staticmethod(iteration_stats)
 
     @nn.compact
     def __call__(self, tokens, resets, carry):
@@ -605,6 +704,7 @@ class Qwen3NextActorCritic(nn.Module):
             x = x[None]
             carry = {"layers": new_layers, "pos": pos + 1}
         else:
+            shares = []
             for i, p in enumerate(layers):
                 # Each layer is recomputed in the backward pass: kept,
                 # the activations of 8,192 tokens do not fit beside the
@@ -614,13 +714,17 @@ class Qwen3NextActorCritic(nn.Module):
                         _decoder_layer_seq(p, x, cfg, dtype, a)
                     )
                 )
-                x, stats = layer(p, x)
+                x, stats, solves = layer(p, x)
                 all_stats.append(stats)
+                shares.extend(solves)
         with jax.named_scope(profiling.LM_HEAD):
             h = rms_norm(x, final_norm, cfg.rms_norm_eps)
             logits = _mm(h, lm_head, dtype)
         values = jnp.dot(h, value_w, precision=_HIGHEST) + value_b
-        return logits, values, carry, moe.stack_layer_stats(all_stats)
+        stats = moe.stack_layer_stats(all_stats)
+        if T > 1:
+            stats[SOLVE_KERNEL_SHARE] = jnp.mean(jnp.stack(shares))
+        return logits, values, carry, stats
 
     def initialize_carry(self, batch: int) -> Dict[str, Any]:
         """The empty carry for ``batch`` environments."""

@@ -179,3 +179,31 @@ def wait_member_rows(client, expect, *, seq=0, timeout=5.0):
                 f"have {sorted(seen)}"
             )
         _time.sleep(0.01)
+
+
+def chunk_matrices(lead, c, repeated, seed=0):
+    """``a [*lead, c, c]`` as the chunked delta rule builds it: ``(k beta)
+    k^T`` of l2-normed keys under the decay, strictly lower. With
+    ``repeated`` keys a chunk draws its keys from four, as the recall
+    env's traffic does, and ``beta`` is near 1: entries near 1."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape, d = lead + (c,), 16
+    if repeated:
+        vocab = jax.random.normal(ks[0], lead + (4, d))
+        pick = jax.random.randint(ks[1], shape, 0, 4)
+        k = jnp.take_along_axis(vocab, pick[..., None], axis=-2)
+        beta = jax.nn.sigmoid(3.0 + jax.random.normal(ks[2], shape))
+        g = -0.01 * jax.random.uniform(ks[3], shape)
+    else:
+        k = jax.random.normal(ks[0], shape + (d,))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[2], shape))
+        g = -jax.random.uniform(ks[3], shape, maxval=2.0)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    gc = jnp.cumsum(g, -1)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    decay = jnp.exp(
+        jnp.where(lower, gc[..., :, None] - gc[..., None, :], 0.0)
+    ) * lower
+    a = jnp.einsum("...id,...jd->...ij", k * beta[..., None], k,
+                   precision=jax.lax.Precision.HIGHEST)
+    return a * decay * jnp.tril(lower, -1)

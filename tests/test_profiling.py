@@ -538,6 +538,19 @@ def test_a_mixer_is_partitioned_into_its_three_parts(core):
     ("jit(f)/update/loss_grad/jvp(M)/gdn/mixer_core/gdn_chunk_products/"
      "while/body/closed_call/bhck,bhkv->bhcv/dot_general",
      (UPDATE, LOSS_GRAD, _P.GDN, _P.MIXER_CORE, _P.GDN_CHUNK_PRODUCTS)),
+    # the chunks' inverse as the TPU's kernel, recomputed, and a product
+    # of the solve's hand-written backward
+    ("jit(f)/update/while/body/closed_call/loss_grad/"
+     "transpose(jvp(Qwen3NextActorCritic))/loss_grad/"
+     "jvp(Qwen3NextActorCritic)/checkpoint/rematted_computation/gdn/"
+     "mixer_core/gdn_chunk_solve/cond/branch_0_fun/unit_lower_inverse/"
+     "pallas_call",
+     (UPDATE, LOSS_GRAD, _P.GDN, _P.MIXER_CORE, _P.GDN_CHUNK_SOLVE)),
+    ("jit(f)/update/while/body/closed_call/loss_grad/"
+     "transpose(jvp(Qwen3NextActorCritic))/loss_grad/"
+     "jvp(Qwen3NextActorCritic)/checkpoint/gdn/mixer_core/gdn_chunk_solve/"
+     "...ji,...jd->...id/dot_general",
+     (UPDATE, LOSS_GRAD, _P.GDN, _P.MIXER_CORE, _P.GDN_CHUNK_SOLVE)),
 ])
 def test_phases_of_an_op_name(op_name, phases):
     assert profiling.phases_of(op_name) == phases

@@ -28,8 +28,12 @@ from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.models import qwen3_next as qn  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.ops import pallas_delta_step  # noqa: E402
+from actor_critic_algs_on_tensorflow_tpu.ops import (  # noqa: E402
+    pallas_unit_lower_inverse,
+)
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
 from perfbench.reference import qwen3_next as ref  # noqa: E402
+from tests.helpers import chunk_matrices  # noqa: E402
 
 TINY = PRESETS["ppo-qwen3next-tiny"][1]
 CFG = TINY["seq_model"]
@@ -249,6 +253,58 @@ def test_chunked_deltanet_equals_the_recurrence(T):
         np.testing.assert_allclose(got, want, atol=1e-4)
 
 
+@pytest.mark.parametrize("c", [8, 64])
+@pytest.mark.parametrize("repeated", [False, True])
+def test_the_solve_through_the_inverse_equals_the_triangular_solve(
+    c, repeated
+):
+    """``solve_unit_lower`` (the inverse kept, its derivative two products
+    with it) against ``solve_triangular`` and JAX's own derivative of
+    it: the value and both gradients, with random keys and with keys
+    that repeat inside a chunk (entries of ``a`` near 1)."""
+    a = chunk_matrices((2, 3), c, repeated)
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    rhs = jax.random.normal(ks[0], (2, 3, c, 12))
+    dX = jax.random.normal(ks[1], rhs.shape)
+
+    def triangular(a, rhs):
+        return jax.scipy.linalg.solve_triangular(
+            a + jnp.eye(c), rhs, lower=True, unit_diagonal=True
+        )
+
+    got, got_vjp = jax.vjp(qn.solve_unit_lower, a, rhs)
+    want, want_vjp = jax.vjp(triangular, a, rhs)
+    for x, y in zip((got, *got_vjp(dX)), (want, *want_vjp(dX))):
+        scale = float(jnp.max(jnp.abs(y)))
+        assert scale > 0.1
+        np.testing.assert_allclose(x, y, atol=1e-5 * scale)
+
+
+def test_the_chunked_deltanet_through_the_kernel_equals_the_recurrence(
+    monkeypatch
+):
+    """The chunked rule with its inverse taken by the TPU's kernel (in the
+    Pallas interpreter): 2 chunks x 2 envs x 64 heads are two lane tiles
+    of matrices. Forward, final state and gradient."""
+    monkeypatch.setattr(qn, "_unit_lower_inverse", functools.partial(
+        pallas_unit_lower_inverse.inverse, interpret=True
+    ))
+    args = _delta_inputs(16, b=2, h=64)
+    o, S = qn.chunk_gated_delta_rule(*args, chunk=8)
+    o_ref, S_ref = _recurrence(*args)
+    np.testing.assert_allclose(o, o_ref, atol=2e-5)
+    np.testing.assert_allclose(S, S_ref, atol=2e-5)
+    weight = jax.random.normal(jax.random.PRNGKey(9), o.shape)
+    loss = lambda f: lambda *a: jnp.sum(f(*a)[0] * weight)
+    grads = jax.grad(
+        loss(lambda *a: qn.chunk_gated_delta_rule(*a, chunk=8)),
+        argnums=(0, 1, 2, 3, 4),
+    )(*args)
+    grads_ref = jax.grad(loss(_recurrence), argnums=(0, 1, 2, 3, 4))(*args)
+    for got, want in zip(grads, grads_ref):
+        np.testing.assert_allclose(got, want, atol=1e-4)
+
+
 # 4. and 5. the expert layer ---------------------------------------------
 
 
@@ -364,7 +420,7 @@ def test_an_iteration_reports_the_buffer_fill_of_both_phases(core):
         if hasattr(module, name):
             rollout[getattr(module, name)] = jnp.ones((steps,))
     for name in ("REVEALED_POSITIONS", "DENOISE_PASSES", "POSITIONS",
-                 "SCORE_TILES_COMPUTED"):
+                 "SCORE_TILES_COMPUTED", "SOLVE_KERNEL_SHARE"):
         if hasattr(module, name):
             update[getattr(module, name)] = jnp.ones((1, blocks))
     stats = jax.vmap(
@@ -533,6 +589,8 @@ def test_a_short_run_trains_and_counts():
         used = float(metrics[f"moe_buffer_rows_used_share_{phase}"])
         assert 0.0 < used <= 1.0
     assert float(metrics["episodes"]) == cfg.num_envs
+    # the tiny widths' chunks do not tile: the triangular solve ran
+    assert float(metrics[qn.SOLVE_KERNEL_SHARE]) == 0.0
     assert int(state.step) == 2
     assert fns.steps_per_iteration == cfg.num_envs * cfg.rollout_length
     counts = [int(x) for path, x in

@@ -364,7 +364,11 @@ def test_chunked_deltanet_compiles_at_the_timed_shape(one_chip):
     """The chunked delta rule at a timed minibatch's shape (32 envs, 32
     value heads, 256 steps, chunk 64), forward and backward: the
     chip's compiler takes it, the scan over the four chunks stays a
-    loop, and the state it carries is float32."""
+    loop, and the state it carries is float32. The chunks' 4,096
+    unit-lower systems are inverted once, by the kernel that
+    substitutes 128 of them at once in VMEM, and nowhere by the
+    compiler's serial InvertDiagBlocksLowerTriangular: the backward
+    reuses the inverse."""
     import jax.numpy as jnp
 
     from actor_critic_algs_on_tensorflow_tpu.models import qwen3_next as qn
@@ -384,6 +388,14 @@ def test_chunked_deltanet_compiles_at_the_timed_shape(one_chip):
     assert re.search(r" while\(", text)
     assert "f32[32,32,128,128]" in text
     assert "bf16[32,32,128,128]" not in text
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert " triangular-solve(" not in text
+    kernels = re.findall(
+        r"%(unit_lower_inverse[\w.]*) = f32\[64,64,4096\]\S* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"",
+        text,
+    )
+    assert len(kernels) == 1, kernels
     # well inside the chip beside the weights (activations of one layer)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
