@@ -65,7 +65,7 @@ kept for the cache's sake only. Two forms share the parameters:
 
 Parameters are float32. Matrix products of a weight, and the
 attention's two products, run in ``dtype`` with float32 accumulation
-(``moe.mm``); the norms, the convolution, ``Delta``, the decays and
+(``decoder.mm``); the norms, the convolution, ``Delta``, the decays and
 their cumulative sums, the state, every chunk product of the scan
 (``Precision.HIGHEST``), the attention's softmax and both heads'
 outputs are float32 whatever ``dtype`` says.
@@ -91,17 +91,16 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from actor_critic_algs_on_tensorflow_tpu.models import moe
-from actor_critic_algs_on_tensorflow_tpu.models.moe import mm as _mm
-from actor_critic_algs_on_tensorflow_tpu.models.sdar import (
-    _attend,
+from actor_critic_algs_on_tensorflow_tpu.models import decoder
+from actor_critic_algs_on_tensorflow_tpu.models.decoder import (
+    mm as _mm,
     rms_norm,
 )
+from actor_critic_algs_on_tensorflow_tpu.ops import kernel_choice
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
-_INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
 MAMBA, ATTENTION = "mamba", "attention"
 # The counter of both forms, one row a call: the mean of exp(Delta A)
 # over the call's tokens, heads and Mamba layers.
@@ -160,12 +159,7 @@ class GraniteHybridConfig:
             "position_embedding_type": "nope", "rope_scaling": None,
             "tie_word_embeddings": True,
         }
-        for key, value in built.items():
-            if getattr(self, key) != value:
-                raise ValueError(
-                    f"{key}={getattr(self, key)!r} is not built "
-                    f"(models/granite_hybrid.py has {value!r} only)"
-                )
+        decoder.refuse_unbuilt(self, built)
         if len(self.layer_types) != self.num_hidden_layers or not (
             set(self.layer_types) <= {MAMBA, ATTENTION}
         ):
@@ -219,7 +213,7 @@ def _dt_bias_init(key, shape, dtype=_F32):
 
 def layer_param_spec(cfg: GraniteHybridConfig, layer: int):
     """``{name: (shape, init)}`` of one decoder layer."""
-    H, w = cfg.hidden_size, nn.initializers.normal(_INIT_STD)
+    H, w = cfg.hidden_size, nn.initializers.normal(decoder.INIT_STD)
     ones = nn.initializers.ones_init()
     I = cfg.shared_intermediate_size
     spec = {"input_norm": ((H,), ones), "post_norm": ((H,), ones)}
@@ -388,23 +382,21 @@ def _state_step(state, layer, x, dt, a, B, C):
     [B, h, p])``. Lowered for a TPU, at widths that tile its vector
     unit, the one-pass kernel that holds the layer's state in VMEM for
     the update and the read-out and writes it to the buffer it came
-    from; anywhere else the plain form through a dynamic-update-slice."""
-    # Imported where it is used: Pallas is ~1.5 s of imports, and
-    # cli/train.py's PRESETS import this module for every preset.
-    from actor_critic_algs_on_tensorflow_tpu.ops import pallas_mamba_step
+    from (``ops/pallas_mamba_step.py``); anywhere else the plain form
+    through a dynamic-update-slice."""
 
     def plain(state, x, dt, a, B, C):
         S, y = mamba_step(state[:, layer], x, dt, a, B, C)
         return state.at[:, layer].set(S), y
 
-    if not pallas_mamba_step.fits(state):
-        return plain(state, x, dt, a, B, C)
+    def kernel(ops):
+        return lambda state, x, dt, a, B, C: ops.mamba_step(
+            state, layer, x, dt, a, B, C
+        )
 
-    def kernel(state, x, dt, a, B, C):
-        return pallas_mamba_step.mamba_step(state, layer, x, dt, a, B, C)
-
-    return jax.lax.platform_dependent(
-        state, x, dt, a, B, C, tpu=kernel, default=plain
+    return kernel_choice.kernel_or_plain(
+        "pallas_mamba_step", lambda ops: ops.fits(state), kernel, plain,
+        state, x, dt, a, B, C,
     )
 
 
@@ -440,7 +432,7 @@ def mamba_mixer_step(p, x, state, tails, layer, keep, cfg, dtype):
 
 def _project(p, x, cfg, dtype):
     """``x [b, t, H]`` -> query ``[b, t, nh, hd]``, key and value ``[b,
-    t, nkv, hd]``, float32. ``sdar._attend`` scales its scores by
+    t, nkv, hd]``, float32. ``decoder.attend`` scales its scores by
     ``head_dim ** -0.5``: the query carries what is left of
     ``attention_multiplier`` (a power of two at the published widths,
     so the rounding to ``dtype`` is the same as without it)."""
@@ -465,7 +457,7 @@ def gqa_seq(p, x, cfg, dtype):
     q, k, v = _project(p, x, cfg, dtype)
     with jax.named_scope(profiling.MIXER_CORE):
         visible = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (b, T, T))
-        out = _attend(q, k, v, visible, dtype)
+        out = decoder.attend(q, k, v, visible, dtype).reshape(b, T, -1)
     with jax.named_scope(profiling.MIXER_PROJ):
         return _mm(out, p["o_proj"], dtype)
 
@@ -486,9 +478,9 @@ def gqa_step(p, x, keys, values, layer, pos, cfg, dtype):
             v[:, 0].astype(values.dtype), unique_indices=True
         )
         visible = (jnp.arange(keys.shape[2])[None, :] <= pos[:, None])
-        out = _attend(
+        out = decoder.attend(
             q, keys[:, layer], values[:, layer], visible[:, None], dtype
-        )
+        ).reshape(B, 1, -1)
     with jax.named_scope(profiling.MIXER_PROJ):
         return _mm(out[:, 0], p["o_proj"], dtype), keys, values
 
@@ -499,7 +491,8 @@ def gqa_step(p, x, keys, values, layer, pos, cfg, dtype):
 def _feed_forward(p, x, cfg, dtype):
     with jax.named_scope(profiling.DENSE_MLP):
         h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
-        y = moe.swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"], dtype)
+        y = decoder.swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"],
+                           dtype)
         return x + cfg.residual_multiplier * y
 
 
@@ -565,29 +558,17 @@ class GraniteHybridActorCritic(nn.Module):
     @nn.compact
     def __call__(self, tokens, resets, carry):
         cfg, dtype = self.cfg, jnp.dtype(self.dtype)
-        H, w = cfg.hidden_size, nn.initializers.normal(_INIT_STD)
-        embedding = self.param("embedding", w, (cfg.vocab_size, H), _F32)
-        layers = [
-            moe.Params(tuple(layer_param_spec(cfg, i).items()),
-                       name=f"layer_{i}")()
-            for i in range(cfg.num_hidden_layers)
-        ]
-        final_norm = self.param(
-            "final_norm", nn.initializers.ones_init(), (H,), _F32
-        )
-        value_w = self.param("value_w", w, (H,), _F32)
-        value_b = self.param(
-            "value_b", nn.initializers.zeros_init(), (), _F32
+        head, layers = decoder.parameters(
+            self, cfg,
+            [layer_param_spec(cfg, i) for i in range(cfg.num_hidden_layers)],
+            nn.initializers.ones_init(), tied=True,
         )
 
         T = tokens.shape[0]
-        x = cfg.embedding_multiplier * jnp.take(
-            embedding, tokens.astype(jnp.int32), axis=0
-        )
+        x = cfg.embedding_multiplier * decoder.embed(head, tokens)
         retention = []
         if T == 1:
-            keep = 1.0 - resets[0].astype(_F32)
-            pos = (carry["pos"] * keep).astype(jnp.int32)
+            keep, pos = decoder.step_position(carry, resets)
             x = x[0]
             carry = dict(carry, pos=pos)
             seen = {MAMBA: 0, ATTENTION: 0}  # layers of each kind so far
@@ -616,10 +597,10 @@ class GraniteHybridActorCritic(nn.Module):
                 if kept is not None:
                     retention.append(kept)
             x = jnp.swapaxes(x, 0, 1)
+        h, logits = decoder.lm_head(x, head, cfg.rms_norm_eps, dtype)
         with jax.named_scope(profiling.LM_HEAD):
-            h = rms_norm(x, final_norm, cfg.rms_norm_eps)
-            logits = _mm(h, embedding.T, dtype) / cfg.logits_scaling
-        values = jnp.dot(h, value_w, precision=_HIGHEST) + value_b
+            logits = logits / cfg.logits_scaling
+        values = decoder.value(h, head)
         stats = {STATE_RETENTION: (
             jnp.mean(jnp.stack(retention)) if retention
             else jnp.ones((), _F32)

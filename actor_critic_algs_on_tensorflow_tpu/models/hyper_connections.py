@@ -43,12 +43,11 @@ streams are that module's kernels, forward and backward.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from actor_critic_algs_on_tensorflow_tpu.ops import kernel_choice
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
@@ -60,6 +59,7 @@ SINKHORN_RESIDUAL = "mhc_sinkhorn_residual"
 # The share of a call's sublayers whose two passes ran as the kernels of
 # ops/pallas_hyper_connections.py: 1 or 0.
 FUSED_SHARE = "mhc_fused_share"
+_KERNELS = "pallas_hyper_connections"
 # The gains of the coefficients' dynamic part start small, so that at
 # the start a sublayer's coefficients are its biases' give or take a
 # little: at 1, ``u Phi`` (unit ``u`` over ``n C`` = 14,336 numbers,
@@ -167,12 +167,6 @@ def post_mix(X, y, h_post, H):
     return mixed + h_post[..., None] * y[:, None, :].astype(_F32)
 
 
-def _kernel_or_plain(kernel, plain, *operands):
-    """``kernel(*operands)`` where the program is lowered for a TPU,
-    ``plain(*operands)`` anywhere else."""
-    return jax.lax.platform_dependent(*operands, tpu=kernel, default=plain)
-
-
 def sublayer(p, prefix, X, F, cfg):
     """One sublayer with hyper-connections: the streams ``X [..., n C]``
     float32 and ``F(h [..., C]) -> (y [..., C], aux)`` -> ``(X', aux,
@@ -181,44 +175,39 @@ def sublayer(p, prefix, X, F, cfg):
     over the streams are that module's kernels, forward and backward;
     anywhere else the plain array operations above. The counter
     ``mhc_fused_share`` says which ran: 1 or 0."""
-    # Imported where it is used: Pallas is ~1.5 s of imports, and
-    # cli/train.py's PRESETS import this module for every preset.
-    from actor_critic_algs_on_tensorflow_tpu.ops import (
-        pallas_hyper_connections as kernels,
-    )
-
     n = cfg.hc_mult
     lead, C = X.shape[:-1], X.shape[-1] // n
     flat = X.reshape(-1, n * C)                  # tokens-major, no copy
     read = {prefix + k: p[prefix + k] for k in ("phi", "alpha", "b_pre")}
+
+    def fits(ops):
+        return ops.fits(flat, n)
 
     def pre_plain(x, read):
         streams = x.reshape(-1, n, C)
         pqr = _project(read, prefix, streams, cfg)
         return pre_mix(streams, _read_weights(read, prefix, pqr, n)), pqr, x
 
-    def pre_kernel(x, read):
-        return kernels.pre(x, read[prefix + "phi"], read[prefix + "alpha"][0],
-                           read[prefix + "b_pre"], eps=cfg.rms_norm_eps)
+    def pre_kernel(ops):
+        return lambda x, read: ops.pre(
+            x, read[prefix + "phi"], read[prefix + "alpha"][0],
+            read[prefix + "b_pre"], eps=cfg.rms_norm_eps,
+        )
 
     def post_plain(x, y, h_post, H):
         return post_mix(x.reshape(-1, n, C), y, h_post, H).reshape(x.shape)
 
-    def zero():
-        return jnp.zeros((), _F32)
-
     with jax.named_scope(profiling.MHC):
-        if kernels.fits(flat, n):
-            pre = functools.partial(_kernel_or_plain, pre_kernel, pre_plain)
-            post = functools.partial(_kernel_or_plain, kernels.post,
-                                     post_plain)
-            share = _kernel_or_plain(lambda: jnp.ones((), _F32), zero)
-        else:
-            pre, post, share = pre_plain, post_plain, zero()
-        h, pqr, flat = pre(flat, read)
+        share = kernel_choice.kernel_ran(_KERNELS, fits)
+        h, pqr, flat = kernel_choice.kernel_or_plain(
+            _KERNELS, fits, pre_kernel, pre_plain, flat, read
+        )
         h_post, H = _write_coefficients(p, prefix, pqr, cfg)
         stats = {**counters(H), FUSED_SHARE: share}
     y, aux = F(h.reshape(lead + (C,)))
     with jax.named_scope(profiling.MHC):
-        out = post(flat, y.reshape(-1, C), h_post, H)
+        out = kernel_choice.kernel_or_plain(
+            _KERNELS, fits, lambda ops: ops.post, post_plain,
+            flat, y.reshape(-1, C), h_post, H,
+        )
         return out.reshape(X.shape), aux, stats

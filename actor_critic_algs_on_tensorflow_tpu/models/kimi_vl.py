@@ -91,16 +91,21 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from actor_critic_algs_on_tensorflow_tpu.models import moe
-from actor_critic_algs_on_tensorflow_tpu.models.moe import mm as _mm
+from actor_critic_algs_on_tensorflow_tpu.models import decoder, moe
+from actor_critic_algs_on_tensorflow_tpu.models.decoder import (
+    dot as _dot,
+    mm as _mm,
+    rms_norm,
+)
+from actor_critic_algs_on_tensorflow_tpu.ops import kernel_choice
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
-_INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
 # The step form's counter: the share of a cache's rows a step read, the
 # same in every layer (1 where the plain form ran).
 CACHE_ROWS_READ = "mla_cache_rows_read_share"
+_MLA_STEP = "pallas_mla_step"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,12 +149,7 @@ class KimiVLConfig:
         built = {"q_lora_rank": None, "rope_scaling": None,
                  "topk_method": "noaux_tc",
                  "n_group": 1, "topk_group": 1, "scoring_func": "sigmoid"}
-        for key, value in built.items():
-            if getattr(self, key) != value:
-                raise ValueError(
-                    f"KimiVLConfig.{key}={getattr(self, key)!r}: only "
-                    f"{value!r} is built (the published value)"
-                )
+        decoder.refuse_unbuilt(self, built)
         if not any(map(self.is_expert_layer, range(self.num_hidden_layers))):
             raise ValueError("no expert layer among the layers held")
 
@@ -176,7 +176,7 @@ def layer_param_spec(cfg: KimiVLConfig, layer: int):
     through one ``q_proj``, or where ``cfg.q_lora_rank`` is set through
     that rank with its own norm (``q_a_proj``, ``q_a_norm``,
     ``q_b_proj``)."""
-    H, w = cfg.hidden_size, nn.initializers.normal(_INIT_STD)
+    H, w = cfg.hidden_size, nn.initializers.normal(decoder.INIT_STD)
     ones = nn.initializers.ones_init()
     nh, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -211,12 +211,6 @@ def layer_param_spec(cfg: KimiVLConfig, layer: int):
 
 
 # ---- pieces ------------------------------------------------------------
-
-
-def rms_norm(x, w, eps):
-    """RMSNorm, float32: ``x * rsqrt(mean(x^2) + eps) * w``."""
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
 def _angles(positions, cfg: KimiVLConfig):
@@ -268,12 +262,6 @@ def _rope(x, angles):
 
 
 # ---- latent attention ---------------------------------------------------
-
-
-def _dot(spec, a, b, dtype):
-    """A product of attention (``einsum``) in ``dtype``, float32 sums."""
-    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
-                      preferred_element_type=_F32)
 
 
 def _mla_project(p, x, angles, cfg, dtype):
@@ -363,52 +351,40 @@ def latent_attention(q, cache, pos, scale, rank, dtype):
     return _dot("bhl,blc->bhc", probs, cache, dtype)[..., :rank]
 
 
-def _kernel_or_plain(caches, rank, kernel, plain, *operands):
-    """``kernel(pallas_mla_step, *operands)`` where the program is
-    lowered for a TPU and ``caches [B, layers, L, rank + d_rope]`` take
-    the one-pass kernel (the latent part whole lane tiles, the rows
-    whole chunks), ``plain(*operands)`` anywhere else: nothing but the
-    lowering platform and the caches' shape chooses."""
-    # Imported where it is used: Pallas is ~1.5 s of imports, and
-    # cli/train.py's PRESETS import this module for every preset.
-    from actor_critic_algs_on_tensorflow_tpu.ops import pallas_mla_step
-
-    if not pallas_mla_step.fits(caches, rank):
-        return plain(*operands)
-    return jax.lax.platform_dependent(
-        *operands, tpu=functools.partial(kernel, pallas_mla_step),
-        default=plain,
-    )
+def _caches_fit(caches, rank):
+    """Whether ``caches [B, layers, L, rank + d_rope]`` take the one-pass
+    kernel (the latent part whole lane tiles, the rows whole chunks)."""
+    return lambda ops: ops.fits(caches, rank)
 
 
 def _latent_attention(q, caches, layer, pos, scale, rank, dtype):
     """``latent_attention`` over layer ``layer``'s cache in ``caches``:
-    the one-pass kernel over the rows that exist
-    (``ops/pallas_mla_step.py``), or the plain form, which reads every
-    row."""
+    lowered for a TPU where the caches fit, the one-pass kernel over the
+    rows that exist (``ops/pallas_mla_step.py``); anywhere else the
+    plain form, which reads every row."""
 
-    def kernel(ops, q, caches, pos):
-        return ops.latent_attention(
+    def kernel(ops):
+        return lambda q, caches, pos: ops.latent_attention(
             q, caches, layer, pos, scale=scale, rank=rank
         )
 
     def plain(q, caches, pos):
         return latent_attention(q, caches[:, layer], pos, scale, rank, dtype)
 
-    return _kernel_or_plain(caches, rank, kernel, plain, q, caches, pos)
+    return kernel_choice.kernel_or_plain(
+        _MLA_STEP, _caches_fit(caches, rank), kernel, plain, q, caches, pos
+    )
 
 
-def _rows_read_share(caches, pos, rank):
+def rows_read_share(caches, pos, rank):
     """The share of a cache's rows that a step at ``pos`` read, the same
-    in every layer: what the kernel's index map fetches, or every row."""
-
-    def kernel(ops, pos):
-        return ops.rows_read_share(pos, caches.shape[2])
-
-    def plain(pos):
-        return jnp.ones((), _F32)
-
-    return _kernel_or_plain(caches, rank, kernel, plain, pos)
+    in every layer: what the kernel's index map fetches, or every row
+    where the plain form ran."""
+    return kernel_choice.kernel_or_plain(
+        _MLA_STEP, _caches_fit(caches, rank),
+        lambda ops: lambda pos: ops.rows_read_share(pos, caches.shape[2]),
+        lambda pos: jnp.ones((), _F32), pos,
+    )
 
 
 def mla_step(p, x, caches, layer, pos, cfg, dtype):
@@ -479,8 +455,8 @@ def shared_experts(p, x, dtype):
     """The ``n_shared_experts`` as one ungated SwiGLU of their summed
     width: what every chip computes alike."""
     with jax.named_scope(profiling.MOE_SHARED):
-        return moe.swiglu(x, p["shared_w_gate"], p["shared_w_up"],
-                          p["shared_w_down"], dtype)
+        return decoder.swiglu(x, p["shared_w_gate"], p["shared_w_up"],
+                              p["shared_w_down"], dtype)
 
 
 def moe_block(p, x, cfg: KimiVLConfig, dtype):
@@ -503,7 +479,8 @@ def feed_forward_branch(p, x, cfg, dtype, expert: bool):
         return y.reshape(x.shape), stats
     with jax.named_scope(profiling.DENSE_MLP):
         h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
-        y = moe.swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"], dtype)
+        y = decoder.swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"],
+                           dtype)
     return y, None
 
 
@@ -557,28 +534,16 @@ class KimiVLActorCritic(nn.Module):
     @nn.compact
     def __call__(self, tokens, resets, carry):
         cfg, dtype = self.cfg, jnp.dtype(self.dtype)
-        H = cfg.hidden_size
-        w = nn.initializers.normal(_INIT_STD)
-        embedding = self.param("embedding", w, (cfg.vocab_size, H), _F32)
-        layers = [
-            moe.Params(tuple(layer_param_spec(cfg, i).items()),
-                       name=f"layer_{i}")()
-            for i in range(cfg.num_hidden_layers)
-        ]
-        final_norm = self.param(
-            "final_norm", nn.initializers.ones_init(), (H,), _F32
-        )
-        lm_head = self.param("lm_head", w, (H, cfg.vocab_size), _F32)
-        value_w = self.param("value_w", w, (H,), _F32)
-        value_b = self.param(
-            "value_b", nn.initializers.zeros_init(), (), _F32
+        head, layers = decoder.parameters(
+            self, cfg,
+            [layer_param_spec(cfg, i) for i in range(cfg.num_hidden_layers)],
+            nn.initializers.ones_init(),
         )
 
-        x = jnp.take(embedding, tokens.astype(jnp.int32), axis=0)
+        x = decoder.embed(head, tokens)
         all_stats = []
         if tokens.shape[0] == 1:
-            keep = 1.0 - resets[0].astype(_F32)
-            pos = (carry["pos"] * keep).astype(jnp.int32)
+            _, pos = decoder.step_position(carry, resets)
             x, caches = x[0], carry["layers"]
             for i, p in enumerate(layers):
                 x, caches, stats = _decoder_layer_step(
@@ -600,24 +565,18 @@ class KimiVLActorCritic(nn.Module):
                 )
                 x, stats = layer(p, x)
                 all_stats.append(stats)
-        with jax.named_scope(profiling.LM_HEAD):
-            h = rms_norm(x, final_norm, cfg.rms_norm_eps)
-            logits = _mm(h, lm_head, dtype)
-        values = jnp.dot(h, value_w, precision=_HIGHEST) + value_b
+        h, logits = decoder.lm_head(x, head, cfg.rms_norm_eps, dtype)
+        values = decoder.value(h, head)
         stats = moe.stack_layer_stats(
             [s for s in all_stats if s is not None]
         )
         if tokens.shape[0] == 1:
-            stats[CACHE_ROWS_READ] = _rows_read_share(
+            stats[CACHE_ROWS_READ] = rows_read_share(
                 carry["layers"], pos, cfg.kv_lora_rank
             )
         return logits, values, carry, stats
 
     def initialize_carry(self, batch: int) -> Dict[str, Any]:
         """The empty carry for ``batch`` environments."""
-        shape = (batch, self.cfg.num_hidden_layers, self.cache_len,
-                 self.cfg.cache_width)
-        return {
-            "layers": jnp.zeros(shape, jnp.dtype(self.dtype)),
-            "pos": jnp.zeros((batch,), jnp.int32),
-        }
+        return decoder.one_cache_carry(batch, self.cfg, self.cache_len,
+                                       self.dtype)

@@ -1,8 +1,9 @@
 """The expert layer the sequence cores share (``models/qwen3_next.py``,
-``models/kimi_vl.py``, ``models/sdar.py``): a chip's share of a routed
-mixture of SwiGLU experts, dropless within one dispatch buffer, with its
-counters; and the small pieces the cores build their layers from
-(``mm``, ``Params``, the softmax-top-k routing two of them bind).
+``models/kimi_vl.py``, ``models/sdar.py``, ``models/xing.py``): a
+chip's share of a routed mixture of SwiGLU experts, dropless within one
+dispatch buffer, with its counters, and the softmax-top-k routing two of
+them bind. The pieces every core builds its layers from are
+``models/decoder.py``'s.
 
 The layer is told which experts it holds (``ExpertSpec``:
 ``first_expert``, ``experts_held`` of ``num_experts``). It routes over
@@ -35,11 +36,9 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any
 
 import jax
 import jax.numpy as jnp
-from flax import linen as nn
 
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
@@ -79,28 +78,6 @@ class ExpertSpec:
         top = self.moe_capacity(tokens)
         lower = sorted({self._rows(tokens, f) for f in _LADDER_FACTORS})
         return (*(rows for rows in lower if 2 * rows <= top), top)
-
-
-class Params(nn.Module):
-    """A named group of float32 parameters (a decoder layer's)."""
-
-    spec: Any  # {name: (shape, init)}, hashable as a tuple of items
-
-    @nn.compact
-    def __call__(self):
-        return {name: self.param(name, init, shape, _F32)
-                for name, (shape, init) in self.spec}
-
-
-def mm(x, w, dtype):
-    """A matrix product in ``dtype`` with float32 accumulation."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=_F32)
-
-
-def swiglu(x, w_gate, w_up, w_down, dtype):
-    return mm(jax.nn.silu(mm(x, w_gate, dtype)) * mm(x, w_up, dtype),
-              w_down, dtype)
 
 
 def route_softmax_top_k(p, x, top_k: int, renormalise: bool):

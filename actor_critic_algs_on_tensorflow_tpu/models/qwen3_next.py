@@ -48,13 +48,14 @@ accumulation; the norms, the router (product, softmax, top-k), the
 DeltaNet's gates, state and chunk products, the attention's softmax
 and both heads' outputs are float32 whatever ``dtype`` says.
 
-The expert layer is ``models/moe.py``'s, shared with the other sequence
-cores: it is told which experts it holds (``first_expert``,
-``experts_held`` of ``num_experts``), routes over all of them with this
-model's ``route`` (softmax, the published top-k and its
-renormalisation), computes only the terms of its own experts in one
-dropless dispatch buffer and counts what did not fit; the sigmoid-gated
-shared expert is this model's own.
+The expert layer is ``models/moe.py``'s and the shared pieces (norm,
+rotary, attention, the heads) are ``models/decoder.py``'s, as in the
+other sequence cores. The expert layer is told which experts it holds
+(``first_expert``, ``experts_held`` of ``num_experts``), routes over
+all of them with this model's ``route`` (softmax, the published top-k
+and its renormalisation), computes only the terms of its own experts in
+one dropless dispatch buffer and counts what did not fit; the
+sigmoid-gated shared expert is this model's own.
 """
 
 from __future__ import annotations
@@ -67,16 +68,13 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from actor_critic_algs_on_tensorflow_tpu.models import moe
-from actor_critic_algs_on_tensorflow_tpu.models.moe import (
-    mm as _mm,
-    swiglu as _expert_ffn,
-)
+from actor_critic_algs_on_tensorflow_tpu.models import decoder, moe
+from actor_critic_algs_on_tensorflow_tpu.models.decoder import mm as _mm
+from actor_critic_algs_on_tensorflow_tpu.ops import kernel_choice
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
-_INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
 # The share of a sequence pass's chunked-rule solves whose inverse ran as
 # the kernel of ops/pallas_unit_lower_inverse.py: 1 or 0.
 SOLVE_KERNEL_SHARE = "gdn_solve_kernel_share"
@@ -135,17 +133,13 @@ class Qwen3NextConfig:
 # ---- parameters --------------------------------------------------------
 
 
-def _normal(std):
-    return nn.initializers.normal(std)
-
-
 def _a_log_init(key, shape, dtype=_F32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-4, 16.0))
 
 
 def layer_param_spec(cfg: Qwen3NextConfig, layer: int):
     """``{name: (shape, init)}`` of one decoder layer."""
-    H, w = cfg.hidden_size, _normal(_INIT_STD)
+    H, w = cfg.hidden_size, nn.initializers.normal(decoder.INIT_STD)
     zeros, ones = nn.initializers.zeros_init(), nn.initializers.ones_init()
     spec = {"input_norm": ((H,), zeros), "post_norm": ((H,), zeros)}
     if cfg.is_attention(layer):
@@ -180,33 +174,13 @@ def layer_param_spec(cfg: Qwen3NextConfig, layer: int):
 # ---- pieces ------------------------------------------------------------
 
 
-def rms_norm(x, w, eps):
-    """Zero-centred RMSNorm, float32: ``x * rsqrt(mean(x^2) + eps) *
-    (1 + w)``."""
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (
-        1.0 + w
-    )
+# Every RMSNorm of this model but the DeltaNet's gated one has a
+# zero-centred weight: ``x / rms(x) * (1 + w)``.
+rms_norm = functools.partial(decoder.rms_norm, zero_centred=True)
 
 
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
-
-
-def _rotary(x, positions, cfg: Qwen3NextConfig):
-    """Rotate-half rotary embedding on the first ``partial_rotary_factor``
-    of the head's dims; ``x [..., heads, head_dim]``, ``positions``
-    shaped like ``x`` without its last two axes."""
-    rot = int(cfg.head_dim * cfg.partial_rotary_factor)
-    inv_freq = cfg.rope_theta ** (-jnp.arange(0, rot, 2, dtype=_F32) / rot)
-    angles = positions.astype(_F32)[..., None, None] * inv_freq
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)
-    x_rot, x_pass = x[..., :rot], x[..., rot:]
-    half = jnp.concatenate(
-        [-x_rot[..., rot // 2:], x_rot[..., : rot // 2]], -1
-    )
-    return jnp.concatenate([x_rot * cos + half * sin, x_pass], -1)
 
 
 # ---- gated attention ---------------------------------------------------
@@ -217,6 +191,9 @@ def _attn_project(p, x, positions, cfg, dtype):
     ``[..., nkv, hd]``; query and key normed and rotated, float32."""
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
+    eps = cfg.rms_norm_eps
+    # the rotary turns the first partial_rotary_factor of a head
+    rope = (cfg.rope_theta, int(hd * cfg.partial_rotary_factor))
     with jax.named_scope(profiling.MIXER_PROJ):
         qg, k, v = (
             _mm(x, p[w], dtype) for w in ("q_proj", "k_proj", "v_proj")
@@ -226,28 +203,9 @@ def _attn_project(p, x, positions, cfg, dtype):
         q, gate = qg[..., :hd], qg[..., hd:]
         k = k.reshape(x.shape[:-1] + (nkv, hd))
         v = v.reshape(x.shape[:-1] + (nkv, hd))
-        q = _rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, cfg)
-        k = _rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, cfg)
+        q = decoder.rotary(rms_norm(q, p["q_norm"], eps), positions, *rope)
+        k = decoder.rotary(rms_norm(k, p["k_norm"], eps), positions, *rope)
     return q, gate, k, v
-
-
-def _attend(q, k, v, mask, cfg, dtype):
-    """``q [b, tq, nh, hd]``, ``k, v [b, tk, nkv, hd]``, ``mask [b, tq,
-    tk]`` (True = visible) -> ``[b, tq, nh, hd]``; softmax in float32."""
-    b, tq, nh, hd = q.shape
-    nkv = k.shape[2]
-    q = q.reshape(b, tq, nkv, nh // nkv, hd)
-    scores = jnp.einsum(
-        "bqkgd,bskd->bkgqs", q.astype(dtype), k.astype(dtype),
-        preferred_element_type=_F32,
-    ) * (hd ** -0.5)
-    scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "bkgqs,bskd->bqkgd", probs.astype(dtype), v.astype(dtype),
-        preferred_element_type=_F32,
-    )
-    return out.reshape(b, tq, nh, hd)
 
 
 def gated_attention_seq(p, x, cfg, dtype):
@@ -259,7 +217,7 @@ def gated_attention_seq(p, x, cfg, dtype):
     q, gate, k, v = _attn_project(p, xb, positions, cfg, dtype)
     with jax.named_scope(profiling.MIXER_CORE):
         mask = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (b, T, T))
-        out = _attend(q, k, v, mask, cfg, dtype)
+        out = decoder.attend(q, k, v, mask, dtype).reshape(q.shape)
     with jax.named_scope(profiling.MIXER_POINTWISE):
         out = (out * jax.nn.sigmoid(gate)).reshape(b, T, -1)
     with jax.named_scope(profiling.MIXER_PROJ):
@@ -283,7 +241,8 @@ def gated_attention_step(p, x, cache, pos, cfg, dtype):
             "v": jnp.where(here, v.astype(cache["v"].dtype), cache["v"]),
         }
         mask = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, :]
-        out = _attend(q, cache["k"], cache["v"], mask, cfg, dtype)
+        out = decoder.attend(q, cache["k"], cache["v"], mask, dtype)
+        out = out.reshape(q.shape)
     with jax.named_scope(profiling.MIXER_POINTWISE):
         out = (out * jax.nn.sigmoid(gate)).reshape(pos.shape[0], -1)
     with jax.named_scope(profiling.MIXER_PROJ):
@@ -294,7 +253,8 @@ def gated_attention_step(p, x, cache, pos, cfg, dtype):
 
 
 def _chunk_rule(q, k, v, g, beta, chunk: int):
-    """``chunk_gated_delta_rule`` and its solve's ``solve_kernel_share``."""
+    """``chunk_gated_delta_rule`` and whether its solve ran the kernel
+    (1 or 0)."""
     T, dv = q.shape[2], v.shape[-1]
     pad = -T % chunk
     if pad:
@@ -325,7 +285,7 @@ def _chunk_rule(q, k, v, g, beta, chunk: int):
         a = dot("nbhid,nbhjd->nbhij", k_beta, k) * decay * strict
         rhs = jnp.concatenate([v_beta, k_beta * jnp.exp(gc)[..., None]], -1)
         solved = solve_unit_lower(a, rhs)
-        share = solve_kernel_share(a)
+        share = kernel_choice.kernel_ran(_INVERSE, _inverse_fits(a))
         u, w = solved[..., :dv], solved[..., dv:]
     with jax.named_scope(profiling.GDN_CHUNK_PRODUCTS):
         qk = dot("nbhid,nbhjd->nbhij", q, k) * decay
@@ -373,38 +333,21 @@ def _plain_inverse(a):
     )
 
 
+_INVERSE = "pallas_unit_lower_inverse"
+
+
+def _inverse_fits(a):
+    return lambda ops: ops.fits(a)
+
+
 def _unit_lower_inverse(a):
     """``(I + a)^{-1}`` of ``a [..., c, c]`` strictly lower, float32.
     Lowered for a TPU, at widths that tile its vector unit, the kernel
     that substitutes 128 matrices at once in VMEM
     (``ops/pallas_unit_lower_inverse.py``); anywhere else XLA's
     triangular solve, which a TPU runs one matrix after another."""
-    # Imported where it is used: Pallas is ~1.5 s of imports, and
-    # cli/train.py's PRESETS import this module for every preset.
-    from actor_critic_algs_on_tensorflow_tpu.ops import (
-        pallas_unit_lower_inverse as kernel,
-    )
-
-    if not kernel.fits(a):
-        return _plain_inverse(a)
-    return jax.lax.platform_dependent(
-        a, tpu=kernel.inverse, default=_plain_inverse
-    )
-
-
-def solve_kernel_share(a):
-    """1 where ``_unit_lower_inverse(a)`` runs the kernel, else 0."""
-    from actor_critic_algs_on_tensorflow_tpu.ops import (
-        pallas_unit_lower_inverse as kernel,
-    )
-
-    def zero():
-        return jnp.zeros((), _F32)
-
-    if not kernel.fits(a):
-        return zero()
-    return jax.lax.platform_dependent(
-        tpu=lambda: jnp.ones((), _F32), default=zero
+    return kernel_choice.kernel_or_plain(
+        _INVERSE, _inverse_fits(a), lambda ops: ops.inverse, _plain_inverse, a
     )
 
 
@@ -507,7 +450,7 @@ def _gdn_output(p, o, z, cfg, dtype):
 
 def gated_deltanet_seq(p, x, cfg, dtype):
     """``x [T, b, H]`` from an empty state and convolution history ->
-    ``(y, solve_kernel_share)``."""
+    ``(y, whether the solve ran the kernel)``."""
     T = x.shape[0]
     K = cfg.linear_conv_kernel_dim
     qkv, z, beta, g = _gdn_inputs(p, x, cfg, dtype)
@@ -534,21 +477,18 @@ def _state_step(S, q, k, v, g, beta, keep):
     """``gated_delta_step`` on the state less the envs that start over
     (``keep [B]``, 0 at a reset). Lowered for a TPU, at widths that
     tile its vector unit, the one-pass kernel with the reset folded
-    into the decay; anywhere else the plain form."""
-    # Imported where it is used: Pallas is ~1.5 s of imports, and
-    # cli/train.py's PRESETS import this module for every preset.
-    from actor_critic_algs_on_tensorflow_tpu.ops import pallas_delta_step
+    into the decay (``ops/pallas_delta_step.py``); anywhere else the
+    plain form."""
 
     def plain(S, q, k, v, g, beta, keep):
         return gated_delta_step(
             S * keep[:, None, None, None], q, k, v, g, beta
         )
 
-    if not pallas_delta_step.fits(S):
-        return plain(S, q, k, v, g, beta, keep)
-    return jax.lax.platform_dependent(
+    return kernel_choice.kernel_or_plain(
+        "pallas_delta_step", lambda ops: ops.fits(S),
+        lambda ops: ops.gated_delta_step, plain,
         S, q, k, v, g, beta, keep,
-        tpu=pallas_delta_step.gated_delta_step, default=plain,
     )
 
 
@@ -593,9 +533,9 @@ def routed_experts(p, x, cfg: Qwen3NextConfig, dtype):
 def shared_expert(p, x, dtype):
     """``sigmoid(x w_s) E_shared(x)``: what every chip computes alike."""
     with jax.named_scope(profiling.MOE_SHARED):
-        return jax.nn.sigmoid(_mm(x, p["shared_gate"], dtype)) * _expert_ffn(
-            x, p["shared_w_gate"], p["shared_w_up"], p["shared_w_down"],
-            dtype,
+        gate = jax.nn.sigmoid(_mm(x, p["shared_gate"], dtype))
+        return gate * decoder.swiglu(
+            x, p["shared_w_gate"], p["shared_w_up"], p["shared_w_down"], dtype
         )
 
 
@@ -621,7 +561,7 @@ def _decoder_layer_seq(p, x, cfg, dtype, attention: bool):
     )
     with jax.named_scope(mixer):
         y = seq(p, h, cfg, dtype)
-        # the layer's chunked-rule solves, solve_kernel_share each
+        # the layer's chunked-rule solves: whether each ran the kernel
         y, shares = (y, ()) if attention else (y[0], y[1:])
         with jax.named_scope(profiling.MIXER_POINTWISE):
             x = x + y
@@ -672,27 +612,17 @@ class Qwen3NextActorCritic(nn.Module):
     @nn.compact
     def __call__(self, tokens, resets, carry):
         cfg, dtype = self.cfg, jnp.dtype(self.dtype)
-        H, std = cfg.hidden_size, _INIT_STD
-        zeros = nn.initializers.zeros_init()
-        embedding = self.param(
-            "embedding", _normal(std), (cfg.vocab_size, H), _F32
+        head, layers = decoder.parameters(
+            self, cfg,
+            [layer_param_spec(cfg, i) for i in range(cfg.num_hidden_layers)],
+            nn.initializers.zeros_init(),
         )
-        layers = [
-            moe.Params(tuple(layer_param_spec(cfg, i).items()),
-                    name=f"layer_{i}")()
-            for i in range(cfg.num_hidden_layers)
-        ]
-        final_norm = self.param("final_norm", zeros, (H,), _F32)
-        lm_head = self.param("lm_head", _normal(std), (H, cfg.vocab_size), _F32)
-        value_w = self.param("value_w", _normal(std), (H,), _F32)
-        value_b = self.param("value_b", zeros, (), _F32)
 
         T = tokens.shape[0]
-        x = jnp.take(embedding, tokens.astype(jnp.int32), axis=0)
+        x = decoder.embed(head, tokens)
         all_stats = []
         if T == 1:
-            keep = 1.0 - resets[0].astype(_F32)
-            pos = (carry["pos"] * keep).astype(jnp.int32)
+            keep, pos = decoder.step_position(carry, resets)
             x, new_layers = x[0], []
             for i, p in enumerate(layers):
                 x, state, stats = _decoder_layer_step(
@@ -717,10 +647,10 @@ class Qwen3NextActorCritic(nn.Module):
                 x, stats, solves = layer(p, x)
                 all_stats.append(stats)
                 shares.extend(solves)
-        with jax.named_scope(profiling.LM_HEAD):
-            h = rms_norm(x, final_norm, cfg.rms_norm_eps)
-            logits = _mm(h, lm_head, dtype)
-        values = jnp.dot(h, value_w, precision=_HIGHEST) + value_b
+        h, logits = decoder.lm_head(
+            x, head, cfg.rms_norm_eps, dtype, zero_centred=True
+        )
+        values = decoder.value(h, head)
         stats = moe.stack_layer_stats(all_stats)
         if T > 1:
             stats[SOLVE_KERNEL_SHARE] = jnp.mean(jnp.stack(shares))

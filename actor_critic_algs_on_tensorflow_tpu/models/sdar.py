@@ -66,9 +66,9 @@ an episode commits. Two forms of one layer share the parameters:
   weighted sum are ``ops/pallas_block_attention.py``'s kernel pair,
   which rebuilds the mask a tile at a time and keeps the scores in VMEM,
   forward and backward; on the CPU, and at ``ppo-sdar-tiny``'s heads
-  anywhere, they are the plain ``_attend`` over the dense ``[b, n, n]``
-  mask (``_attend_seq`` chooses, by the lowering platform and the
-  shapes alone; the log row's ``gqa_score_tiles_computed_share`` says
+  anywhere, they are the plain ``decoder.attend`` over the dense ``[b,
+  n, n]`` mask (``_attend_seq`` chooses, by the lowering platform and
+  the shapes alone; the log row's ``gqa_score_tiles_computed_share`` says
   which ran).
 
 Rotary embedding: rotate-half over all ``head_dim`` dimensions
@@ -83,7 +83,8 @@ the attention's softmax and both heads' outputs are float32 whatever
 ``dtype`` says. RMSNorm is the plain ``w * x / rms(x)``, ``w`` from 1.
 
 The expert layer is ``models/moe.py``'s, shared with the other cores,
-under its softmax-top-k-renormalise ``route``.
+under its softmax-top-k-renormalise ``route``; norm, rotary, attention
+and heads are ``models/decoder.py``'s.
 """
 
 from __future__ import annotations
@@ -96,13 +97,16 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from actor_critic_algs_on_tensorflow_tpu.models import moe
-from actor_critic_algs_on_tensorflow_tpu.models.moe import mm as _mm
+from actor_critic_algs_on_tensorflow_tpu.models import decoder, moe
+from actor_critic_algs_on_tensorflow_tpu.models.decoder import (
+    dot as _dot,
+    mm as _mm,
+    rms_norm,
+)
+from actor_critic_algs_on_tensorflow_tpu.ops import kernel_choice
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
-_HIGHEST = jax.lax.Precision.HIGHEST
-_INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
 # The step form's counter, one row a rollout pass: the tokens a pass
 # committed, mean over the envs.
 COMMITTED_TOKENS = "diffusion_committed_tokens"
@@ -158,12 +162,7 @@ class SDARConfig:
         built = {"use_sliding_window": False, "rope_scaling": None,
                  "mlp_only_layers": (), "decoder_sparse_step": 1,
                  "attention_bias": False}
-        for key, value in built.items():
-            if getattr(self, key) != value:
-                raise ValueError(
-                    f"SDARConfig.{key}={getattr(self, key)!r}: only "
-                    f"{value!r} is built (the published value)"
-                )
+        decoder.refuse_unbuilt(self, built)
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("query heads must share the key/value heads "
                              "evenly")
@@ -195,7 +194,7 @@ class SDARConfig:
 
 def layer_param_spec(cfg: SDARConfig):
     """``{name: (shape, init)}`` of one decoder layer."""
-    H, w = cfg.hidden_size, nn.initializers.normal(_INIT_STD)
+    H, w = cfg.hidden_size, nn.initializers.normal(decoder.INIT_STD)
     ones = nn.initializers.ones_init()
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
@@ -214,25 +213,6 @@ def layer_param_spec(cfg: SDARConfig):
 # ---- pieces ------------------------------------------------------------
 
 
-def rms_norm(x, w, eps):
-    """RMSNorm, float32: ``x * rsqrt(mean(x^2) + eps) * w``."""
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rotary(x, positions, cfg: SDARConfig):
-    """Rotate-half rotary embedding over all of the head's dimensions;
-    ``x [..., heads, head_dim]``, ``positions`` shaped like ``x``
-    without its last two axes."""
-    hd = cfg.head_dim
-    inv_freq = cfg.rope_theta ** (-jnp.arange(0, hd, 2, dtype=_F32) / hd)
-    angles = positions.astype(_F32)[..., None, None] * inv_freq
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)
-    half = jnp.concatenate([-x[..., hd // 2:], x[..., : hd // 2]], -1)
-    return x * cos + half * sin
-
-
 def is_commit(tokens, cfg: SDARConfig):
     """``tokens [..., block_length]`` -> whether each pass is a commit:
     its block holds no mask token."""
@@ -248,6 +228,9 @@ def _project(p, x, positions, cfg, dtype):
     float32."""
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
+    eps = cfg.rms_norm_eps
+    # the rotary turns all of a head's dimensions
+    rope = (cfg.rope_theta, hd)
     with jax.named_scope(profiling.MIXER_PROJ):
         q, k, v = (
             _mm(x, p[w], dtype) for w in ("q_proj", "k_proj", "v_proj")
@@ -256,40 +239,21 @@ def _project(p, x, positions, cfg, dtype):
         q = q.reshape(x.shape[:-1] + (nh, hd))
         k = k.reshape(x.shape[:-1] + (nkv, hd))
         v = v.reshape(x.shape[:-1] + (nkv, hd))
-        q = _rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, cfg)
-        k = _rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, cfg)
+        q = decoder.rotary(rms_norm(q, p["q_norm"], eps), positions, *rope)
+        k = decoder.rotary(rms_norm(k, p["k_norm"], eps), positions, *rope)
     return q, k, v
-
-
-def _dot(spec, x, y, dtype):
-    """A product of attention (``einsum``) in ``dtype``, float32 sums."""
-    return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
-                      preferred_element_type=_F32)
-
-
-def _attend(q, k, v, visible, dtype):
-    """``q [b, tq, nh, hd]``, ``k, v [b, tk, nkv, hd]``, ``visible [b,
-    tq, tk]`` -> ``[b, tq, nh * hd]``; query head ``j`` reads key/value
-    head ``j // (nh / nkv)``; softmax in float32."""
-    b, tq, nh, hd = q.shape
-    nkv = k.shape[2]
-    q = q.reshape(b, tq, nkv, nh // nkv, hd)
-    scores = _dot("bqkgd,bskd->bkgqs", q, k, dtype) * hd ** -0.5
-    scores = jnp.where(visible[:, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return _dot("bkgqs,bskd->bqkgd", probs, v, dtype).reshape(b, tq, nh * hd)
 
 
 def _attend_cached(q, cache, visible, cfg, dtype):
     """``q [b, n, nh, hd]`` over the rows of ``cache [b, L, 2 * nkv *
     hd]`` (a token's keys, then its values) that ``visible [b, L]``
-    marks -> ``[b, n, nh * hd]``, as ``_attend`` would give it. A pair
-    of products a key/value head over ITS ``hd`` columns of the rows,
-    which are whole lane tiles at the published ``head_dim`` of 128:
-    the TPU's compiler stages the layer's rows through VMEM once a pass
-    and slices them there. (Reshaped into per-head keys and values for
-    ``_attend`` it copies the layer's cache out and transposes both
-    halves every pass, and with both products over the whole 1,024-wide
+    marks -> ``[b, n, nh * hd]``, what ``decoder.attend`` gives with the
+    heads merged. A pair of products a key/value head over ITS ``hd``
+    columns of the rows, which are whole lane tiles at the published
+    ``head_dim`` of 128: the TPU's compiler stages the layer's rows
+    through VMEM once a pass and slices them there. (Reshaped into
+    per-head keys and values for ``decoder.attend`` it copies the
+    layer's cache out and transposes both halves every pass, and with both products over the whole 1,024-wide
     row as one operand the query operand is as large as the cache: 4.89
     and 4.43 ms a pass of six layers against 2.57; PERF.md section 6,
     PR 33.)"""
@@ -373,58 +337,50 @@ def trajectory_mask(commit, block_length: int):
     return positions, _visible(step, key_commit)
 
 
-def _kernel_or_plain(q, k, v, kernel, plain, *operands):
-    """``kernel(pallas_block_attention, *operands)`` where the program
-    is lowered for a TPU and the heads take the kernels (``head_dim``
-    whole lane tiles, ``n`` whole sublane tiles), ``plain(*operands)``
-    anywhere else: nothing but the lowering platform and the shapes
-    chooses."""
-    # Imported where it is used: Pallas is ~1.5 s of imports, and
-    # cli/train.py's PRESETS import this module for every preset.
-    from actor_critic_algs_on_tensorflow_tpu.ops import (
-        pallas_block_attention,
-    )
+_BLOCK_ATTENTION = "pallas_block_attention"
 
-    if not pallas_block_attention.fits(q, k, v):
-        return plain(*operands)
-    return jax.lax.platform_dependent(
-        *operands, tpu=functools.partial(kernel, pallas_block_attention),
-        default=plain,
-    )
+
+def _heads_fit(q, k, v):
+    """Whether the heads take the kernels (``head_dim`` whole lane tiles,
+    ``n`` whole sublane tiles)."""
+    return lambda ops: ops.fits(q, k, v)
 
 
 def _attend_seq(q, k, v, step, key_commit, dtype):
-    """``_attend`` under the trajectory's mask, rounded to ``dtype``
-    (the output projection's product rounds it there anyway): the
-    kernels that keep the scores in VMEM
-    (``ops/pallas_block_attention.py``), or the plain form over the
-    dense mask."""
+    """``decoder.attend`` under the trajectory's mask -> ``[b, n, nh *
+    hd]`` rounded to ``dtype`` (the output projection's product rounds it
+    there anyway): lowered for a TPU where the heads fit, the kernels
+    that keep the scores in VMEM (``ops/pallas_block_attention.py``);
+    anywhere else the plain form over the dense mask."""
+    b, n = q.shape[:2]
 
-    def kernel(ops, q, k, v, key_commit):
-        return ops.block_attention(q, k, v, step, key_commit, dtype)
+    def kernel(ops):
+        return lambda q, k, v, key_commit: ops.block_attention(
+            q, k, v, step, key_commit, dtype
+        )
 
     def plain(q, k, v, key_commit):
-        out = _attend(q, k, v, _visible(step, key_commit), dtype)
-        return out.astype(dtype)
+        out = decoder.attend(q, k, v, _visible(step, key_commit), dtype)
+        return out.reshape(b, n, -1).astype(dtype)
 
     with jax.named_scope(profiling.MIXER_CORE), jax.named_scope(
         profiling.GQA_SEQ_ATTEND
     ):
-        return _kernel_or_plain(q, k, v, kernel, plain, q, k, v, key_commit)
+        return kernel_choice.kernel_or_plain(
+            _BLOCK_ATTENTION, _heads_fit(q, k, v), kernel, plain,
+            q, k, v, key_commit,
+        )
 
 
 def _score_tiles_computed_share(q, k, v, step):
     """The share of the sequence form's (tile of queries, chunk of
     keys) pairs whose scores are computed, the same in every layer:
     what the kernels' skip rule visits, or every one."""
-
-    def kernel(ops, step):
-        return ops.score_tiles_computed_share(step)
-
-    def plain(step):
-        return jnp.ones((), _F32)
-
-    return _kernel_or_plain(q, k, v, kernel, plain, step)
+    return kernel_choice.kernel_or_plain(
+        _BLOCK_ATTENTION, _heads_fit(q, k, v),
+        lambda ops: lambda step: ops.score_tiles_computed_share(step),
+        lambda step: jnp.ones((), _F32), step,
+    )
 
 
 def gqa_seq(p, x, positions, step, key_commit, cfg, dtype):
@@ -552,28 +508,18 @@ class SDARActorCritic(nn.Module):
     def __call__(self, tokens, resets, carry):
         cfg, dtype = self.cfg, jnp.dtype(self.dtype)
         H, n = cfg.hidden_size, cfg.block_length
-        w = nn.initializers.normal(_INIT_STD)
-        embedding = self.param("embedding", w, (cfg.vocab_size, H), _F32)
-        spec = tuple(layer_param_spec(cfg).items())
-        layers = [moe.Params(spec, name=f"layer_{i}")()
-                  for i in range(cfg.num_hidden_layers)]
-        final_norm = self.param(
-            "final_norm", nn.initializers.ones_init(), (H,), _F32
-        )
-        lm_head = self.param("lm_head", w, (H, cfg.vocab_size), _F32)
-        value_w = self.param("value_w", w, (H,), _F32)
-        value_b = self.param(
-            "value_b", nn.initializers.zeros_init(), (), _F32
+        head, layers = decoder.parameters(
+            self, cfg, [layer_param_spec(cfg)] * cfg.num_hidden_layers,
+            nn.initializers.ones_init(),
         )
 
         tokens = tokens.astype(jnp.int32)
         T, B = tokens.shape[:2]
         commit = is_commit(tokens, cfg)                       # [T, B]
-        x = jnp.take(embedding, tokens, axis=0)               # [T, B, n, H]
+        x = decoder.embed(head, tokens)                       # [T, B, n, H]
         all_stats = []
         if T == 1:
-            keep = 1.0 - resets[0].astype(_F32)
-            pos = (carry["pos"] * keep).astype(jnp.int32)
+            _, pos = decoder.step_position(carry, resets)
             x, caches = x[0], carry["layers"]
             for i, p in enumerate(layers):
                 x, caches, stats = _decoder_layer_step(
@@ -598,17 +544,14 @@ class SDARActorCritic(nn.Module):
                 x, stats = layer(p, x, positions, step, key_commit)
                 all_stats.append(stats)
             x = jnp.swapaxes(x.reshape(B, T, n, H), 0, 1)
+        h, logits = decoder.lm_head(x, head, cfg.rms_norm_eps, dtype)
         with jax.named_scope(profiling.LM_HEAD):
-            h = rms_norm(x, final_norm, cfg.rms_norm_eps)
-            logits = _mm(h, lm_head, dtype)
             # a mask is never an action
             logits = jnp.where(
                 jnp.arange(cfg.vocab_size) == cfg.mask_token_id, -jnp.inf,
                 logits,
             )
-        values = jnp.dot(
-            jnp.mean(h, axis=-2), value_w, precision=_HIGHEST
-        ) + value_b
+        values = decoder.value(jnp.mean(h, axis=-2), head)
         stats = moe.stack_layer_stats(all_stats)
         if T == 1:
             stats[COMMITTED_TOKENS] = jnp.mean(committed.astype(_F32))
@@ -630,9 +573,5 @@ class SDARActorCritic(nn.Module):
 
     def initialize_carry(self, batch: int) -> Dict[str, Any]:
         """The empty carry for ``batch`` environments."""
-        shape = (batch, self.cfg.num_hidden_layers, self.cache_len,
-                 self.cfg.cache_width)
-        return {
-            "layers": jnp.zeros(shape, jnp.dtype(self.dtype)),
-            "pos": jnp.zeros((batch,), jnp.int32),
-        }
+        return decoder.one_cache_carry(batch, self.cfg, self.cache_len,
+                                       self.dtype)

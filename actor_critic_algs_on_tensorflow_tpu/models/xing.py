@@ -64,15 +64,12 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from actor_critic_algs_on_tensorflow_tpu.models import decoder
 from actor_critic_algs_on_tensorflow_tpu.models import hyper_connections as hc
 from actor_critic_algs_on_tensorflow_tpu.models import kimi_vl as kv
 from actor_critic_algs_on_tensorflow_tpu.models import moe
-from actor_critic_algs_on_tensorflow_tpu.models.moe import mm as _mm
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
-_F32 = jnp.float32
-_HIGHEST = jax.lax.Precision.HIGHEST
-_INIT_STD = 0.02  # every matrix, normal (assumed; the config gives none)
 # The published YaRN parameters, as a tuple of items (a config is hashed).
 _YARN = (("beta_fast", 32), ("beta_slow", 1), ("factor", 64),
          ("mscale", 1), ("mscale_all_dim", 1),
@@ -133,12 +130,7 @@ class XingConfig:
                  "tie_word_embeddings": False, "topk_method": "noaux_tc",
                  "n_group": 1, "topk_group": 1, "scoring_func": "sigmoid",
                  "num_key_value_heads": self.num_attention_heads}
-        for key, value in built.items():
-            if getattr(self, key) != value:
-                raise ValueError(
-                    f"XingConfig.{key}={getattr(self, key)!r}: only "
-                    f"{value!r} is built (the published value)"
-                )
+        decoder.refuse_unbuilt(self, built)
         if self.rope_scaling is not None:
             yarn = dict(self.rope_scaling)
             if yarn.get("type") != "yarn" or (
@@ -176,7 +168,7 @@ def _decoder_layer_seq(p, X, cfg, dtype, expert: bool):
     def attention(h):
         with jax.named_scope(profiling.MLA):
             with jax.named_scope(profiling.MIXER_POINTWISE):
-                h = kv.rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
+                h = decoder.rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
             return kv.mla_seq(p, h, cfg, dtype), None
 
     X, _, attn = hc.sublayer(p, "attn_hc_", X, attention, cfg)
@@ -193,7 +185,7 @@ def _decoder_layer_step(p, X, caches, layer, pos, cfg, dtype, expert: bool):
     def attention(h):
         with jax.named_scope(profiling.MLA):
             with jax.named_scope(profiling.MIXER_POINTWISE):
-                h = kv.rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
+                h = decoder.rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
             return kv.mla_step(p, h, caches, layer, pos, cfg, dtype)
 
     X, caches, attn = hc.sublayer(p, "attn_hc_", X, attention, cfg)
@@ -252,29 +244,16 @@ class XingActorCritic(nn.Module):
     @nn.compact
     def __call__(self, tokens, resets, carry):
         cfg, dtype = self.cfg, jnp.dtype(self.dtype)
-        H = cfg.hidden_size
-        w = nn.initializers.normal(_INIT_STD)
-        embedding = self.param("embedding", w, (cfg.vocab_size, H), _F32)
-        layers = [
-            moe.Params(tuple(layer_param_spec(cfg, i).items()),
-                       name=f"layer_{i}")()
-            for i in range(cfg.num_hidden_layers)
-        ]
-        final_norm = self.param(
-            "final_norm", nn.initializers.ones_init(), (H,), _F32
-        )
-        lm_head = self.param("lm_head", w, (H, cfg.vocab_size), _F32)
-        value_w = self.param("value_w", w, (H,), _F32)
-        value_b = self.param(
-            "value_b", nn.initializers.zeros_init(), (), _F32
+        head, layers = decoder.parameters(
+            self, cfg,
+            [layer_param_spec(cfg, i) for i in range(cfg.num_hidden_layers)],
+            nn.initializers.ones_init(),
         )
 
-        X = hc.expand(jnp.take(embedding, tokens.astype(jnp.int32), axis=0),
-                      cfg.hc_mult)
+        X = hc.expand(decoder.embed(head, tokens), cfg.hc_mult)
         expert_stats, mhc_stats = [], []
         if tokens.shape[0] == 1:
-            keep = 1.0 - resets[0].astype(_F32)
-            pos = (carry["pos"] * keep).astype(jnp.int32)
+            _, pos = decoder.step_position(carry, resets)
             X, caches = X[0], carry["layers"]
             for i, p in enumerate(layers):
                 X, caches, stats, counters = _decoder_layer_step(
@@ -298,26 +277,21 @@ class XingActorCritic(nn.Module):
                 X, stats, counters = layer(p, X)
                 expert_stats.append(stats)
                 mhc_stats.extend(counters)
-        x = hc.reduce(X, cfg.hc_mult)
-        with jax.named_scope(profiling.LM_HEAD):
-            h = kv.rms_norm(x, final_norm, cfg.rms_norm_eps)
-            logits = _mm(h, lm_head, dtype)
-        values = jnp.dot(h, value_w, precision=_HIGHEST) + value_b
+        h, logits = decoder.lm_head(
+            hc.reduce(X, cfg.hc_mult), head, cfg.rms_norm_eps, dtype
+        )
+        values = decoder.value(h, head)
         stats = moe.stack_layer_stats(
             [s for s in expert_stats if s is not None]
         )
         stats.update(_mhc_stats(mhc_stats))
         if tokens.shape[0] == 1:
-            stats[kv.CACHE_ROWS_READ] = kv._rows_read_share(
+            stats[kv.CACHE_ROWS_READ] = kv.rows_read_share(
                 carry["layers"], pos, cfg.kv_lora_rank
             )
         return logits, values, carry, stats
 
     def initialize_carry(self, batch: int) -> Dict[str, Any]:
         """The empty carry for ``batch`` environments."""
-        shape = (batch, self.cfg.num_hidden_layers, self.cache_len,
-                 self.cfg.cache_width)
-        return {
-            "layers": jnp.zeros(shape, jnp.dtype(self.dtype)),
-            "pos": jnp.zeros((batch,), jnp.int32),
-        }
+        return decoder.one_cache_carry(batch, self.cfg, self.cache_len,
+                                       self.dtype)

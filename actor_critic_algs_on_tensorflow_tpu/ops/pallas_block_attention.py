@@ -2,7 +2,7 @@
 trajectory under the block-diffusion mask, forward and backward, with
 the scores in VMEM from the first product to the last.
 
-``models/sdar.py::_attend`` is the same thing in plain array
+``models/decoder.py::attend`` is the same thing in plain array
 operations: float32 scores ``[b, heads, n, n]``, the mask, a float32
 softmax, the probabilities rounded to the products' dtype, the weighted
 sum. XLA writes the scores to HBM and every one of those steps, the
@@ -486,7 +486,7 @@ def block_attention(q, k, v, step, key_commit, dtype, *, tile_q=TILE_Q,
     hdv]`` (query head ``j`` reads key/value head ``j // (nh / nkv)``),
     ``step [n]`` (a position's pass, non-decreasing) and ``key_commit
     [b, n]`` -> ``[b, n, nh * hdv]`` in ``dtype``: what
-    ``models.sdar._attend`` returns under the dense mask ``step[k] ==
+    ``models.decoder.attend`` gives, heads merged, under the mask ``step[k] ==
     step[q] | (step[k] < step[q]) & key_commit[:, k]``, the products in
     ``dtype``, rounded to it; differentiable in ``q``, ``k`` and ``v``.
 

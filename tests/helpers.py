@@ -207,3 +207,40 @@ def chunk_matrices(lead, c, repeated, seed=0):
     a = jnp.einsum("...id,...jd->...ij", k * beta[..., None], k,
                    precision=jax.lax.Precision.HIGHEST)
     return a * decay * jnp.tril(lower, -1)
+
+
+def kernels_interpreted(monkeypatch, **sizes):
+    """Off the chip, every Pallas kernel a model chooses where its
+    operands fit, as a program lowered for a TPU takes it, the body in
+    the Pallas interpreter: ``ops/kernel_choice.py``'s one seam replaced.
+    ``sizes`` (``chunk=8``, ``tile_q=32``, ...) go to each function of
+    the Pallas module that takes them, ``fits`` among them."""
+    import functools
+    import importlib
+    import inspect
+
+    from actor_critic_algs_on_tensorflow_tpu.ops import kernel_choice
+
+    given = {"interpret": True, **sizes}
+
+    class Interpreted:
+        def __init__(self, module):
+            self._module = module
+
+        def __getattr__(self, name):
+            f = getattr(self._module, name)
+            if not inspect.isfunction(f):
+                return f
+            params = inspect.signature(f).parameters
+            anything = any(p.kind is p.VAR_KEYWORD for p in params.values())
+            return functools.partial(f, **{
+                k: v for k, v in given.items() if anything or k in params
+            })
+
+    def lowered_for(name):
+        module = importlib.import_module(f"{kernel_choice.__package__}.{name}")
+        return Interpreted(module), (
+            lambda kernel, plain, *operands: kernel(*operands)
+        )
+
+    monkeypatch.setattr(kernel_choice, "_lowered_for", lowered_for)

@@ -31,6 +31,7 @@ from actor_critic_algs_on_tensorflow_tpu.models import (  # noqa: E402
 )
 from perfbench.reference import granite_hybrid as ref  # noqa: E402
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
+from tests.helpers import kernels_interpreted  # noqa: E402
 
 TINY = PRESETS["ppo-granite-tiny"][1]
 CFG = TINY["seq_model"]
@@ -119,15 +120,12 @@ def interpreted_kernel(monkeypatch):
 
     kernel, traced = pallas_mamba_step.mamba_step, []
 
-    def interpreted(state, layer, *args):
+    def counted(state, layer, *args, **kwargs):
         traced.append(layer)
-        return kernel(state, layer, *args, interpret=True)
+        return kernel(state, layer, *args, **kwargs)
 
-    monkeypatch.setattr(pallas_mamba_step, "mamba_step", interpreted)
-    monkeypatch.setattr(
-        jax.lax, "platform_dependent",
-        lambda *args, tpu, default: tpu(*args),
-    )
+    monkeypatch.setattr(pallas_mamba_step, "mamba_step", counted)
+    kernels_interpreted(monkeypatch)
     return traced
 
 

@@ -28,9 +28,13 @@ from actor_critic_algs_on_tensorflow_tpu.models import (  # noqa: E402
 )
 from actor_critic_algs_on_tensorflow_tpu.models import kimi_vl as kv  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.models import xing  # noqa: E402
+from actor_critic_algs_on_tensorflow_tpu.models.decoder import (  # noqa: E402
+    rms_norm,
+)
 from actor_critic_algs_on_tensorflow_tpu.ops import (  # noqa: E402
     pallas_hyper_connections as kernels,
 )
+from tests.helpers import kernels_interpreted  # noqa: E402
 
 # the published numbers of the residual path, at a width for the CPU
 CFG = dataclasses.replace(xing.XingConfig(), hidden_size=16)
@@ -139,14 +143,13 @@ def _through_the_kernels(monkeypatch):
     their bodies in the interpreter; the names of the passes called."""
     calls = []
     for name in ("pre", "post"):
-        def interpreted(*args, _kernel=getattr(kernels, name), _name=name,
-                        **kwargs):
+        def counted(*args, _kernel=getattr(kernels, name), _name=name,
+                    **kwargs):
             calls.append(_name)
-            return _kernel(*args, interpret=True, **kwargs)
+            return _kernel(*args, **kwargs)
 
-        monkeypatch.setattr(kernels, name, interpreted)
-    monkeypatch.setattr(hc, "_kernel_or_plain",
-                        lambda kernel, plain, *operands: kernel(*operands))
+        monkeypatch.setattr(kernels, name, counted)
+    kernels_interpreted(monkeypatch)
     return calls
 
 
@@ -263,7 +266,7 @@ def test_one_stream_pinned_to_one_is_kimi_vls_residual_layer(
     ffn = lambda h: kv.feed_forward_branch(p, h, KIMI, jnp.float32, expert)
     if form == "sequence":
         attention = lambda h: (
-            kv.mla_seq(p, kv.rms_norm(h, p["input_norm"], eps), KIMI,
+            kv.mla_seq(p, rms_norm(h, p["input_norm"], eps), KIMI,
                        jnp.float32), None)
         X, _, _ = hc.sublayer(p, "", hc.expand(x, 1), attention, ONE)
         X, _, _ = hc.sublayer(p, "", X, ffn, ONE)
@@ -275,7 +278,7 @@ def test_one_stream_pinned_to_one_is_kimi_vls_residual_layer(
                                 KIMI.cache_width))
     pos = jnp.array([3, 5])
     attention = lambda h: kv.mla_step(
-        p, kv.rms_norm(h, p["input_norm"], eps), caches, layer, pos, KIMI,
+        p, rms_norm(h, p["input_norm"], eps), caches, layer, pos, KIMI,
         jnp.float32)
     X, got_caches, _ = hc.sublayer(p, "", hc.expand(x[0], 1), attention, ONE)
     X, _, _ = hc.sublayer(p, "", X, ffn, ONE)

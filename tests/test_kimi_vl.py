@@ -34,6 +34,7 @@ from actor_critic_algs_on_tensorflow_tpu.models import kimi_vl as kv  # noqa: E4
 from actor_critic_algs_on_tensorflow_tpu.ops import pallas_mla_step  # noqa: E402
 from perfbench.reference import kimi_vl as ref  # noqa: E402
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
+from tests.helpers import kernels_interpreted  # noqa: E402
 
 TINY = PRESETS["ppo-kimivl-tiny"][1]
 CFG = TINY["seq_model"]
@@ -146,25 +147,16 @@ def attention(request, monkeypatch):
     """The step form's scores, softmax and weighted sum as the CPU runs
     them, and once more through the TPU's one-pass kernel in the Pallas
     interpreter (what ``kv._latent_attention`` picks where the program
-    is lowered for a TPU at the published widths). Gives the cache's
-    length to build the model with."""
+    is lowered for a TPU), at a latent of 128, a lane tile, and the
+    kernel's blocks of ``BLOCK`` envs and ``CHUNK`` rows. Gives the
+    model's config and cache length."""
     if request.param == "plain":
-        return T
-
-    def kernel(q, caches, layer, pos, scale, rank, dtype):
-        return pallas_mla_step.latent_attention(
-            q, caches, layer, pos, scale=scale, rank=rank,
-            block_envs=BLOCK, chunk=CHUNK, interpret=True,
-        )
-
-    def rows_read(caches, pos, rank):
-        return pallas_mla_step.rows_read_share(
-            pos, caches.shape[2], block_envs=BLOCK, chunk=CHUNK
-        )
-
-    monkeypatch.setattr(kv, "_latent_attention", kernel)
-    monkeypatch.setattr(kv, "_rows_read_share", rows_read)
-    return CACHE_LEN
+        return {"cfg": CFG, "cache_len": T}
+    kernels_interpreted(monkeypatch, block_envs=BLOCK, chunk=CHUNK)
+    cfg = dataclasses.replace(CFG, kv_lora_rank=128)
+    caches = jnp.zeros((B, cfg.num_hidden_layers, CACHE_LEN, cfg.cache_width))
+    assert pallas_mla_step.fits(caches, cfg.kv_lora_rank, chunk=CHUNK)
+    return {"cfg": cfg, "cache_len": CACHE_LEN}
 
 
 def _stepwise(model, params, tokens, resets, stats=None):
@@ -185,7 +177,7 @@ def _stepwise(model, params, tokens, resets, stats=None):
 def test_stepping_through_the_cache_equals_the_sequence_pass(attention):
     """The absorbed form over the cache of latents, a token at a time,
     is the expanded causal pass: float32, tight."""
-    model = _model(cache_len=attention)
+    model = _model(**attention)
     params, tokens = _init(model), _tokens()
     seq_logits, seq_values, _, _ = model.apply(
         params, tokens, jnp.zeros((T, B)), None
@@ -199,15 +191,16 @@ def test_stepping_through_the_cache_equals_the_sequence_pass(attention):
     # a layer's cache: rows of latent + rope key an env, no more; the
     # layers' caches are one array
     # array, the envs leading as the trainer shards a carry
+    cfg = attention["cfg"]
     assert carry["layers"].shape == (
-        B, CFG.num_hidden_layers, attention,
-        CFG.kv_lora_rank + CFG.qk_rope_head_dim,
+        B, cfg.num_hidden_layers, attention["cache_len"],
+        cfg.kv_lora_rank + cfg.qk_rope_head_dim,
     )
 
 
 def test_a_reset_mid_way_is_a_fresh_start(attention):
     cut = 8
-    model = _model(cache_len=attention)
+    model = _model(**attention)
     params, tokens = _init(model), _tokens()
     resets = jnp.zeros((T, B)).at[cut, 1].set(1.0)
     logits, values, _ = _stepwise(model, params, tokens, resets)
@@ -233,7 +226,7 @@ def test_the_step_form_counts_the_rows_it_read(attention):
     index map fetches, at lock-step positions the chunks up to the one
     that holds ``pos`` over the cache's length; a reset env reads one
     chunk again, but its block still fetches what its neighbour needs."""
-    model = _model(cache_len=attention)
+    model = _model(**attention)
     params, tokens = _init(model, batch=4), _tokens(B=4)
     resets = jnp.zeros((T, 4)).at[10, 1].set(1.0).at[17, 2:].set(1.0)
     stats = []
@@ -242,7 +235,7 @@ def test_the_step_form_counts_the_rows_it_read(attention):
     assert all(0.0 < share <= 1.0 for share in shares)
     assert all(set(row) >= {kv.CACHE_ROWS_READ, "moe_overflow_pairs"}
                for row in stats)
-    if attention == T:
+    if attention["cache_len"] == T:
         assert shares == [1.0] * T
         return
     chunks = [t // CHUNK + 1 for t in range(T)]

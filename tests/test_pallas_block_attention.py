@@ -1,6 +1,6 @@
 """The sequence form's attention kernels (ops/pallas_block_attention.py)
 against the plain form they stand in for on the TPU
-(models/sdar.py::_attend under trajectory_mask's dense mask).
+(models/decoder.py::attend under trajectory_mask's dense mask).
 
 The suite runs on the CPU mesh, so every call passes ``interpret=True``:
 the interpreter is never picked from the backend. The kernels compiled
@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS
-from actor_critic_algs_on_tensorflow_tpu.models import sdar
+from actor_critic_algs_on_tensorflow_tpu.models import decoder, sdar
 from actor_critic_algs_on_tensorflow_tpu.ops import (
     pallas_block_attention as pba,
 )
@@ -21,6 +21,14 @@ B, NH, NKV, HD = 2, 4, 2, 128
 # a turn of ppo-sdar-turns: the env's block committed, four denoising
 # passes over the policy's, its commit
 TURN = [True, False, False, False, False, True]
+
+
+def _attend(q, k, v, visible, dtype):
+    """The plain form, its heads laid out as the kernels give them:
+    ``[b, n, nh * hd]``."""
+    return decoder.attend(q, k, v, visible, dtype).reshape(
+        q.shape[:2] + (-1,)
+    )
 
 
 def _commits(pattern, T):
@@ -50,7 +58,7 @@ def _both(commit, block, dtype, tile_q, chunk):
     _, visible = sdar.trajectory_mask(commit, block)
 
     def plain(q, k, v):
-        return sdar._attend(q, k, v, visible, dtype)
+        return _attend(q, k, v, visible, dtype)
 
     def kernel(q, k, v):
         return pba.block_attention(
@@ -133,13 +141,13 @@ def test_the_causal_case_is_a_block_of_one_and_every_pass_a_commit():
     )
     causal = jnp.broadcast_to(jnp.tril(jnp.ones((n, n), bool)), (B, n, n))
     np.testing.assert_allclose(
-        got, sdar._attend(q, k, v, causal, jnp.float32), atol=1e-5
+        got, _attend(q, k, v, causal, jnp.float32), atol=1e-5
     )
 
 
 def test_values_may_be_wider_than_keys():
     """Latent attention's expanded form has values of another width
-    than its keys; ``sdar._attend`` has not, so the plain form is
+    than its keys; ``decoder.attend`` has not, so the plain form is
     written out."""
     T, block, tile_q, chunk = SHAPES["padded"]
     q, k, v, w = _inputs(T * block, hdv=2 * HD, seed=3)

@@ -182,7 +182,7 @@ def test_only_the_published_widths_take_the_kernel():
         q, caches, 1, pos, 1.0, cfg.kv_lora_rank, jnp.float32
     )
     assert plain.shape == (2, cfg.num_attention_heads, cfg.kv_lora_rank)
-    assert float(kv._rows_read_share(caches, pos, cfg.kv_lora_rank)) == 1.0
+    assert float(kv.rows_read_share(caches, pos, cfg.kv_lora_rank)) == 1.0
     with pytest.raises(ValueError, match="whole chunks"):
         pallas_mla_step.latent_attention(
             q, caches, 1, pos, scale=1.0, rank=cfg.kv_lora_rank, chunk=7,

@@ -7,7 +7,6 @@ entry points (``collect``, ``block_grads``).
 """
 
 import dataclasses
-import functools
 import os
 import sys
 
@@ -28,12 +27,9 @@ from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.cli.train import PRESETS  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.models import qwen3_next as qn  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.ops import pallas_delta_step  # noqa: E402
-from actor_critic_algs_on_tensorflow_tpu.ops import (  # noqa: E402
-    pallas_unit_lower_inverse,
-)
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
 from perfbench.reference import qwen3_next as ref  # noqa: E402
-from tests.helpers import chunk_matrices  # noqa: E402
+from tests.helpers import chunk_matrices, kernels_interpreted  # noqa: E402
 
 TINY = PRESETS["ppo-qwen3next-tiny"][1]
 CFG = TINY["seq_model"]
@@ -145,15 +141,20 @@ def test_each_step_below_the_stated_precision_is_another_function(lower):
 
 @pytest.fixture(params=["plain", "kernel"])
 def state_step(request, monkeypatch):
-    """The step form's state update as the CPU runs it, and once more
-    through the TPU's one-pass kernel in the Pallas interpreter (what
-    ``qn._state_step`` picks where the program is lowered for a TPU at
-    the published widths)."""
-    if request.param == "kernel":
-        monkeypatch.setattr(qn, "_state_step", functools.partial(
-            pallas_delta_step.gated_delta_step, interpret=True
-        ))
-    return request.param
+    """The config to build the model with: the step form's state update
+    as the CPU runs it, and once more through the TPU's one-pass kernel
+    in the Pallas interpreter (what ``qn._state_step`` picks where the
+    program is lowered for a TPU), at value heads of 128, a state that
+    tiles the vector unit."""
+    if request.param == "plain":
+        return CFG
+    kernels_interpreted(monkeypatch)
+    cfg = dataclasses.replace(CFG, linear_value_head_dim=128)
+    assert pallas_delta_step.fits(jnp.zeros((
+        1, cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+        cfg.linear_value_head_dim,
+    )))
+    return cfg
 
 
 def _stepwise(model, params, tokens, resets):
@@ -173,7 +174,7 @@ def test_stepping_through_the_carry_equals_the_sequence_pass(state_step):
     """DeltaNet state, convolution tail, key/value cache and rotary
     position, over two chunks and a ragged tail."""
     T, B = 2 * CHUNK + 3, 3
-    model = _model()
+    model = _model(cfg=state_step)
     params, tokens = _init(model), _tokens(T, B)
     seq_logits, seq_values, _, _ = model.apply(
         params, tokens, jnp.zeros((T, B)), None
@@ -188,7 +189,7 @@ def test_stepping_through_the_carry_equals_the_sequence_pass(state_step):
 
 def test_a_reset_mid_way_is_a_fresh_start(state_step):
     T, B, cut = 2 * CHUNK + 3, 3, CHUNK + 2
-    model = _model()
+    model = _model(cfg=state_step)
     params, tokens = _init(model), _tokens(T, B)
     resets = jnp.zeros((T, B)).at[cut, 1].set(1.0)
     logits, values, _ = _stepwise(model, params, tokens, resets)
@@ -286,10 +287,10 @@ def test_the_chunked_deltanet_through_the_kernel_equals_the_recurrence(
     """The chunked rule with its inverse taken by the TPU's kernel (in the
     Pallas interpreter): 2 chunks x 2 envs x 64 heads are two lane tiles
     of matrices. Forward, final state and gradient."""
-    monkeypatch.setattr(qn, "_unit_lower_inverse", functools.partial(
-        pallas_unit_lower_inverse.inverse, interpret=True
-    ))
+    kernels_interpreted(monkeypatch)
     args = _delta_inputs(16, b=2, h=64)
+    _, _, share = qn._chunk_rule(*args, chunk=8)
+    assert float(share) == 1.0
     o, S = qn.chunk_gated_delta_rule(*args, chunk=8)
     o_ref, S_ref = _recurrence(*args)
     np.testing.assert_allclose(o, o_ref, atol=2e-5)

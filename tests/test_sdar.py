@@ -30,6 +30,7 @@ from actor_critic_algs_on_tensorflow_tpu.models import sdar  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.ops import BlockReveal  # noqa: E402
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
 from perfbench.reference import sdar as ref  # noqa: E402
+from tests.helpers import kernels_interpreted  # noqa: E402
 
 TINY = PRESETS["ppo-sdar-tiny"][1]
 CFG = TINY["seq_model"]
@@ -430,17 +431,9 @@ def test_the_sequence_form_through_the_kernels_is_the_plain_form(
     """``SDARActorCritic``'s sequence form at a head of 128 (what the
     kernels take; the preset's 16 goes to the plain form) through
     ``ops/pallas_block_attention.py`` in the Pallas interpreter, as
-    ``sdar._kernel_or_plain`` picks it where the program is lowered for
-    a TPU: the plain form's logits and values, its gradients under the
-    layers' ``jax.checkpoint``, and a counter that says what was
-    skipped."""
-    import functools
-    import types
-
-    from actor_critic_algs_on_tensorflow_tpu.ops import (
-        pallas_block_attention as pba,
-    )
-
+    the model picks it where the program is lowered for a TPU: the
+    plain form's logits and values, its gradients under the layers'
+    ``jax.checkpoint``, and a counter that says what was skipped."""
     model = _model(cfg=dataclasses.replace(CFG, head_dim=128))
     params, tokens = _init(model), trajectory
     weights = jax.random.normal(
@@ -458,21 +451,8 @@ def test_the_sequence_form_through_the_kernels_is_the_plain_form(
     (_, (logits, values, stats)), grads = run(params)
     assert float(stats[sdar.SCORE_TILES_COMPUTED]) == 1.0
 
-    sizes = dict(tile_q=32, chunk=32)  # 3 x 3 tiles over 96 positions
-    interpreted = types.SimpleNamespace(
-        block_attention=functools.partial(
-            pba.block_attention, interpret=True, **sizes
-        ),
-        score_tiles_computed_share=functools.partial(
-            pba.score_tiles_computed_share, **sizes
-        ),
-    )
-
-    def kernels(q, k, v, kernel, plain, *operands):
-        assert pba.fits(q, k, v)
-        return kernel(interpreted, *operands)
-
-    monkeypatch.setattr(sdar, "_kernel_or_plain", kernels)
+    # 3 x 3 tiles over 96 positions
+    kernels_interpreted(monkeypatch, tile_q=32, chunk=32)
     (_, (k_logits, k_values, k_stats)), k_grads = run(params)
     assert float(k_stats[sdar.SCORE_TILES_COMPUTED]) == pytest.approx(6 / 9)
     np.testing.assert_allclose(

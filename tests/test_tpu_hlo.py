@@ -622,6 +622,7 @@ def test_the_update_attention_keeps_its_scores_off_the_chips_memory(one_chip):
     import jax.numpy as jnp
 
     from actor_critic_algs_on_tensorflow_tpu.models import sdar
+    from actor_critic_algs_on_tensorflow_tpu.models.decoder import rms_norm
 
     cfg = PRESETS["ppo-sdar-turns"][1]["seq_model"]
     b, T, L, H = 16, 144, cfg.block_length, cfg.hidden_size
@@ -640,7 +641,7 @@ def test_the_update_attention_keeps_its_scores_off_the_chips_memory(one_chip):
         positions, step, key_commit = sdar.trajectory_steps(commit, L)
 
         def mixer(p, x):
-            h = sdar.rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+            h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
             return x + sdar.gqa_seq(
                 p, h, positions, step, key_commit, cfg, jnp.bfloat16
             )

@@ -33,6 +33,9 @@ from actor_critic_algs_on_tensorflow_tpu.models import (  # noqa: E402
 )
 from actor_critic_algs_on_tensorflow_tpu.models import kimi_vl as kv  # noqa: E402
 from actor_critic_algs_on_tensorflow_tpu.models import xing  # noqa: E402
+from actor_critic_algs_on_tensorflow_tpu.models.decoder import (  # noqa: E402
+    rms_norm,
+)
 from perfbench.reference import ppo_loss as ref_ppo  # noqa: E402
 from perfbench.reference import xing as ref  # noqa: E402
 
@@ -267,7 +270,7 @@ def test_the_shares_add_up_through_the_residual_mix():
             X.shape)
 
     def normed(h):
-        return kv.rms_norm(h, params["post_norm"], CFG.rms_norm_eps).reshape(
+        return rms_norm(h, params["post_norm"], CFG.rms_norm_eps).reshape(
             -1, CFG.hidden_size)
 
     base = sublayer(lambda h: (jnp.zeros_like(h), None))        # H_res X
