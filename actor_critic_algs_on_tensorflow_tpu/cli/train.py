@@ -109,6 +109,12 @@ def _xing_config(**kw):
     return XingConfig(**kw)
 
 
+def _lfm2_config(**kw):
+    from actor_critic_algs_on_tensorflow_tpu.models.lfm2 import Lfm2Config
+
+    return Lfm2Config(**kw)
+
+
 def _snapshot_fits(fns, cfg) -> bool:
     """Whether a device has room for the sentinel's rollback target, a
     second copy of the train state: three times the state within the
@@ -712,6 +718,73 @@ PRESETS = {
                 num_experts_per_tok=2, kv_lora_rank=32, q_lora_rank=16,
                 qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16,
                 hc_sinkhorn_iters=3, vocab_size=64, first_expert=0,
+                experts_held=4, capacity_factor=4.0,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 8,
+            "rollout_length": 16,
+            "total_env_steps": 4_096,
+            "num_devices": 1,
+        },
+    ),
+    # 18. Token-level PPO with LFM2-24B-A2B as the policy: double-gated
+    # short convolutions as most of the mixers, one grouped-query
+    # attention layer with per-head query/key norms in every four, and
+    # sigmoid-routed experts after both kinds, at the published widths,
+    # cut to one chip's share of a stated deployment: each expert layer
+    # shared by 8 chips, expert-parallel (8 of the 64 experts here, the
+    # whole router and every mixer), published layer 0 (a convolution
+    # and the dense SwiGLU; the two leading dense layers count once) and
+    # layers 2-5 (one whole period: attention and three convolutions,
+    # the rest on further chips as pipeline stages) and 1/8 of the tied
+    # vocabulary: 469.3 M parameters, 7.5 GB with gradients and Adam's
+    # moments. One episode is one sequence of 512 tokens on the
+    # token-recall env; the schedule is
+    # perfbench/traffic/recall-128x512-e1mb8-lfm2.json's.
+    "ppo-lfm2-recall": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=8_192, delay=64, episode_length=512
+            ),
+            "torso": "lfm2",
+            "seq_model": _lfm2_config(
+                num_hidden_layers=5, num_dense_layers=1,
+                layer_types=("conv", "full_attention", "conv", "conv",
+                             "conv"),
+                vocab_size=8_192, first_expert=0, experts_held=8,
+                capacity_factor=2.0,
+            ),
+            **_PPO_TOKEN_SCHEDULE,
+            "num_envs": 128,
+            "rollout_length": 512,
+            "num_minibatches": 8,
+            "compute_dtype": "bfloat16",
+            "total_env_steps": 10_000_000,
+        },
+    ),
+    # The same model and schedule at widths for the CPU tests: hidden
+    # 64, convolutions of 3 taps, 4 query / 2 key-value heads of 16, a
+    # dense layer of width 128, then 4 expert layers of 8 experts top-2
+    # of width 32 (4 held), vocabulary 64; the cell's layer kinds and
+    # order.
+    "ppo-lfm2-tiny": (
+        "ppo",
+        {
+            "env": "TokenRecallTPU-v0",
+            "env_params": _token_recall_params(
+                vocab_size=64, delay=4, episode_length=16
+            ),
+            "torso": "lfm2",
+            "seq_model": _lfm2_config(
+                hidden_size=64, intermediate_size=128,
+                moe_intermediate_size=32, num_hidden_layers=5,
+                num_dense_layers=1,
+                layer_types=("conv", "full_attention", "conv", "conv",
+                             "conv"),
+                num_attention_heads=4, num_key_value_heads=2, num_experts=8,
+                num_experts_per_tok=2, vocab_size=64, first_expert=0,
                 experts_held=4, capacity_factor=4.0,
             ),
             **_PPO_TOKEN_SCHEDULE,

@@ -28,6 +28,7 @@ SEQUENCE_CORES = {
     "granite_hybrid": ("granite_hybrid", "GraniteHybridActorCritic",
                        "GraniteHybridConfig"),
     "xing": ("xing", "XingActorCritic", "XingConfig"),
+    "lfm2": ("lfm2", "Lfm2ActorCritic", "Lfm2Config"),
 }
 
 

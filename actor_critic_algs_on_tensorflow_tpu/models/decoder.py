@@ -1,7 +1,8 @@
 """The pieces the sequence cores build their decoders from, written once:
 the parameter-group module, the matrix products, the SwiGLU, the
 RMSNorm, the rotate-half rotary embedding, grouped-query softmax
-attention, and the actor-critic shell around a core's layers (the head
+attention (causal over a sequence, or one token against a cache), and
+the actor-critic shell around a core's layers (the head
 parameters, the step form's preamble, the policy and value heads).
 
 Each core keeps what is its own: its mixers, its layer body, its layouts
@@ -114,6 +115,33 @@ def attend(q, k, v, visible, dtype):
     scores = jnp.where(visible[:, None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     return dot("bkgqs,bskd->bqkgd", probs, v, dtype)
+
+
+def causal_attend(q, k, v, dtype):
+    """``attend`` over a whole sequence, causal: ``q [b, T, nh, hd]``,
+    ``k, v [b, T, nkv, hd]`` -> ``[b, T, nh * hd]``."""
+    b, T = q.shape[:2]
+    visible = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (b, T, T))
+    return attend(q, k, v, visible, dtype).reshape(b, T, -1)
+
+
+def cached_attend(q, k, v, keys, values, layer, pos, dtype):
+    """One token an env against a key/value cache: ``q [B, 1, nh,
+    hd]``, ``k, v [B, 1, nkv, hd]`` written at ``pos [B]`` into ``keys``
+    and ``values [B, layers, L, nkv, hd]``'s attention layer ``layer`` (a
+    Python int), in place; rows beyond ``pos`` are masked. -> ``(out [B,
+    1, nh * hd], keys, values)``."""
+    B = q.shape[0]
+    at = (jnp.arange(B), layer, pos)
+    keys = keys.at[at].set(k[:, 0].astype(keys.dtype), unique_indices=True)
+    values = values.at[at].set(
+        v[:, 0].astype(values.dtype), unique_indices=True
+    )
+    visible = (jnp.arange(keys.shape[2])[None, :] <= pos[:, None])
+    out = attend(
+        q, keys[:, layer], values[:, layer], visible[:, None], dtype
+    ).reshape(B, 1, -1)
+    return out, keys, values
 
 
 # ---- the actor-critic shell ------------------------------------------------

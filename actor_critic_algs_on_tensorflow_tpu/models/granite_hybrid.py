@@ -453,11 +453,9 @@ def _project(p, x, cfg, dtype):
 
 def gqa_seq(p, x, cfg, dtype):
     """``x [b, T, H]``, causal over the sequence."""
-    b, T, _ = x.shape
     q, k, v = _project(p, x, cfg, dtype)
     with jax.named_scope(profiling.MIXER_CORE):
-        visible = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (b, T, T))
-        out = decoder.attend(q, k, v, visible, dtype).reshape(b, T, -1)
+        out = decoder.causal_attend(q, k, v, dtype)
     with jax.named_scope(profiling.MIXER_PROJ):
         return _mm(out, p["o_proj"], dtype)
 
@@ -467,20 +465,11 @@ def gqa_step(p, x, keys, values, layer, pos, cfg, dtype):
     ``values [B, layers, L, nkv, hd]`` of which this is attention layer
     ``layer`` (a Python int), ``pos [B]``. The new key and value are
     written at ``pos``, in place; rows beyond it are masked."""
-    B = x.shape[0]
     q, k, v = _project(p, x[:, None], cfg, dtype)
     with jax.named_scope(profiling.MIXER_CORE):
-        at = (jnp.arange(B), layer, pos)
-        keys = keys.at[at].set(
-            k[:, 0].astype(keys.dtype), unique_indices=True
+        out, keys, values = decoder.cached_attend(
+            q, k, v, keys, values, layer, pos, dtype
         )
-        values = values.at[at].set(
-            v[:, 0].astype(values.dtype), unique_indices=True
-        )
-        visible = (jnp.arange(keys.shape[2])[None, :] <= pos[:, None])
-        out = decoder.attend(
-            q, keys[:, layer], values[:, layer], visible[:, None], dtype
-        ).reshape(B, 1, -1)
     with jax.named_scope(profiling.MIXER_PROJ):
         return _mm(out[:, 0], p["o_proj"], dtype), keys, values
 

@@ -101,7 +101,6 @@ from actor_critic_algs_on_tensorflow_tpu.ops import kernel_choice
 from actor_critic_algs_on_tensorflow_tpu.utils import profiling
 
 _F32 = jnp.float32
-_HIGHEST = jax.lax.Precision.HIGHEST
 # The step form's counter: the share of a cache's rows a step read, the
 # same in every layer (1 where the plain form ran).
 CACHE_ROWS_READ = "mla_cache_rows_read_share"
@@ -433,14 +432,10 @@ def route(p, x, cfg):
     """``x [N, H]`` -> the top-k experts ``[N, k]`` of ALL
     ``n_routed_experts`` and their weights, float32 throughout: the
     bias chooses, the scores weigh."""
-    logits = jnp.dot(x.astype(_F32), p["router"], precision=_HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    bias = jax.lax.stop_gradient(p["e_score_correction_bias"])
-    _, experts = jax.lax.top_k(scores + bias, cfg.num_experts_per_tok)
-    weights = jnp.take_along_axis(scores, experts, -1)
-    if cfg.norm_topk_prob:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
-    return experts, weights * cfg.routed_scaling_factor
+    return moe.route_sigmoid_top_k(
+        p, x, p["e_score_correction_bias"], cfg.num_experts_per_tok,
+        cfg.norm_topk_prob, cfg.routed_scaling_factor, 1e-20,
+    )
 
 
 def routed_experts(p, x, cfg: KimiVLConfig, dtype):

@@ -1,9 +1,10 @@
 """The expert layer the sequence cores share (``models/qwen3_next.py``,
-``models/kimi_vl.py``, ``models/sdar.py``, ``models/xing.py``): a
-chip's share of a routed mixture of SwiGLU experts, dropless within one
-dispatch buffer, with its counters, and the softmax-top-k routing two of
-them bind. The pieces every core builds its layers from are
-``models/decoder.py``'s.
+``models/kimi_vl.py``, ``models/sdar.py``, ``models/xing.py``,
+``models/lfm2.py``): a chip's share of a routed mixture of SwiGLU
+experts, dropless within one dispatch buffer, with its counters, and
+the two routing functions the cores bind (softmax top-k; sigmoid top-k
+with a selection bias). The pieces every core builds its layers from
+are ``models/decoder.py``'s.
 
 The layer is told which experts it holds (``ExpertSpec``:
 ``first_expert``, ``experts_held`` of ``num_experts``). It routes over
@@ -26,9 +27,10 @@ that hold a pair is ``stats["moe_buffer_fill_share"]`` and the rung's
 rows over the capacity ``stats["moe_buffer_rows_used_share"]``. Each
 model keeps its routing function
 and its shared branch (Qwen3-Next: softmax scores, a sigmoid-gated
-shared expert; Kimi-VL: sigmoid scores with a selection bias, an
-ungated one; SDAR: Qwen3-Next's routing, ``route_softmax_top_k``, no
-shared expert).
+shared expert; Kimi-VL and Xing: sigmoid scores with a selection bias,
+``route_sigmoid_top_k``, an ungated one; SDAR: Qwen3-Next's routing,
+``route_softmax_top_k``, no shared expert; LFM2: the sigmoid routing
+with its own epsilon, no shared expert).
 """
 
 from __future__ import annotations
@@ -90,6 +92,23 @@ def route_softmax_top_k(p, x, top_k: int, renormalise: bool):
     if renormalise:
         weights = weights / jnp.sum(weights, -1, keepdims=True)
     return experts, weights
+
+
+def route_sigmoid_top_k(p, x, bias, top_k: int, renormalise: bool,
+                        scale: float, eps: float):
+    """``x [N, H]`` -> the ``top_k`` experts ``[N, k]`` of ALL the
+    router's outputs by sigmoid score plus the selection bias ``bias
+    [E]`` (which no gradient reaches: it enters the selection only) and
+    their weights, the scores WITHOUT the bias, divided by their sum +
+    ``eps`` where ``renormalise``, times ``scale``; float32 throughout."""
+    logits = jnp.dot(x.astype(_F32), p["router"], precision=_HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    bias = jax.lax.stop_gradient(bias)
+    _, experts = jax.lax.top_k(scores + bias, top_k)
+    weights = jnp.take_along_axis(scores, experts, -1)
+    if renormalise:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + eps)
+    return experts, weights * scale
 
 
 def _buffer_at(rows, spec, dtype, x, w_gate, w_up, w_down, weights,

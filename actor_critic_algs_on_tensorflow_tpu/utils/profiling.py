@@ -11,9 +11,9 @@
   of the unscoped build.
 - ``LAYER_SCOPES``: the sequence cores' layers, named the same way
   inside ``policy_act`` and ``loss_grad``: a mixer (``gdn``,
-  ``gated_attn``, ``mla``, ``gqa``, ``mamba``), inside it exactly one of
-  ``mixer_proj`` / ``mixer_pointwise`` / ``mixer_core`` over every
-  instruction (projections; norms, rotary, gates, convolution,
+  ``gated_attn``, ``mla``, ``gqa``, ``mamba``, ``shortconv``), inside it
+  exactly one of ``mixer_proj`` / ``mixer_pointwise`` / ``mixer_core``
+  over every instruction (projections; norms, rotary, gates, convolution,
   reshapes; scores to weighted sum or the delta rule, with the cache or
   state write), inside the core its forms (``gdn_state``,
   ``gdn_chunk_solve``, ``gdn_chunk_products``, ``mla_seq_attend``,
@@ -61,7 +61,7 @@ LOSS_GRAD = "loss_grad"            # in update: forward + backward
 OPTIMIZER = "optimizer"            # in update: all-reduce + Adam
 # Layers of the sequence-policy cores (models/qwen3_next.py,
 # models/kimi_vl.py, models/sdar.py, models/granite_hybrid.py,
-# models/xing.py; the
+# models/xing.py, models/lfm2.py; the
 # expert block is models/moe.py's), inside policy_act and loss_grad; read like the
 # phases, listed apart.
 GDN = "gdn"                        # a Gated DeltaNet mixer
@@ -80,8 +80,8 @@ GQA = "gqa"                        # a grouped-query mixer, both forms
 GQA_BLOCK_STEP = "gqa_block_step"  # in gqa: a block's pass over the cache
 GQA_SEQ_ATTEND = "gqa_seq_attend"  # in gqa: the sequence form less projections
 # One level down, the same three names in every mixer (gdn, gated_attn,
-# mla, gqa, mamba), both forms, no layer index: every instruction traced under
-# a mixer's scope is under exactly one of them.
+# mla, gqa, mamba, shortconv), both forms, no layer index: every
+# instruction traced under a mixer's scope is under exactly one of them.
 MIXER_PROJ = "mixer_proj"          # the linear projections in and out
 MIXER_POINTWISE = "mixer_pointwise"  # norms, rotary, gates, conv, reshapes
 MIXER_CORE = "mixer_core"          # scores to weighted sum, or the delta rule
@@ -95,13 +95,15 @@ MAMBA_STATE = "mamba_state"        # in mamba's core: the step form's update
 MAMBA_CHUNK_SCAN = "mamba_chunk_scan"  # in mamba's core: the chunked scan
 MHC = "mhc"                        # a sublayer's hyper-connections, less F
 MHC_SINKHORN = "mhc_sinkhorn"      # in mhc: the Sinkhorn iterations
+SHORTCONV = "shortconv"            # a gated short-convolution mixer, both forms
 LAYER_SCOPES = (GDN, GDN_STATE, GATED_ATTN, MOE, MOE_ROUTER, MOE_DISPATCH,
                 MOE_EXPERTS, MOE_SHARED, LM_HEAD, MLA, MLA_ABSORBED,
                 DENSE_MLP, GQA, GQA_BLOCK_STEP, GQA_SEQ_ATTEND,
                 MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE, MLA_SEQ_ATTEND,
                 GDN_CHUNK_SOLVE, GDN_CHUNK_PRODUCTS, MOE_COMBINE, SAMPLE,
-                MAMBA, MAMBA_STATE, MAMBA_CHUNK_SCAN, MHC, MHC_SINKHORN)
-MIXER_SCOPES = (GDN, GATED_ATTN, MLA, GQA, MAMBA)
+                MAMBA, MAMBA_STATE, MAMBA_CHUNK_SCAN, MHC, MHC_SINKHORN,
+                SHORTCONV)
+MIXER_SCOPES = (GDN, GATED_ATTN, MLA, GQA, MAMBA, SHORTCONV)
 MIXER_PARTS = (MIXER_PROJ, MIXER_POINTWISE, MIXER_CORE)
 PHASES = (ROLLOUT, POLICY_ACT, ENV_STEP, ADVANTAGE, UPDATE,
           MINIBATCH_PREP, LOSS_GRAD, OPTIMIZER)

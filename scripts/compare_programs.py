@@ -37,7 +37,7 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = ("ppo-qwen3next-tiny", "ppo-kimivl-tiny", "ppo-sdar-tiny",
-        "ppo-granite-tiny", "ppo-xing-tiny")
+        "ppo-granite-tiny", "ppo-xing-tiny", "ppo-lfm2-tiny")
 # A Mosaic kernel's serialised module; in MLIR text with debug
 # information, a named location's definition and an operation's
 # reference to its location.

@@ -706,3 +706,59 @@ def test_the_xing_presets_resolve_and_need_no_rollback_copy(monkeypatch):
     )
     monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [chip])
     assert not cli._snapshot_fits(fns, cfg)
+
+
+def test_the_lfm2_presets_resolve_and_need_no_rollback_copy(monkeypatch):
+    """Both LFM2 presets are PPO configs whose env and model agree on
+    the vocabulary; one episode is one rollout, longer than the
+    convolutions' reach; the cell holds published layer 0 and layers
+    2-5 (conv, attention, three conv), the first dense and four expert
+    layers of 8 of 64 experts; its carry is the convolutions' float32
+    tails and one attention layer's cache, the env axis first; and its
+    train state (469 M parameters with Adam's moments, 7.0 GiB) does not
+    fit a 16 GB chip beside a rollback copy of itself."""
+    import types
+
+    import jax
+
+    from actor_critic_algs_on_tensorflow_tpu.algos.ppo import (
+        PPOConfig,
+        make_ppo,
+    )
+
+    for name in ("ppo-lfm2-recall", "ppo-lfm2-tiny"):
+        algo, base = cli.PRESETS[name]
+        cfg = PPOConfig(**dict(base, num_devices=1))
+        env, model = cfg.env_params, cfg.seq_model
+        assert algo == "ppo" and cfg.torso == "lfm2"
+        assert cfg.recurrent and env.episode_length == cfg.rollout_length
+        assert env.vocab_size == model.vocab_size
+        assert env.delay > model.conv_L_cache - 1  # beyond the taps' reach
+        assert model.layer_types == ("conv", "full_attention", "conv",
+                                     "conv", "conv")
+        assert [model.is_expert_layer(i) for i in range(5)] == (
+            [False] + [True] * 4)
+    fns = make_ppo(cfg)  # the tiny one
+    assert cli._snapshot_fits(fns, cfg)  # the CPU reports no capacity
+    algo, base = cli.PRESETS["ppo-lfm2-recall"]
+    cfg = PPOConfig(**dict(base, num_devices=1))
+    assert (cfg.num_envs, cfg.rollout_length, cfg.num_minibatches) == (
+        128, 512, 8)
+    assert cfg.seq_model.hidden_size == 2048
+    assert cfg.seq_model.num_experts == 64
+    assert cfg.seq_model.experts_held == 8
+    fns = make_ppo(cfg)
+    state = jax.eval_shape(fns.init, jax.random.PRNGKey(0))
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
+    assert n_params == 469_287_297
+    core = state.carry["core"]
+    assert core["conv"].shape == (128, 4, 2, 2048)
+    assert core["conv"].dtype == jax.numpy.float32
+    assert core["k"].shape == core["v"].shape == (128, 1, 512, 8, 64)
+    assert core["k"].dtype == jax.numpy.bfloat16
+    assert state.obs.shape == (128,)
+    chip = types.SimpleNamespace(
+        memory_stats=lambda: {"bytes_limit": int(15.75 * 2**30)}
+    )
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [chip])
+    assert not cli._snapshot_fits(fns, cfg)

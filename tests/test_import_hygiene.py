@@ -23,7 +23,8 @@ import sys
 # block-diffusion core and its env among them, are imported by now
 for module in ("models.qwen3_next", "models.kimi_vl", "models.sdar",
                "models.granite_hybrid", "models.xing",
-               "models.hyper_connections", "envs.block_turns"):
+               "models.hyper_connections", "models.lfm2",
+               "envs.block_turns"):
     assert "actor_critic_algs_on_tensorflow_tpu." + module in sys.modules
 # Behavioral probe (public API only): selecting a platform after the
 # package import only takes effect while the backend is still

@@ -98,10 +98,11 @@ HEADS = {
     # the logits read the tied embedding
     "granite_hybrid": ("embedding", "final_norm", "value_w", "value_b"),
     "xing": ("embedding", "final_norm", "lm_head", "value_w", "value_b"),
+    "lfm2": ("embedding", "final_norm", "value_w", "value_b"),
 }
 TINY = {"qwen3_next": "ppo-qwen3next-tiny", "kimi_vl": "ppo-kimivl-tiny",
         "sdar": "ppo-sdar-tiny", "granite_hybrid": "ppo-granite-tiny",
-        "xing": "ppo-xing-tiny"}
+        "xing": "ppo-xing-tiny", "lfm2": "ppo-lfm2-tiny"}
 
 
 @pytest.mark.parametrize("torso", sorted(models.SEQUENCE_CORES))

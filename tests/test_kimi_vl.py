@@ -546,7 +546,7 @@ def test_the_carry_is_sharded_by_env_on_two_devices():
 
 def test_the_sequence_cores_are_a_table():
     assert set(models.SEQUENCE_CORES) == {
-        "qwen3_next", "kimi_vl", "sdar", "granite_hybrid", "xing"
+        "qwen3_next", "kimi_vl", "sdar", "granite_hybrid", "xing", "lfm2"
     }
     for torso in models.SEQUENCE_CORES:
         core, config = models.sequence_core(torso)
@@ -566,7 +566,8 @@ def test_the_sequence_cores_are_a_table():
 def test_refusals_name_the_table_and_not_a_model(torso):
     presets = {"qwen3_next": "ppo-qwen3next-tiny",
                "kimi_vl": "ppo-kimivl-tiny", "sdar": "ppo-sdar-tiny",
-               "granite_hybrid": "ppo-granite-tiny", "xing": "ppo-xing-tiny"}
+               "granite_hybrid": "ppo-granite-tiny", "xing": "ppo-xing-tiny",
+               "lfm2": "ppo-lfm2-tiny"}
     preset = presets[torso]
     tiny = PRESETS[preset][1]
     with pytest.raises(ValueError, match="episode_length"):

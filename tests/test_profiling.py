@@ -225,6 +225,7 @@ SEQUENCE_PRESETS = {
     "sdar": "ppo-sdar-tiny",
     "granite_hybrid": "ppo-granite-tiny",
     "xing": "ppo-xing-tiny",
+    "lfm2": "ppo-lfm2-tiny",
 }
 # The cores with no expert layer: no moe_* scope is looked for there.
 DENSE_CORES = {"granite_hybrid"}
@@ -372,6 +373,34 @@ def test_the_xing_iteration_carries_its_layer_scopes():
         assert any(scope in p and POLICY_ACT in p for p in lists)
 
 
+def test_the_lfm2_iteration_carries_its_layer_scopes():
+    """The layers ``models/lfm2.py`` names — the short-convolution mixer
+    and the grouped-query one, the leading dense layer, the expert
+    block's three without a shared expert — in the compiled text of the
+    tiny preset's fused iteration: ``shortconv`` holds no projection or
+    pointwise work of attention's, no core (its convolution is
+    pointwise work) and neither the expert block nor the dense layer."""
+    from actor_critic_algs_on_tensorflow_tpu.utils.profiling import (
+        DENSE_MLP, GQA, LM_HEAD, MIXER_CORE, MOE, MOE_DISPATCH, MOE_EXPERTS,
+        MOE_ROUTER, MOE_SHARED, SHORTCONV,
+    )
+
+    assert SHORTCONV in profiling.LAYER_SCOPES
+    assert SHORTCONV in profiling.MIXER_SCOPES
+    lists = _phase_lists("lfm2")
+    found = {phase for p in lists for phase in p}
+    assert {SHORTCONV, GQA, DENSE_MLP, MOE, MOE_ROUTER, MOE_DISPATCH,
+            MOE_EXPERTS, LM_HEAD} <= found
+    assert MOE_SHARED not in found
+    for phases in lists:
+        if SHORTCONV in phases:
+            assert not {GQA, MIXER_CORE, MOE, DENSE_MLP, LM_HEAD} & set(
+                phases), phases
+    for mixer in (SHORTCONV, GQA):
+        assert any(mixer in p and LOSS_GRAD in p for p in lists)
+        assert any(mixer in p and POLICY_ACT in p for p in lists)
+
+
 # One level down (PR 35): where each sub-scope must be found in a
 # core's compiled iteration. (core, scope) -> (the scopes it is nested
 # in, a phase it must be found under, the phases it must never be
@@ -426,6 +455,13 @@ SUB_SCOPES = {
     ("xing", _P.MHC): ((), LOSS_GRAD, ()),
     ("xing", _P.MHC_SINKHORN): ((_P.MHC,), POLICY_ACT, ()),
     ("xing", _P.SAMPLE): ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
+    ("lfm2", _P.SHORTCONV): ((), LOSS_GRAD, ()),
+    ("lfm2", _P.MIXER_PROJ): ((_P.SHORTCONV,), LOSS_GRAD, ()),
+    ("lfm2", _P.MIXER_POINTWISE): ((_P.SHORTCONV,), POLICY_ACT, ()),
+    ("lfm2", _P.MIXER_CORE): ((_P.GQA,), LOSS_GRAD, ()),
+    ("lfm2", _P.DENSE_MLP): ((), LOSS_GRAD, ()),
+    ("lfm2", _P.MOE_COMBINE): ((_P.MOE, _P.MOE_DISPATCH), LOSS_GRAD, ()),
+    ("lfm2", _P.SAMPLE): ((ROLLOUT, POLICY_ACT), ROLLOUT, (UPDATE,)),
 }
 
 
@@ -463,7 +499,7 @@ def test_a_sequence_core_iteration_carries_its_sub_scopes(core, scope):
 @pytest.mark.parametrize("core", sorted(SEQUENCE_PRESETS))
 def test_a_mixer_is_partitioned_into_its_three_parts(core):
     """Every instruction traced under a mixer's scope (gdn, gated_attn,
-    mla, gqa, mamba) is under exactly one of mixer_proj, mixer_pointwise and
+    mla, gqa, mamba, shortconv) is under exactly one of mixer_proj, mixer_pointwise and
     mixer_core, in the forward pass, the recomputed one and the
     backward pass: the three time shares add up to the mixers'."""
     names = [n for n in re.findall(r'op_name="([^"]*)"',

@@ -935,3 +935,96 @@ def test_the_xing_update_mixes_its_streams_in_the_mhc_kernels(one_chip):
                 for dims in re.findall(r"f32\[([\d,]+)\]", result))
     ]
     assert not moved, moved
+
+
+def test_the_lfm2_step_writes_its_tails_and_cache_in_place(one_chip):
+    """The LFM2 core's step form at the timed shape (128 envs; the four
+    short convolutions' float32 tails ``[2, 2048]`` in one array, one
+    attention layer's key and value caches of 512 rows) as the rollout
+    runs it: two convolution mixers and the attention in a loop that
+    carries the donated arrays, lowered for the described v5e. All three
+    arrays are aliased to the program's arguments; in the loop's body
+    each is either written in place or staged whole through VMEM around
+    the step (``copy-start`` / ``copy-done``) or, for the caches, laid
+    out anew by a fusion for attention's products; and beside the carry
+    the program holds no more than those two relayouts, double-buffered
+    (the scatter and the products want different layouts of one carried
+    array: PERF.md section 7). The convolutions alone hold
+    nothing beside the carry: their tails are written in place."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import lfm2
+
+    cfg = PRESETS["ppo-lfm2-recall"][1]["seq_model"]
+    B, L, H, steps = 128, 512, cfg.hidden_size, 3
+    n_conv, n_attn = cfg.layers_of(lfm2.CONV), cfg.layers_of(lfm2.ATTENTION)
+    assert (n_conv, n_attn) == (4, 1)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def params(layer, names):
+        spec = lfm2.layer_param_spec(cfg, layer)
+        return {name: arr(spec[name][0]) for name in names}
+
+    conv = params(2, ("in_proj", "conv", "out_proj"))
+    attn = params(1, ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm",
+                      "k_norm"))
+    tails = (B, n_conv, cfg.conv_L_cache - 1, H)
+    cache = (B, n_attn, L, cfg.num_key_value_heads, cfg.head_dim)
+
+    def rollout(conv, attn, xs, carry, pos):
+        def step(carried, x):
+            (tails, k, v), pos = carried
+            keep = jnp.ones((B,), jnp.float32)
+            for layer in (1, 3):
+                y, tails, _ = lfm2.shortconv_step(
+                    conv, x, tails, layer, keep, cfg, jnp.bfloat16
+                )
+                x = x + y
+            y, k, v = lfm2.gqa_step(attn, x, k, v, 0, pos, cfg, jnp.bfloat16)
+            return ((tails, k, v), pos + 1), x + y
+
+        (carry, pos), ys = jax.lax.scan(step, (carry, pos), xs)
+        return ys, carry, pos
+
+    compiled = jax.jit(rollout, donate_argnums=3).lower(
+        conv, attn, arr((steps, B, H)),
+        (arr(tails), arr(cache, jnp.bfloat16), arr(cache, jnp.bfloat16)),
+        arr((B,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    memory = compiled.memory_analysis()
+    cache_bytes = 2 * math.prod(cache)
+    carried = 4 * math.prod(tails) + 2 * cache_bytes
+    assert memory.alias_size_in_bytes >= carried
+    # (and the step's own small arrays: scores, a row of each cache)
+    assert memory.temp_size_in_bytes <= 2 * 2 * cache_bytes + 8 * 2**20
+    convs_only = jax.jit(
+        lambda conv, x, tails: lfm2.shortconv_step(
+            conv, x, tails, 1, jnp.ones((B,)), cfg, jnp.bfloat16
+        )[1:2],
+        donate_argnums=2,
+    ).lower(conv, arr((B, H)), arr(tails)).compile().memory_analysis()
+    assert convs_only.alias_size_in_bytes >= 4 * math.prod(tails)
+    assert convs_only.temp_size_in_bytes < 4 * math.prod(tails) // n_conv
+    comps = unfused(text)
+
+    def made(shape):
+        return [opcode for comp, rows in comps.items()
+                if not comp.startswith("main")
+                for name, result, opcode, *_ in rows
+                if result.startswith(shape) and opcode not in FREE | {"while"}]
+
+    dims = lambda shape: ",".join(map(str, shape))
+    # (one attention layer: the loop may hold its cache without the
+    # layer axis, a view; the tails, 8 MiB, may be laid out in VMEM by a
+    # copy there)
+    for shapes, also in (((dims(tails),), {"copy"}),
+                         ((dims(cache), dims(cache[:1] + cache[2:])), set())):
+        moved = {opcode for shape in shapes for kind in ("f32", "bf16")
+                 for opcode in made(f"{kind}[{shape}]")}
+        assert moved and moved <= {
+            "fusion", "copy-start", "copy-done", "custom-call"
+        } | also, (shapes, moved)
