@@ -631,8 +631,8 @@ def make_ppo(cfg: PPOConfig) -> common.IterationFns:
                 p, norm(obs), block["resets"], block["core"]
             )
             with jax.named_scope(profiling.LM_HEAD):
-                log_probs = dist.log_prob(block["actions"]).reshape(-1)
-                ent = dist.entropy().mean()
+                log_probs, ent = dist.log_prob_and_entropy(block["actions"])
+                log_probs, ent = log_probs.reshape(-1), ent.mean()
             clip = ppo_clip_loss(
                 log_probs,
                 block["old_log_probs"].reshape(-1),

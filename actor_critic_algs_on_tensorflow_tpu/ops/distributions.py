@@ -48,10 +48,63 @@ class Categorical(NamedTuple):
         p = jnp.exp(log_p)
         return -jnp.sum(p * log_p, axis=-1)
 
+    def log_prob_and_entropy(self, actions: jax.Array):
+        """``(log_prob(actions), entropy())`` from one pass of row
+        statistics, float32, with a hand-written backward (see
+        ``_log_prob_and_entropy``)."""
+        return _log_prob_and_entropy(
+            self.logits.astype(jnp.float32), actions.astype(jnp.int32)
+        )
+
     def kl(self, other: "Categorical") -> jax.Array:
         log_p = jax.nn.log_softmax(self.logits, axis=-1)
         log_q = jax.nn.log_softmax(other.logits, axis=-1)
         return jnp.sum(jnp.exp(log_p) * (log_p - log_q), axis=-1)
+
+
+def _row_statistics(z, actions):
+    """``(log p(a), H, (m, log S))`` of float32 logits ``z [..., V]``:
+    the row's max ``m``, then one pass that sums ``e = exp(z - m)`` to
+    ``S`` and ``e (z - m)``. Taken about the max, ``log p = (z - m) -
+    log S`` and ``H = lse - sum p z = log S - sum e (z - m) / S``."""
+    m = jnp.max(z, axis=-1, keepdims=True)
+    d = z - m
+    e = jnp.exp(d)
+    total = jnp.sum(e, axis=-1, keepdims=True)
+    log_total = jnp.log(total)
+    entropy = log_total - jnp.sum(e * d, axis=-1, keepdims=True) / total
+    # read off z, not d: a gather from d would write d out
+    taken = jnp.take_along_axis(z, actions[..., None], axis=-1) - m
+    return (taken - log_total)[..., 0], entropy[..., 0], (m, log_total)
+
+
+@jax.custom_vjp
+def _log_prob_and_entropy(z, actions):
+    log_prob, entropy, _ = _row_statistics(z, actions)
+    return log_prob, entropy
+
+
+def _log_prob_and_entropy_fwd(z, actions):
+    log_prob, entropy, shift = _row_statistics(z, actions)
+    return (log_prob, entropy), (z, shift, entropy, actions)
+
+
+def _log_prob_and_entropy_bwd(res, g):
+    """``dz = g_lp (onehot(a) - p) - g_H p (log p + H)``, ``log p = z -
+    lse``: one read of ``z``, one write of ``dz``."""
+    z, (m, log_total), entropy, actions = res
+    g_lp, g_h = (x[..., None] for x in g)
+    log_p = (z - m) - log_total
+    onehot = actions[..., None] == jnp.arange(z.shape[-1], dtype=jnp.int32)
+    dz = jnp.where(onehot, g_lp, 0.0) - jnp.exp(log_p) * (
+        g_lp + g_h * (log_p + entropy[..., None])
+    )
+    return dz, None
+
+
+_log_prob_and_entropy.defvjp(
+    _log_prob_and_entropy_fwd, _log_prob_and_entropy_bwd
+)
 
 
 class BlockReveal(NamedTuple):
@@ -107,6 +160,9 @@ class BlockReveal(NamedTuple):
         ids = jnp.where(scored, actions, 0).astype(jnp.int32)
         taken = jnp.take_along_axis(log_p, ids[..., None], axis=-1)[..., 0]
         return jnp.sum(jnp.where(scored, taken, 0.0), axis=-1)
+
+    def log_prob_and_entropy(self, actions: jax.Array):
+        return self.log_prob(actions), self.entropy()
 
     def entropy(self) -> jax.Array:
         """Mean categorical entropy over the masked positions."""

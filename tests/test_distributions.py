@@ -181,3 +181,61 @@ def test_block_reveal_entropy_is_the_masked_positions_mean():
     ).entropy())(logits)
     assert np.isfinite(np.asarray(grad)).all()
     assert float(jnp.max(jnp.abs(grad[1]))) == 0.0  # a clean position
+
+
+@pytest.mark.parametrize("vocab", [6, 200, 8192])
+def test_categorical_log_prob_and_entropy_from_one_pass(vocab):
+    """``log_prob_and_entropy`` over ``[T, b]`` rows is ``log_prob`` and
+    ``entropy`` at float32 rounding, and so is the gradient of a loss
+    that weights both (its own backward against JAX's derivative of the
+    two); both agree with float64."""
+    k_z, k_a, k_w = jax.random.split(jax.random.PRNGKey(vocab), 3)
+    logits = 3.0 * jax.random.normal(k_z, (5, 4, vocab)) + 40.0
+    actions = jax.random.randint(k_a, (5, 4), 0, vocab)
+    w_lp, w_h = jax.random.normal(k_w, (2, 5, 4))
+
+    def loss(lg, one_pass):
+        d = Categorical(lg)
+        if one_pass:
+            lp, h = d.log_prob_and_entropy(actions)
+        else:
+            lp, h = d.log_prob(actions), d.entropy()
+        return jnp.sum(w_lp * lp + w_h * h), (lp, h)
+
+    (_, (lp, h)), grad = jax.jit(jax.value_and_grad(
+        lambda lg: loss(lg, True), has_aux=True
+    ))(logits)
+    (_, (lp0, h0)), grad0 = jax.jit(jax.value_and_grad(
+        lambda lg: loss(lg, False), has_aux=True
+    ))(logits)
+    np.testing.assert_allclose(lp, lp0, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(h, h0, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(grad, grad0, rtol=1e-5, atol=1e-6)
+
+    z = np.asarray(logits, np.float64)
+    log_p = z - z.max(-1, keepdims=True)
+    log_p -= np.log(np.exp(log_p).sum(-1, keepdims=True))
+    p = np.exp(log_p)
+    ent = -(p * log_p).sum(-1)
+    taken = np.take_along_axis(log_p, np.asarray(actions)[..., None], -1)
+    np.testing.assert_allclose(lp, taken[..., 0], rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(h, ent, rtol=1e-6, atol=2e-6)
+    onehot = np.arange(vocab) == np.asarray(actions)[..., None]
+    w_lp, w_h = (np.asarray(w, np.float64)[..., None] for w in (w_lp, w_h))
+    expected = w_lp * (onehot - p) - w_h * p * (log_p + ent[..., None])
+    np.testing.assert_allclose(grad, expected, rtol=1e-5, atol=1e-6)
+    # with no gradient taken, the forward alone
+    lp1, h1 = jax.jit(Categorical(logits).log_prob_and_entropy)(actions)
+    np.testing.assert_allclose(lp1, taken[..., 0], rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(h1, ent, rtol=1e-6, atol=2e-6)
+
+
+def test_block_reveal_log_prob_and_entropy_is_its_two_methods():
+    logits = _block_logits(6, (3, 4))
+    block = jnp.asarray([[MASK, 3, MASK, 0], [0, 1, 2, 3],
+                         [MASK, MASK, MASK, MASK]])
+    actions = jnp.asarray([[4, 3, MASK, 0], [0, 1, 2, 3], [1, MASK, 2, 5]])
+    d = BlockReveal(logits, block, 2, MASK)
+    lp, h = d.log_prob_and_entropy(actions)
+    np.testing.assert_array_equal(lp, d.log_prob(actions))
+    np.testing.assert_array_equal(h, d.entropy())

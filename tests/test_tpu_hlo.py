@@ -1028,3 +1028,63 @@ def test_the_lfm2_step_writes_its_tails_and_cache_in_place(one_chip):
         assert moved and moved <= {
             "fusion", "copy-start", "copy-done", "custom-call"
         } | also, (shapes, moved)
+
+
+@pytest.mark.parametrize("ent_coef", [0.0, 0.01])
+def test_the_lfm2_update_head_takes_its_row_statistics_in_one_pass(
+    one_chip, ent_coef
+):
+    """The ``ppo-lfm2-recall`` update's head at a minibatch's shapes
+    (``h [512, 16, 2048]``, the tied embedding ``[8192, 2048]`` in
+    bfloat16 with float32 sums), its log-probability and entropy and a
+    PPO loss, differentiated for the described v5e. With
+    ``log_prob_and_entropy`` no ``reduce-window`` is left (``log_softmax``
+    took the row's max as one 16,383 wide, once a column: 189 ms an
+    iteration on the chip, PERF.md section 5), and fewer float32
+    logits-sized arrays are written than with ``log_prob`` + ``entropy``
+    (one, the logits, where those write three)."""
+    import jax.numpy as jnp
+
+    from actor_critic_algs_on_tensorflow_tpu.models import decoder
+    from actor_critic_algs_on_tensorflow_tpu.ops import (
+        Categorical, ppo_clip_loss,
+    )
+
+    cfg = PRESETS["ppo-lfm2-recall"][1]
+    T, b = cfg["rollout_length"], cfg["num_envs"] // cfg["num_minibatches"]
+    H, V = cfg["seq_model"].hidden_size, cfg["seq_model"].vocab_size
+    assert (T, b, H, V) == (512, 16, 2048, 8192)
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compiled_text(one_pass):
+        def loss(head, x, actions, old_log_probs, adv):
+            _, logits = decoder.lm_head(x, head, 1e-5, jnp.bfloat16)
+            dist = Categorical(logits)
+            if one_pass:
+                log_probs, ent = dist.log_prob_and_entropy(actions)
+            else:
+                log_probs, ent = dist.log_prob(actions), dist.entropy()
+            clip = ppo_clip_loss(log_probs.reshape(-1),
+                                 old_log_probs.reshape(-1), adv.reshape(-1),
+                                 clip_eps=cfg["clip_eps"])
+            return clip.policy_loss - ent_coef * ent.mean()
+
+        head = {"final_norm": arr((H,)), "embedding": arr((V, H))}
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            head, arr((T, b, H)), arr((T, b), jnp.int32), arr((T, b)),
+            arr((T, b)),
+        ).compile().as_text()
+
+    def logits_written(text):
+        return [name for rows in unfused(text).values()
+                for name, result, opcode, *_ in rows
+                if f"f32[{T},{b},{V}]" in result and opcode not in FREE]
+
+    text = compiled_text(True)
+    assert " reduce-window(" not in text
+    written = logits_written(text)
+    before = logits_written(compiled_text(False))
+    assert len(written) < len(before), (written, before)
+    assert len(written) == 1, written
